@@ -156,7 +156,11 @@ async def _run(args):
                 JobState.STOPPED, timeout=86400,
             )
             print(f"job {state.value.lower()}")
-            return 0 if state != JobState.FAILED else 1
+            if state == JobState.FAILED:
+                print(f"cause: {controller.jobs[job_id].failure}",
+                      file=sys.stderr)
+                return 1
+            return 0
         except KeyboardInterrupt:
             await controller.stop_job(job_id, "checkpoint"
                                       if args.state_dir else "graceful")

@@ -20,7 +20,7 @@ from .core import (
 )
 
 # what a default run covers, relative to the lint root
-DEFAULT_ROOTS = ("arroyo_tpu", "tools", "bench.py")
+DEFAULT_ROOTS = ("arroyo_tpu", "tools", "bench.py", "chip_smoke.py")
 EXCLUDED_PARTS = {"__pycache__", "lint_fixtures", ".git", "node_modules"}
 
 

@@ -155,7 +155,16 @@ def spawn_worker(controller_addr: str, worker_id: int,
     (ARROYO__CHAOS__PLAN) arms only in generation 0 by default, so a
     heartbeat-hit worker.kill cannot become a kill LOOP — each respawned
     process used to re-read the env and re-install the plan with fresh
-    hit counters (the carried truncation-as-FINISHED bug)."""
+    hit counters (the carried truncation-as-FINISHED bug).
+
+    The environment is inherited, so every worker process whose
+    operators take a device tier initialises jax's accelerator backend.
+    A chip serves ONE process: a second worker on the same chip fails
+    its StartExecution with the error ops/_jax.accelerator_present
+    raises (libtpu refuses the claim within a second; measured on a
+    v5e, PR 21) and the job goes FAILED after the restart budget — run
+    one device-tier worker per chip and pin the others to
+    JAX_PLATFORMS=cpu through `extra_env`."""
     env = dict(os.environ)
     env.update(extra_env or {})
     env["ARROYO_WORKER_ID"] = str(worker_id)

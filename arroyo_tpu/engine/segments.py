@@ -34,8 +34,7 @@ stateless chain, not one dispatch per operator):
   rung change recompiles the segment once, not N times), dispatched
   through `InstrumentedJit` (compile/dispatch telemetry +
   `arroyo_segment_dispatch_seconds`), with buffer donation on the
-  steady-state program where the jax generation allows it
-  (`engine.segment_donation`, gated like mesh donation). Chaos drills
+  steady-state program (`engine.segment_donation`). Chaos drills
   pin fused-vs-unfused byte identity across all tiers.
 
 * **Async double-buffered pipelining**: jax-tier dispatches stage
@@ -287,6 +286,7 @@ class _SegmentProgram:
     jit: Any = None               # InstrumentedJit over jax.jit(raw_fn)
     rung: Any = None              # shared _StickyRung
     n_rows_cap: int = 1 << 30
+    float64_checked: bool = False  # see _float64_off_device
 
 
 _FIXED_NP = {
@@ -455,19 +455,18 @@ def build_program(stages: List[_Stage], program_name: str):
 
 def attach_device_program(prog: _SegmentProgram, program_name: str) -> None:
     """Build the jitted device form of a composed segment program: jax
-    jit with donation where allowed (engine.segment_donation, gated like
-    mesh donation via safe_donate), an InstrumentedJit wrapper feeding
-    the compile/dispatch + segment telemetry, and the shared sticky
-    padding rung."""
+    jit with input donation per engine.segment_donation, an
+    InstrumentedJit wrapper feeding the compile/dispatch + segment
+    telemetry, and the shared sticky padding rung."""
     from ..obs import device as obs_device
-    from ..ops._jax import accelerator_present, get_jax, safe_donate
+    from ..ops._jax import accelerator_present, get_jax
     from ..parallel.sharded_state import _StickyRung
 
     jax = get_jax()
     donate_cfg = str(config().engine.segment_donation).lower()
     donate: tuple = ()
     if donate_cfg == "on" or (donate_cfg == "auto" and accelerator_present()):
-        donate = safe_donate(*range(len(prog.spec)))
+        donate = tuple(range(len(prog.spec)))
     jfn = jax.jit(prog.raw_fn, donate_argnums=donate)
     # power-of-two ladder up to the coarse shape_buckets ceiling: engine
     # batches are pow2-sized (pipeline.source_batch_size), so the sticky
@@ -629,7 +628,8 @@ class FusedSegmentOperator(Operator):
         self._staged: deque = deque()
         self._depth = max(1, int(config().engine.pipeline_depth))
         self._prog: Any = False   # False = not yet built; None = view tier
-        self._use_jax: Optional[bool] = None
+        self._use_jax = False
+        self._host_drops: set = set()
         self._vector_broken = False
         self._host_h = SEGMENT_DISPATCH_SECONDS.labels(
             program=self.program_name, tier="host")
@@ -658,39 +658,65 @@ class FusedSegmentOperator(Operator):
         VECTOR tier runs it directly (filter-late, one mask pass on the
         narrow outputs); when the device tier is active it is jitted
         into ONE XLA program. None = not composable (opaque py_fn member
-        etc.) -> the lazy-view host path."""
+        etc.) -> the lazy-view host path. A composition or lowering
+        error propagates: dropping a tier silently would hide the device
+        from whoever reads the result."""
         if self._prog is False:
-            prog = None
-            try:
-                prog = build_program(self._stages, self.program_name)
-            except Exception:  # composition is an optimization, never fatal
-                logger.exception(
-                    "segment %s: program composition failed; view tier",
-                    self.program_name,
-                )
-                prog = None
-            self._prog = prog
-        if self._use_jax is None and self._prog is not None:
-            from ..ops._jax import device_tier_active
+            self._prog = build_program(self._stages, self.program_name)
+            from ..ops import _jax
 
-            self._use_jax = device_tier_active()
+            self._use_jax = (self._prog is not None
+                             and _jax.device_tier_active())
             if self._use_jax:
-                try:
-                    attach_device_program(self._prog, self.program_name)
-                    logger.info(
-                        "segment %s: lowered %d ops to one jitted program "
-                        "(%d input leaves)", self.program_name,
-                        len(self._stages), len(self._prog.spec),
-                    )
-                except Exception:
-                    logger.exception(
-                        "segment %s: device lowering failed; vector tier",
-                        self.program_name,
-                    )
-                    self._use_jax = False
+                attach_device_program(self._prog, self.program_name)
+            logger.info(
+                "segment %s: tier=%s platform=%s (%d ops%s)",
+                self.program_name,
+                "jax" if self._use_jax else
+                "vector" if self._prog is not None and self._prog.exact
+                else "view",
+                _jax.platform(), len(self._stages),
+                "" if self._prog is not None else ", not composable",
+            )
         return self._prog
 
     # -- execution ---------------------------------------------------------
+
+    def _float64_off_device(self, prog: _SegmentProgram, arrays) -> bool:
+        """Before the first dispatch: a program that reads, computes or
+        produces float64 leaves the jax tier for good where the device's
+        float64 is not the host's (ops/_jax.float64_is_ieee). Decided
+        from an abstract trace of the program over the first batch's
+        leaf dtypes; nothing runs."""
+        from ..ops import _jax
+
+        prog.float64_checked = True
+        if _jax.float64_is_ieee():
+            return False
+        jax = _jax.get_jax()
+        traced = jax.make_jaxpr(prog.raw_fn)(
+            *[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays])
+        if "f64[" not in str(traced):
+            return False
+        self._use_jax = False
+        logger.warning(
+            "segment %s: tier=%s, not jax: the program uses float64 and "
+            "platform=%s has no IEEE float64", self.program_name,
+            "vector" if prog.exact else "view", _jax.platform(),
+        )
+        return True
+
+    def _note_host_drop(self, reason: str) -> None:
+        """A batch the jax tier hands to the host tiers. Each one is
+        counted in arroyo_segment_dispatch_seconds{tier="host"} (so the
+        host share of a jax-tier segment is readable beside its jax
+        dispatches); the reason is logged once."""
+        if reason not in self._host_drops:
+            self._host_drops.add(reason)
+            logger.warning(
+                "segment %s: jax tier hands batches to the host tier: %s",
+                self.program_name, reason,
+            )
 
     def _run_host(self, batch: pa.RecordBatch) -> Optional[pa.RecordBatch]:
         from ..sql.expressions import _LazyFilteredBatch
@@ -752,11 +778,17 @@ class FusedSegmentOperator(Operator):
         fall back (nulls in a non-strict subtree, oversized batch)."""
         n = batch.num_rows
         if n > prog.n_rows_cap:
+            self._note_host_drop(
+                f"batch of {n} rows exceeds the {prog.n_rows_cap}-row rung")
             return None
         packed = self._pack_leaves(batch, prog)
         if packed is None:
+            self._note_host_drop("nulls reach a non-strict subtree")
             return None
         arrays, validities = packed
+        if not prog.float64_checked and self._float64_off_device(
+                prog, arrays):
+            return None
         rung = prog.rung.fit(n)
         if rung < n:  # a just-decayed rung can undershoot; re-climb
             rung = prog.rung.fit(n)

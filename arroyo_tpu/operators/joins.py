@@ -24,7 +24,10 @@ from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
 from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
+from ..utils.logging import get_logger
 from .base import Operator
+
+logger = get_logger("joins")
 
 _JOIN_TYPE_MAP = {
     "inner": "inner",
@@ -47,6 +50,15 @@ class JoinBase(Operator):
         self.left_schema = config.get("left_schema")  # StreamSchema of jl
         self.right_schema = config.get("right_schema")
         self.residual = config.get("residual_py")
+        self._host_join_reasons: set = set()
+
+    def _note_host_join(self, reason: str) -> None:
+        """A join that ran on the arrow host join although the device
+        probe is active; each reason is logged once."""
+        if reason not in self._host_join_reasons:
+            self._host_join_reasons.add(reason)
+            logger.info("join %s: host join with the device probe "
+                        "active: %s", self.name, reason)
 
     def _filter_to_range(self, batch: pa.RecordBatch, ctx):
         """Row-level key-range filter for restored state: replays every
@@ -73,25 +85,25 @@ class JoinBase(Operator):
         """Bin-local inner equi-join via the jitted device probe
         (ops/device_join.py), producing the same column layout as
         pa.Table.join(..., coalesce_keys=True, right_suffix='_right').
-        Returns None when the device path doesn't apply (disabled, too
-        small, non-integer or nullable keys) — caller falls back to the
-        arrow host join."""
+        Returns None when the device path doesn't apply (probe tier
+        off, join below the row floor, key types the probe can't code) —
+        the caller runs the arrow host join; why is logged once per
+        reason."""
         from ..config import config
-
-        cfg = config().tpu
+        from ..ops import device_join
         from ..ops._jax import device_join_active
 
         if not device_join_active():
-            return None
-        if left_nt.num_rows + right_nt.num_rows < cfg.device_join_min_rows:
-            return None
-        from ..ops import device_join
-
-        if not device_join.available():
+            return None  # host tier: said once at open
+        floor = config().tpu.device_join_min_rows
+        if left_nt.num_rows + right_nt.num_rows < floor:
+            self._note_host_join(
+                f"bins below tpu.device_join_min_rows={floor}")
             return None
         lkeys = [f"__key{i}" for i in range(self.n_keys)]
         prep = device_join.prepare_join_keys(left_nt, right_nt, lkeys)
         if prep is None:
+            self._note_host_join("a key type the probe cannot code")
             return None
         lcols, rcols, lsel, rsel = prep
         li, ri = device_join.probe(lcols, rcols)
@@ -354,6 +366,9 @@ class InstantJoinOperator(JoinBase):
         }
 
     async def on_start(self, ctx):
+        from ..ops.device_join import log_probe_tier
+
+        log_probe_tier(self)
         if ctx.table_manager is not None:
             self._durable = True
             self._tables = [
@@ -516,6 +531,9 @@ class JoinWithExpirationOperator(JoinBase):
         }
 
     async def on_start(self, ctx):
+        from ..ops.device_join import log_probe_tier
+
+        log_probe_tier(self)
         if ctx.table_manager is not None:
             table = await ctx.table("jb")
             for snap in table.all_values():

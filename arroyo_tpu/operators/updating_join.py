@@ -82,6 +82,9 @@ class UpdatingJoinOperator(Operator):
         return {"uj": global_table("uj")}
 
     async def on_start(self, ctx):
+        from ..ops.device_join import log_probe_tier
+
+        log_probe_tier(self)
         if ctx.table_manager is not None:
             table = await ctx.table("uj")
             for snap in table.all_values():
@@ -195,13 +198,11 @@ class UpdatingJoinOperator(Operator):
         if not device_join_active():
             return None
         # cheap per-batch disqualifiers BEFORE any O(store) work (key
-        # scan, mirror rebuild): jax availability, key-type codability,
-        # null keys anywhere (per-row dict-equality semantics are
-        # authoritative for nulls), retracts in the batch
+        # scan, mirror rebuild): key-type codability, null keys anywhere
+        # (per-row dict-equality semantics are authoritative for nulls),
+        # retracts in the batch
         from ..ops import device_join
 
-        if not device_join.available():
-            return None
         names = batch.schema.names
         kcols = [f"__key{i}" for i in range(self.n_keys)]
         from ..ops.device_join import _codable

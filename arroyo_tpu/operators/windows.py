@@ -26,11 +26,18 @@ import pyarrow as pa
 
 from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
-from ..ops.aggregates import AggSpec, make_accumulator
+from ..ops.aggregates import (
+    AggSpec,
+    float_state_stays_on_host,
+    make_accumulator,
+)
 from ..ops.directory import SlotDirectory, unintern_value
 from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
+from ..utils.logging import get_logger
 from .base import Operator
+
+logger = get_logger("windows")
 
 
 def _specs_from_config(config: dict) -> List[AggSpec]:
@@ -153,7 +160,10 @@ class WindowOperatorBase(Operator):
     def _mesh_devices(self, config: dict) -> int:
         if not self._mesh_ok or self.backend == "numpy":
             return 0
-        return self._cfg_mesh_devices(config)
+        n = self._cfg_mesh_devices(config)
+        if n >= 2 and float_state_stays_on_host(self.specs):
+            return 0  # make_accumulator below takes the numpy tier
+        return n
 
     @staticmethod
     def _cfg_mesh_devices(config: dict) -> int:
@@ -171,9 +181,9 @@ class WindowOperatorBase(Operator):
 
     @staticmethod
     def _mesh_device_list(n: int):
-        import jax
+        from ..ops._jax import get_jax
 
-        devices = jax.devices()
+        devices = get_jax().devices()
         if len(devices) < n:
             raise ValueError(
                 f"tpu.mesh_devices={n} but only {len(devices)} devices "
@@ -242,6 +252,27 @@ class WindowOperatorBase(Operator):
                         self.dir = NativeSlotDirectory(
                             load_native(), n_keys=sum(widths)
                         )
+            self._log_tier()
+
+    def _log_tier(self):
+        """The line a window operator logs once, when its first batch
+        has settled the directory: which tiers it took, on what
+        platform."""
+        from ..ops import _jax
+
+        acc = self.acc
+        exchange = getattr(acc, "_exchange", None)
+        note = ""
+        if exchange:
+            note = f" mesh={acc.n_shards} exchange={exchange}"
+        elif acc.backend == "numpy" and float_state_stays_on_host(self.specs):
+            note = " (float64 accumulators: no IEEE float64 on this device)"
+        logger.info(
+            "window %s: accumulator=%s%s directory=%s platform=%s "
+            "capacity=%d",
+            self.name, acc.backend, note,
+            type(self.dir).__name__, _jax.platform(), acc.capacity,
+        )
 
     def _set_flat_layout(self, widths: List[int]):
         """Record the flat native key layout when struct keys flatten

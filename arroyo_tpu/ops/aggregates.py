@@ -31,7 +31,6 @@ from ..obs import device as obs_device
 
 # jax import deferred so host-only deployments can import the module tree
 from ._jax import get_jax as _get_jax
-from ._jax import safe_donate
 
 
 INT_MIN = np.iinfo(np.int64).min
@@ -576,7 +575,7 @@ class Accumulator:
         jax = _get_jax()
         phys = list(self.phys)
 
-        @partial(jax.jit, donate_argnums=safe_donate(0))
+        @partial(jax.jit, donate_argnums=(0,))
         def update(state, slots, *vals):
             out = []
             for (op, dt, src, si), s, v in zip(phys, state, vals):
@@ -685,7 +684,7 @@ class Accumulator:
                 self._neutral(op, dt) for op, dt, _, _ in self.phys
             ]
 
-            @partial(jax.jit, donate_argnums=safe_donate(0))
+            @partial(jax.jit, donate_argnums=(0,))
             def reset(state, s_idx):
                 return [
                     s.at[s_idx].set(nv) for s, nv in zip(state, neutrals)
@@ -923,12 +922,29 @@ class Accumulator:
                 s.block_until_ready()
 
 
+def float_state_stays_on_host(specs: List[AggSpec]) -> bool:
+    """True when `specs` keep float64 physical accumulators (float
+    sum/min/max, avg, the variance and regression families) and the
+    device tier's float64 is not the host's (a TPU): their results must
+    equal the host tier's, so the whole accumulator runs on numpy
+    there. Integer aggregates — counts, integer sums, min/max — are
+    exact on the device and stay on it."""
+    from . import _jax
+
+    return (
+        any(dt == "f8" for s in specs for _, dt, _ in s.phys())
+        and _jax.device_tier_active() and not _jax.float64_is_ieee()
+    )
+
+
 def make_accumulator(specs: List[AggSpec], capacity: Optional[int] = None,
                      backend: Optional[str] = None) -> Accumulator:
     if backend is None:
         from ._jax import device_tier_active
 
-        backend = "jax" if device_tier_active() else "numpy"
+        backend = "jax" if (
+            device_tier_active() and not float_state_stays_on_host(specs)
+        ) else "numpy"
     if capacity is None:
         capacity = int(config().tpu.initial_capacity)
     return Accumulator(specs, capacity, backend)

@@ -57,7 +57,6 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..types import hash_arrays, hash_column
-from ._jax import safe_donate
 from .aggregates import _bucket
 
 SENT = np.int64(np.iinfo(np.int64).max)
@@ -69,11 +68,10 @@ def _fns():
     """Lazily-built jitted table ops (shape-specialized by jax's cache)."""
     if _FNS:
         return _FNS
-    import jax
+    from ._jax import get_jax
 
-    from ..parallel.mesh import _get_jnp
-
-    jnp = _get_jnp()
+    jax = get_jax()
+    jnp = jax.numpy
 
     @jax.jit
     def lookup(tab_hash, tab_slot, q):
@@ -82,7 +80,7 @@ def _fns():
         found = tab_hash[idx] == q
         return found, tab_slot[idx]
 
-    @partial(jax.jit, donate_argnums=safe_donate(0, 1))
+    @partial(jax.jit, donate_argnums=(0, 1))
     def merge(tab_hash, tab_slot, add_h, add_slot):
         # splice sorted add_h (SENT-padded) into sorted tab_hash by
         # computing every element's merged position and scattering; SENT
@@ -105,7 +103,7 @@ def _fns():
         out_s = out_s.at[pos_new].set(add_slot, mode="drop")
         return out_h, out_s, n_add
 
-    @partial(jax.jit, donate_argnums=safe_donate(0, 1))
+    @partial(jax.jit, donate_argnums=(0, 1))
     def remove(tab_hash, tab_slot, del_h):
         # drop entries whose hash appears in sorted del_h (SENT-padded),
         # then compact left to restore the sorted-real/SENT-tail layout
@@ -171,11 +169,10 @@ class DeviceSlotDirectory:
     emission path."""
 
     def __init__(self, n_keys: int = 1, table_capacity: int = 1 << 16):
-        import jax
+        from ._jax import get_jax
 
-        from ..parallel.mesh import _get_jnp
-
-        jnp = _get_jnp()
+        jax = get_jax()
+        jnp = jax.numpy
         self.n_keys = n_keys
         self._stride = max(1, n_keys)
         self._cap = int(table_capacity)

@@ -24,9 +24,26 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from ..utils.logging import get_logger
 from ._jax import get_jax as _get_jax
 
+logger = get_logger("device_join")
+
 _fns = None
+
+
+def log_probe_tier(op) -> None:
+    """The line a join operator logs once at open: which probe tier it
+    takes, on what platform, and the row floor below which a join stays
+    on the host arrow join."""
+    from ..config import config
+    from . import _jax
+
+    logger.info(
+        "join %s: probe tier=%s platform=%s (device_join_min_rows=%d)",
+        op.name, "device" if _jax.device_join_active() else "host",
+        _jax.platform(), config().tpu.device_join_min_rows,
+    )
 
 
 def _build_fns():
@@ -159,15 +176,6 @@ def probe(
     for lc, rc in zip(lcols, rcols):
         keep &= lc[li] == rc[ri]
     return li[keep], ri[keep]
-
-
-def available() -> bool:
-    """Device probe usable in this process (jax importable)?"""
-    try:
-        _get_jax()
-        return True
-    except Exception:  # noqa: BLE001 - host-only deployment
-        return False
 
 
 def _codable(t) -> bool:

@@ -1,61 +1,66 @@
 """Loader + wrapper for the native (C++) slot directory.
 
-The native path handles the common single-int64-key case; everything else
-falls back to the python SlotDirectory. Build happens lazily on first use
-(g++ is in the image); failures degrade silently to the python
-implementation.
+The native path handles keys that flatten to int64 words; everything
+else uses the python SlotDirectory. The extension builds lazily on first
+use (native/build.py, g++). A host that deliberately runs without it
+sets ARROYO_DISABLE_NATIVE=1; a build or import that FAILS is an error —
+the python directory is several times slower on the host's critical
+path, and taking it silently would hide that.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import os
+import subprocess
 import sys
 from typing import List, Tuple
 
 import numpy as np
 
 _native = None
-_tried = False
+
+_NATIVE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    "native",
+)
+
+
+def native_build_module():
+    """native/build.py as a module (it is not a package member)."""
+    spec = importlib.util.spec_from_file_location(
+        "_arroyo_native_build", os.path.join(_NATIVE_DIR, "build.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_native():
-    global _native, _tried
-    if _tried:
+    global _native
+    if _native is not None or os.environ.get("ARROYO_DISABLE_NATIVE"):
         return _native
-    _tried = True
-    if os.environ.get("ARROYO_DISABLE_NATIVE"):
-        return None
+    # always run the (mtime-cached) build first: importing an existing
+    # .so without the check would silently use a stale binary after
+    # slotdir.cpp changes
     try:
-        repo_root = os.path.dirname(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        )
-        native_dir = os.path.join(repo_root, "native")
-        sys.path.insert(0, native_dir)
-        try:
-            # always run the (mtime-cached) build first: importing an
-            # existing .so without the check would silently use a stale
-            # binary after slotdir.cpp changes
-            from importlib import invalidate_caches
-
-            build_py = os.path.join(native_dir, "build.py")
-            import importlib.util
-
-            spec = importlib.util.spec_from_file_location("_anb", build_py)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            mod.build()
-            invalidate_caches()
-            import arroyo_native  # noqa: F401
-        finally:
-            # the extension stays imported; nothing else should resolve
-            # through native/ (it contains a generic build.py)
-            try:
-                sys.path.remove(native_dir)
-            except ValueError:
-                pass
-        _native = arroyo_native
-    except Exception:  # noqa: BLE001 - silent fallback to python impl
-        _native = None
+        native_build_module().build()
+    except (OSError, subprocess.CalledProcessError) as e:
+        raise RuntimeError(
+            "building native/slotdir.cpp failed "
+            f"({getattr(e, 'stderr', None) or e}); install g++ or set "
+            "ARROYO_DISABLE_NATIVE=1 to run on the (slower) python slot "
+            "directory"
+        ) from e
+    importlib.invalidate_caches()
+    sys.path.insert(0, _NATIVE_DIR)
+    try:
+        import arroyo_native
+    finally:
+        # the extension stays imported; nothing else should resolve
+        # through native/ (it contains a generic build.py)
+        sys.path.remove(_NATIVE_DIR)
+    _native = arroyo_native
     return _native
 
 

@@ -15,11 +15,11 @@ _MESH_CACHE: Dict[Tuple, object] = {}
 
 
 def _get_jnp():
-    """jax.numpy with x64 enabled (routes through ops.aggregates so the
-    enable-x64 flag is set exactly once, before any tracing)."""
-    from ..ops.aggregates import _get_jax
+    """jax.numpy behind the shared bootstrap (ops/_jax.py: x64 and the
+    compile cache are configured before anything can trace)."""
+    from ..ops._jax import get_jax
 
-    return _get_jax().numpy
+    return get_jax().numpy
 
 
 def key_mesh(devices: Optional[Sequence] = None, axis: str = "keys"):
@@ -28,18 +28,18 @@ def key_mesh(devices: Optional[Sequence] = None, axis: str = "keys"):
     the process-level jitted-program cache in sharded_state.py (keyed by
     mesh identity among other things) actually hits across operators —
     distinct Mesh objects would re-trace identical programs per stage."""
-    import jax
+    from ..ops._jax import get_jax
 
+    jax = get_jax()
     if devices is None:
         devices = jax.devices()
     key = (tuple(d.id for d in devices), axis)
     mesh = _MESH_CACHE.get(key)
     if mesh is None:
-        from jax.sharding import Mesh
-
         import numpy as np
 
-        mesh = _MESH_CACHE.setdefault(key, Mesh(np.array(devices), (axis,)))
+        mesh = _MESH_CACHE.setdefault(
+            key, jax.sharding.Mesh(np.array(devices), (axis,)))
     return mesh
 
 

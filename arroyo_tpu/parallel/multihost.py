@@ -63,8 +63,9 @@ def ensure_initialized() -> Tuple[int, int]:
                 f"tpu.mesh_processes={n_proc} requires mesh_coordinator "
                 f"and mesh_process_id (got {coord!r}, {pid})"
             )
-        import jax
+        from ..ops._jax import get_jax
 
+        jax = get_jax()
         logger.info(
             "joining %d-process mesh as rank %d (coordinator %s)",
             n_proc, pid, coord,
@@ -111,10 +112,10 @@ def put_global(np_arr, mesh, spec):
     data plane broadcast guarantees it); only locally-addressable shards
     are materialized. Single-process meshes take the direct device_put
     fast path."""
-    import jax
-    from jax.sharding import NamedSharding
+    from ..ops._jax import get_jax
 
-    sharding = NamedSharding(mesh, spec)
+    jax = get_jax()
+    sharding = jax.sharding.NamedSharding(mesh, spec)
     if not is_multiprocess_mesh(mesh):
         return jax.device_put(np_arr, sharding)
     return jax.make_array_from_callback(

@@ -2,7 +2,8 @@
 """arroyolint CLI — project-specific static analysis for arroyo_tpu.
 
 Usage:
-    python tools/lint.py                  # lint arroyo_tpu/, tools/, bench.py
+    python tools/lint.py                  # lint arroyo_tpu/, tools/, bench.py,
+                                          #   chip_smoke.py
     python tools/lint.py --strict         # CI mode: findings OR a stale /
                                           #   unjustified baseline fail (exit 1)
     python tools/lint.py --changed-only   # only files touched vs git HEAD

@@ -331,8 +331,6 @@ QUEUE_SIZE = REGISTRY.gauge(
     "arroyo_worker_queue_size", "occupancy of an edge queue (batches)")
 QUEUE_BYTES = REGISTRY.gauge(
     "arroyo_worker_queue_bytes", "occupancy of an edge queue (bytes)")
-TPU_KERNEL_MILLIS = REGISTRY.counter(
-    "arroyo_tpu_kernel_millis", "wall millis spent inside device kernels")
 BUSY_SECONDS = REGISTRY.counter(
     "arroyo_worker_busy_seconds",
     "wall seconds a subtask spent doing useful work (processing input "
@@ -385,8 +383,10 @@ XLA_COMPILE_SECONDS = REGISTRY.histogram(
     buckets=COMPILE_BUCKETS)
 DEVICE_DISPATCH_SECONDS = REGISTRY.histogram(
     "arroyo_device_dispatch_seconds",
-    "steady-state dispatch wall time of already-compiled jitted "
-    "programs, per program")
+    "HOST wall time of one call into an already-compiled jitted program "
+    "(the enqueue: argument transfer and launch; jax returns before the "
+    "device runs it), per program. Device time comes from a profiler "
+    "trace only")
 DEVICE_EXCHANGE_SECONDS = REGISTRY.histogram(
     "arroyo_device_exchange_seconds",
     "per-dispatch wall time of the mesh EXCHANGE programs only (the "
@@ -467,7 +467,8 @@ JOB_ATTR_CPU_SECONDS = REGISTRY.counter(
     "proportional to their attributed busy time in that interval)")
 JOB_ATTR_DEVICE_SECONDS = REGISTRY.counter(
     "arroyo_job_attributed_device_seconds",
-    "wall seconds inside jitted device programs (compiles + dispatches) "
+    "HOST wall seconds of the calls into jitted device programs "
+    "(compiles + enqueues, not the programs' run on the device) "
     "attributed to a job — the per-job dimension of the shared-program "
     "XLA telemetry (programs are cached process-wide across jobs, so "
     "the per-program families cannot carry a job label themselves)")
@@ -479,11 +480,6 @@ JOB_ATTR_BYTES = REGISTRY.counter(
     "arroyo_job_attributed_bytes",
     "data-plane bytes (batches received by the job's subtasks) "
     "attributed to a job via the ambient job-id context")
-JOB_ATTR_PHASE_SECONDS = REGISTRY.counter(
-    "arroyo_job_attributed_phase_seconds",
-    "wall seconds per batch-pipeline phase (phase=decode|process|"
-    "dispatch|exchange|emit|flush|watermark) attributed to a job — the "
-    "metric rollup of the timeline profiler's phase ledger")
 # StateServe (ISSUE 12): the queryable-state serving tier. Every family
 # carries a `job` label so Registry.drop_job GCs a stopped job's serve
 # series with the rest of its metrics; the tenant label on the request

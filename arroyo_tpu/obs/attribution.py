@@ -17,8 +17,14 @@ metric families:
   process-CPU delta across jobs proportional to attributed busy);
 * device seconds + dispatch counts (the per-job dimension of the XLA
   telemetry — jitted programs are cached process-wide across jobs, so
-  the per-program families cannot carry a job label themselves);
-* bytes, and per-phase wall seconds (the timeline ledger's rollup).
+  the per-program families cannot carry a job label themselves).
+  "Device seconds" here and in `arroyo_job_attributed_device_seconds`
+  are the HOST time of the calls into jitted programs, as
+  `obs/device.py`'s `InstrumentedJit` clocks them: the enqueue (and, on
+  a first call, trace and compile), never the program's run on the
+  device, which only a profiler trace shows;
+* bytes. (Per-phase wall seconds live in the timeline ledger's own
+  store, `obs/timeline.py`: its `phase_totals` is what the doctor reads.)
 
 Shared-plan apportioning (ISSUE 16): a shared source scan runs as a
 hidden host job `__shared/<fp>`, so its runner notes busy/device time
@@ -89,7 +95,7 @@ def job_scope(job_id: str):
 class _Pending:
     """One job's unflushed deltas (plain floats; lock held by Accounting)."""
 
-    __slots__ = ("busy", "device", "dispatches", "bytes", "phases",
+    __slots__ = ("busy", "device", "dispatches", "bytes",
                  "first_ts", "last_ts")
 
     def __init__(self):
@@ -97,7 +103,6 @@ class _Pending:
         self.device = 0.0
         self.dispatches = 0
         self.bytes = 0
-        self.phases: Dict[str, float] = {}
         self.first_ts = time.monotonic()
         self.last_ts = self.first_ts
 
@@ -128,8 +133,7 @@ class Accounting:
 
     def note(self, *, job: Optional[str] = None, busy: float = 0.0,
              device: float = 0.0, dispatches: int = 0,
-             nbytes: int = 0, phase: Optional[str] = None,
-             phase_secs: float = 0.0) -> None:
+             nbytes: int = 0) -> None:
         """Accumulate one site's delta under `job` (default: the ambient
         job id). Unattributed work lands under "" and is surfaced as the
         coverage gap, never silently dropped."""
@@ -143,8 +147,6 @@ class Accounting:
             p.device += device
             p.dispatches += dispatches
             p.bytes += nbytes
-            if phase is not None:
-                p.phases[phase] = p.phases.get(phase, 0.0) + phase_secs
             p.last_ts = time.monotonic()
 
     def note_lag(self, lag: float) -> None:
@@ -204,7 +206,6 @@ class Accounting:
             device = split_f(p.device)
             disp = split_i(p.dispatches)
             nbytes = split_i(p.bytes)
-            phases = {ph: split_f(s) for ph, s in p.phases.items()}
             for t in tenants:
                 q = pending.get(t)
                 if q is None:
@@ -213,8 +214,6 @@ class Accounting:
                 q.device += device[t]
                 q.dispatches += disp[t]
                 q.bytes += nbytes[t]
-                for ph, share in phases.items():
-                    q.phases[ph] = q.phases.get(ph, 0.0) + share[t]
                 q.first_ts = min(q.first_ts, p.first_ts)
                 q.last_ts = max(q.last_ts, p.last_ts)
 
@@ -235,7 +234,6 @@ class Accounting:
                 "device": JOB_ATTR_DEVICE_SECONDS.labels(job=job),
                 "dispatches": JOB_ATTR_DISPATCHES.labels(job=job),
                 "bytes": JOB_ATTR_BYTES.labels(job=job),
-                "phases": {},
             }
         return h
 
@@ -244,8 +242,6 @@ class Accounting:
         interval's process-CPU delta across jobs proportional to their
         attributed busy time in the interval. Idempotent; called by the
         pump each interval and by scrape-side readers (doctor, harness)."""
-        from ..metrics import JOB_ATTR_PHASE_SECONDS
-
         with self._lock:
             pending, self._pending = self._pending, {}
             cpu_now = time.process_time()
@@ -289,13 +285,6 @@ class Accounting:
             if p.bytes:
                 h["bytes"].inc(p.bytes)
                 tot["bytes"] += p.bytes
-            for phase, secs in p.phases.items():
-                ph = h["phases"].get(phase)
-                if ph is None:
-                    ph = h["phases"][phase] = JOB_ATTR_PHASE_SECONDS.labels(
-                        job=job, phase=phase
-                    )
-                ph.inc(secs)
 
     # ----------------------------------------------------------- surface
 

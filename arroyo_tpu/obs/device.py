@@ -19,6 +19,19 @@ or a steady-state dispatch:
   batch/checkpoint trace triggered the compile;
 * dispatches feed `arroyo_device_dispatch_seconds` and a cache hit.
 
+Every second booked here is HOST time: how long the call of the jitted
+function took to return, which for a dispatch is the enqueue (argument
+transfer and launch) and never the program's run on the device. JAX
+returns before the device finishes; `dispatch_s`, the "device seconds" of
+the attribution accounting and the ledger's `dispatch` phase all mean
+this. Device time per program comes from a profiler trace only
+(`benchmark/trace_reduce.py`): no call here waits for the device.
+
+A call site that pads passes `rung=` (the padded rows) and `rows=` (the
+real ones); both are summed per program (`summary()["programs"][p]`:
+`rows`, `padded_rows`) and onto the ledger entry of the phase that
+encloses the call (`timeline.phase`: `n`, `padded`).
+
 `note_padding` records the per-(program, rung) padding-waste gauge from
 the packing paths (aggregates + the mesh exchange in parallel/).
 
@@ -54,6 +67,8 @@ _RECOMPILE_LOG: deque = deque(maxlen=256)
 _SPAN_EPOCH = 0
 # per-(program, rung) cached gauge handles for the padding-waste path
 _PAD_HANDLES: Dict[Tuple[str, str], Any] = {}
+# program -> [real rows, padded rows] summed over its calls
+_ROWS: Dict[str, List[int]] = {}
 
 
 def enabled() -> bool:
@@ -80,6 +95,8 @@ def reset() -> None:
     with _LOCK:
         _RECOMPILE_LOG.clear()
         _PAD_HANDLES.clear()
+        for counts in _ROWS.values():
+            counts[:] = [0, 0]
         _SPAN_EPOCH = 0
 
 
@@ -195,7 +212,8 @@ class InstrumentedJit:
     histogram will show it — but it still costs a python-side trace."""
 
     __slots__ = ("program", "fn", "seen", "_compiles", "_hit", "_miss",
-                 "_compile_h", "_dispatch_h", "_exchange_h", "_segment_h")
+                 "_compile_h", "_dispatch_h", "_exchange_h", "_segment_h",
+                 "_rows")
 
     def __init__(self, program: str, fn, exchange: bool = False,
                  segment: bool = False):
@@ -207,6 +225,9 @@ class InstrumentedJit:
         self._miss = XLA_COMPILE_CACHE.labels(program=program, result="miss")
         self._compile_h = XLA_COMPILE_SECONDS.labels(program=program)
         self._dispatch_h = DEVICE_DISPATCH_SECONDS.labels(program=program)
+        # [real rows, padded rows] of the calls that gave both, shared by
+        # every wrapper of one program (join.phase2 has one per size)
+        self._rows = _ROWS.setdefault(program, [0, 0])
         # exchange programs (the mesh keyed shuffle: route/step kernels)
         # additionally feed arroyo_device_exchange_seconds so the
         # collective's per-flush cost is separable from emission reads
@@ -222,9 +243,17 @@ class InstrumentedJit:
             if segment else None
         )
 
-    def __call__(self, *args, rung: Optional[int] = None):
+    def __call__(self, *args, rung: Optional[int] = None,
+                 rows: Optional[int] = None):
         if not enabled():
             return self.fn(*args)
+        if rows is not None and rung is not None:
+            self._rows[0] += rows
+            self._rows[1] += rung
+            open_phase = timeline.open_phase()
+            if open_phase is not None:
+                open_phase.n += rows
+                open_phase.padded += rung
         key = signature_key(args)
         fresh = key not in self.seen
         start_us = time.time() * 1e6
@@ -235,7 +264,8 @@ class InstrumentedJit:
         # cached process-wide ACROSS jobs, so the per-program families
         # cannot carry a job label — the ambient job context gives
         # dispatch/compile seconds their job dimension instead, and the
-        # timeline ledger its device swimlane
+        # timeline ledger its `dispatch` swimlane. `dt` is the host time
+        # of the enqueue (module docstring), not time on the device
         attribution.note(device=dt, dispatches=1)
         timeline.note("dispatch", dt)
         if fresh:
@@ -377,6 +407,11 @@ def summary() -> dict:
         p["exchange_quantiles"] = {
             q: round(v, 6) for q, v in hist_quantiles(h).items()
         }
+    for prog, (real, padded) in list(_ROWS.items()):
+        if padded:
+            p = programs.setdefault(prog, {})
+            p["rows"] = real
+            p["padded_rows"] = padded
     for labels, v in snap.get("arroyo_xla_compile_cache_total", []):
         p = programs.setdefault(labels.get("program", "?"), {})
         p[f"cache_{labels.get('result', '?')}"] = int(v)

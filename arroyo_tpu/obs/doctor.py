@@ -161,8 +161,10 @@ def collect(job_id: str, registry=None) -> dict:
         default=0.0,
     )
 
+    # self seconds: a phase's duration less the phases booked inside it,
+    # so the shares below add up however finely a batch is split
     phases = {
-        p: t["total_s"]
+        p: t["self_s"]
         for p, t in timeline.phase_totals(job_id).items()
     }
     sig = {
@@ -203,7 +205,7 @@ def diagnose(sig: dict) -> dict:
     busy = float(sig.get("busy_ratio") or 0.0)
     phases = sig.get("phases") or {}
     phase_total = sum(
-        v for p, v in phases.items() if p != "loop.lag"
+        v for p, v in phases.items() if p not in ("loop.lag", "queue.wait")
     ) or 1e-9
     device_s = float(sig.get("device_s") or phases.get("dispatch", 0.0))
     busy_s = float(sig.get("busy_s") or 0.0) or phase_total
@@ -299,11 +301,14 @@ def signals_from_trace(events: List[dict], job_id: str) -> dict:
             continue
         args = ev.get("args") or {}
         job = args.get("job", "")
-        dur_s = (ev.get("dur") or 0.0) / 1e6
         phase = ev["name"][len("phase."):]
         if phase == "loop.lag":
-            lags.append(dur_s)
+            lags.append((ev.get("dur") or 0.0) / 1e6)
             continue
+        if phase == "queue.wait":
+            continue            # waiting on a full out queue is not work
+        # self time where the dump carries it: nested phases add up
+        dur_s = (args.get("self", ev.get("dur")) or 0.0) / 1e6
         by_job[job] = by_job.get(job, 0.0) + dur_s
         ts = ev.get("ts", 0.0)
         t_min = ts if t_min is None else min(t_min, ts)

@@ -339,7 +339,9 @@ def perfetto_trace(spans: List[Dict[str, Any]],
             "name": f"phase.{e['phase']}", "cat": "phase", "ph": "X",
             "ts": e["ts"], "dur": max(0.0, e.get("dur") or 0.0),
             "pid": e.get("pid", pid), "tid": tid,
-            "args": {"job": e.get("job", ""), "task": e.get("task", "")},
+            "args": {"job": e.get("job", ""), "task": e.get("task", ""),
+                     "n": e.get("n", 0), "key": e.get("key"),
+                     "self": e.get("self", e.get("dur") or 0.0)},
         })
     events.sort(key=lambda ev: (ev.get("ts", 0), ev.get("pid", 0)))
     doc["phaseCount"] = len(timeline)

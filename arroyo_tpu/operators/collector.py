@@ -11,7 +11,6 @@ forward edges are 1-1. Signals broadcast to every destination queue.
 
 from __future__ import annotations
 
-import time
 import weakref
 from typing import List, Optional
 
@@ -178,10 +177,13 @@ class Collector:
         # fleet observatory: emit time (partitioning + queue sends,
         # INCLUDING any backpressure wait) is its own timeline phase —
         # a batch stuck here points downstream, not at this operator
-        t0 = time.perf_counter()
-        for edge in self.edges:
-            await edge.send_batch(batch)
-        timeline.note("emit", time.perf_counter() - t0, task=self.task_id)
+        # (a blocked send books its wait as `queue.wait` inside it: emit's
+        # self time is the rest. It awaits, so it is not annotated: over a
+        # full queue its interval covers whatever the other tasks do)
+        with timeline.phase("emit", task=self.task_id, n=batch.num_rows,
+                            annotate=False):
+            for edge in self.edges:
+                await edge.send_batch(batch)
         self._bp_tick += 1
         if self._bp_tick == 1 or self._bp_tick % self._BP_SAMPLE_EVERY == 0:
             # post-send occupancy of the most-loaded out queue: 1.0 means

@@ -22,6 +22,7 @@ import pyarrow as pa
 
 from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
+from ..obs import timeline
 from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
 from ..utils.logging import get_logger
@@ -101,32 +102,35 @@ class JoinBase(Operator):
                 f"bins below tpu.device_join_min_rows={floor}")
             return None
         lkeys = [f"__key{i}" for i in range(self.n_keys)]
-        prep = device_join.prepare_join_keys(left_nt, right_nt, lkeys)
+        with timeline.phase("join.prep"):
+            prep = device_join.prepare_join_keys(left_nt, right_nt, lkeys)
         if prep is None:
             self._note_host_join("a key type the probe cannot code")
             return None
         lcols, rcols, lsel, rsel = prep
-        li, ri = device_join.probe(lcols, rcols)
-        if lsel is not None:
-            li = lsel[li]
-        if rsel is not None:
-            ri = rsel[ri]
-        l_take = pa.array(li)
-        r_take = pa.array(ri)
-        arrays, names = [], []
-        lset = set(left_nt.column_names)
-        for name in left_nt.column_names:
-            arrays.append(left_nt.column(name).take(l_take))
-            names.append(name)
-        for name in right_nt.column_names:
-            if name in lkeys:
-                continue  # coalesced join keys
-            out = name + "_right" if name in lset else name
-            arrays.append(right_nt.column(name).take(r_take))
-            names.append(out)
-        # from_arrays, not a dict: duplicate output names must survive
-        # exactly like the arrow join's suffix behavior
-        return pa.Table.from_arrays(arrays, names=names)
+        with timeline.phase("join.probe", n=len(lcols[0])):
+            li, ri = device_join.probe(lcols, rcols)
+        with timeline.phase("join.take", n=len(li)):
+            if lsel is not None:
+                li = lsel[li]
+            if rsel is not None:
+                ri = rsel[ri]
+            l_take = pa.array(li)
+            r_take = pa.array(ri)
+            arrays, names = [], []
+            lset = set(left_nt.column_names)
+            for name in left_nt.column_names:
+                arrays.append(left_nt.column(name).take(l_take))
+                names.append(name)
+            for name in right_nt.column_names:
+                if name in lkeys:
+                    continue  # coalesced join keys
+                out = name + "_right" if name in lset else name
+                arrays.append(right_nt.column(name).take(r_take))
+                names.append(out)
+            # from_arrays, not a dict: duplicate output names must survive
+            # exactly like the arrow join's suffix behavior
+            return pa.Table.from_arrays(arrays, names=names)
 
     def _inner_join(self, left_nt: pa.Table, right_nt: pa.Table) -> pa.Table:
         """Inner equi-join on the __key columns: device probe when
@@ -135,15 +139,17 @@ class JoinBase(Operator):
         if joined is not None:
             return joined
         lkeys = [f"__key{i}" for i in range(self.n_keys)]
-        return left_nt.join(
-            right_nt,
-            keys=lkeys,
-            right_keys=lkeys,
-            join_type="inner",
-            left_suffix="",
-            right_suffix="_right",
-            coalesce_keys=True,
-        )
+        # the host tier's probe, under the device probe's name
+        with timeline.phase("join.probe", n=left_nt.num_rows):
+            return left_nt.join(
+                right_nt,
+                keys=lkeys,
+                right_keys=lkeys,
+                join_type="inner",
+                left_suffix="",
+                right_suffix="_right",
+                coalesce_keys=True,
+            )
 
     def _join_tables(
         self, left: pa.Table, right: pa.Table, ts_value: int
@@ -157,27 +163,33 @@ class JoinBase(Operator):
         the residual, then anti-join to synthesize the null-padded rows
         (reference behavior comes from DataFusion's join filters)."""
         lkeys = [f"__key{i}" for i in range(self.n_keys)]
-        left_nt = _flatten_structs(left.drop_columns([TIMESTAMP_FIELD]))
-        right_nt = _flatten_structs(right.drop_columns([TIMESTAMP_FIELD]))
+        with timeline.phase("join.prep", key=ts_value):
+            left_nt = _flatten_structs(left.drop_columns([TIMESTAMP_FIELD]))
+            right_nt = _flatten_structs(
+                right.drop_columns([TIMESTAMP_FIELD]))
         if self.residual is None or self.join_type == "inner":
             if self.join_type == "inner":
                 joined = self._inner_join(left_nt, right_nt)
             else:
-                joined = left_nt.join(
-                    right_nt,
-                    keys=lkeys,
-                    right_keys=lkeys,
-                    join_type=_JOIN_TYPE_MAP[self.join_type],
-                    left_suffix="",
-                    right_suffix="_right",
-                    coalesce_keys=True,
-                )
-            batch = self._project(joined, ts_value)
-            if batch is None:
-                return None
-            if self.residual is not None:
-                batch = batch.filter(self.residual(batch))
-            return batch if batch.num_rows else None
+                with timeline.phase("join.probe", n=left_nt.num_rows):
+                    joined = left_nt.join(
+                        right_nt,
+                        keys=lkeys,
+                        right_keys=lkeys,
+                        join_type=_JOIN_TYPE_MAP[self.join_type],
+                        left_suffix="",
+                        right_suffix="_right",
+                        coalesce_keys=True,
+                    )
+            # the output table: projection and the residual predicate
+            with timeline.phase("join.take", key=ts_value,
+                                n=joined.num_rows):
+                batch = self._project(joined, ts_value)
+                if batch is None:
+                    return None
+                if self.residual is not None:
+                    batch = batch.filter(self.residual(batch))
+                return batch if batch.num_rows else None
 
         import pyarrow.compute as pc
 
@@ -452,15 +464,19 @@ class InstantJoinOperator(JoinBase):
                 continue
             if self.join_type == "right" and not right:
                 continue
-            lt = _concat(left) or _empty_from_schema(
-                self.left_schema, right[0], self.n_keys
-            )
-            rt = _concat(right) or _empty_from_schema(
-                self.right_schema, left[0], self.n_keys
-            )
+            with timeline.phase("join.buffer", key=ts) as ph:
+                lt = _concat(left) or _empty_from_schema(
+                    self.left_schema, right[0], self.n_keys
+                )
+                rt = _concat(right) or _empty_from_schema(
+                    self.right_schema, left[0], self.n_keys
+                )
+                ph.n = lt.num_rows + rt.num_rows
             out = self._join_tables(lt, rt, ts_value=ts)
             if out is not None:
-                await collector.collect(out)
+                with timeline.phase("join.emit", key=ts, n=out.num_rows,
+                                    annotate=False):
+                    await collector.collect(out)
             self.emitted_up_to = max(self.emitted_up_to or 0, ts)
         return watermark
 

@@ -19,6 +19,7 @@ from typing import Optional
 import pyarrow as pa
 
 from ..metrics import QUEUE_BYTES, QUEUE_SIZE
+from ..obs import timeline
 from ..types import SignalMessage
 
 
@@ -99,11 +100,16 @@ class BatchQueue:
             return
         if nbytes is None:
             nbytes = batch_bytes(item)
-        while not self._has_capacity():
-            self._writable.clear()
-            await self._writable.wait()
-            if self._closed:
-                raise QueueClosed(self.name)
+        if not self._has_capacity():
+            # backpressure: the wait has a name of its own, so the sender's
+            # `emit` keeps only the time it worked
+            with timeline.phase("queue.wait", task=self.name,
+                                annotate=False):
+                while not self._has_capacity():
+                    self._writable.clear()
+                    await self._writable.wait()
+                    if self._closed:
+                        raise QueueClosed(self.name)
         self._push(item, nbytes)
 
     def _push(self, item, nbytes: int):

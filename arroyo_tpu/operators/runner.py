@@ -203,14 +203,6 @@ class SubtaskRunner:
             self._rx_taps = [None] * len(inputs)
         self._op_counts = [[0, 0] for _ in ops]
 
-    def _note_busy(self, dt: float, phase: str):
-        """Mirror one busy-seconds increment into the fleet observatory:
-        per-job attributed busy (the ambient job context is set by run(),
-        so flush tasks and device work inherit it) plus the batch-phase
-        timeline ledger. Both are single dict/deque updates when on."""
-        obs.attribution.note(busy=dt)
-        obs.timeline.note(phase, dt, task=self.task_info.task_id)
-
     @property
     def is_source(self) -> bool:
         return isinstance(self.ops[0], SourceOperator)
@@ -559,13 +551,21 @@ class SubtaskRunner:
                         self._compile_trace, "watermark.advance",
                         task=self.task_info.task_id,
                     )
-                    try:
-                        await self._chain_watermark(0, changed)
-                    finally:
-                        anchor.close()
+                    # the enclosing phase of a close: its leaves are
+                    # booked where the work happens, its self time is
+                    # what they leave unnamed
+                    with obs.timeline.phase(
+                            "watermark", task=self.task_info.task_id,
+                            annotate=False):
+                        try:
+                            await self._chain_watermark(0, changed)
+                        finally:
+                            anchor.close()
                     dt = time.perf_counter() - t0
                     self._busy_secs.inc(dt)
-                    self._note_busy(dt, "watermark")
+                    # per-job attributed busy (the ambient job context is
+                    # set by run())
+                    obs.attribution.note(busy=dt)
                 return True
             if item.kind == SignalKind.LATENCY_MARKER:
                 await self._handle_marker(item)
@@ -596,16 +596,18 @@ class SubtaskRunner:
             self._compile_trace, "batch.process",
             task=self.task_info.task_id,
         )
-        try:
-            await self.ops[0].process_batch(
-                item, self.ctxs[0], self.collectors[0], iq.logical_input
-            )
-        finally:
-            anchor.close()
+        with obs.timeline.phase("process", task=self.task_info.task_id,
+                                n=item.num_rows, annotate=False):
+            try:
+                await self.ops[0].process_batch(
+                    item, self.ctxs[0], self.collectors[0], iq.logical_input
+                )
+            finally:
+                anchor.close()
         dt = time.perf_counter() - t0
         self._batch_seconds.observe(dt)
         self._busy_secs.inc(dt)
-        self._note_busy(dt, "process")
+        obs.attribution.note(busy=dt)
         return True
 
     async def _handle_marker(self, item: SignalMessage):
@@ -749,7 +751,9 @@ class SubtaskRunner:
         )
         t0 = time.perf_counter()
         cap_span = self._barrier_span("checkpoint.capture", barrier)
-        with cap_span:
+        with cap_span, obs.timeline.phase(
+                "ckpt.capture", task=self.task_info.task_id,
+                key=barrier.epoch):
             from ..serve import seal_op
 
             captured = []

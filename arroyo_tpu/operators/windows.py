@@ -26,6 +26,7 @@ import pyarrow as pa
 
 from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
+from ..obs import timeline
 from ..ops.aggregates import (
     AggSpec,
     float_state_stays_on_host,
@@ -314,7 +315,25 @@ class WindowOperatorBase(Operator):
     def _ensure_capacity(self):
         need = self.dir.required_capacity()
         if need > self.acc.capacity - 1:
-            self.acc.grow(need + 1)
+            with timeline.phase("win.grow") as ph:
+                self.acc.grow(need + 1)
+                ph.n = self.acc.capacity
+
+    def _scatter(self, batch: pa.RecordBatch, bins: np.ndarray,
+                 keys: List[np.ndarray], ctx):
+        """The shared tail of a batch in the tumbling and sliding
+        operators: (bin, key) -> slot, room for the new slots, the dirty
+        marks of an incremental checkpoint, then the accumulator's scatter.
+        Each step is a leaf of the phase ledger."""
+        with timeline.phase("dir.assign", n=len(bins)):
+            slots = self.dir.assign(bins, keys)
+        self._ensure_capacity()
+        if ctx.table_manager is not None and self._use_incremental():
+            with timeline.phase("win.dirty", n=len(slots)):
+                self._mark_dirty(slots, bins, keys)
+        with timeline.phase("win.cols", n=batch.num_rows):
+            cols = self._agg_input_cols(batch)
+        self.acc.update(slots, cols)
 
     # -- incremental checkpoints --------------------------------------------
     # Window state checkpoints write only the (bin, key) groups whose slots
@@ -717,7 +736,10 @@ class WindowOperatorBase(Operator):
             # which stages its batch itself with the partial flag set.
             from ..serve import stage_batch
 
-            stage_batch(self._serve_view, out)
+            # a sub-step of the caller's close.build, in the ledger only
+            with timeline.phase("close.build.stage", n=out.num_rows,
+                                annotate=False):
+                stage_batch(self._serve_view, out)
         return out
 
     # -- checkpoint form ----------------------------------------------------
@@ -958,25 +980,22 @@ class TumblingWindowOperator(WindowOperatorBase):
         return (b + 1) * self.width if self.width else b
 
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
-        self._capture_key_meta(ctx)
-        ts = ctx.in_schemas[0].timestamps(batch)
-        bins = self._bin_of(ts)
-        if self.emitted_up_to is not None:
-            if self.width:
-                live = (bins + 1) * self.width > self.emitted_up_to
-            else:
-                live = bins > self.emitted_up_to
-            if not live.all():
-                if not live.any():
-                    return
-                batch = batch.filter(pa.array(live))
-                bins = bins[live]
-        keys = self._key_arrays(batch)
-        slots = self.dir.assign(bins, keys)
-        self._ensure_capacity()
-        if ctx.table_manager is not None and self._use_incremental():
-            self._mark_dirty(slots, bins, keys)
-        self.acc.update(slots, self._agg_input_cols(batch))
+        with timeline.phase("win.keys", n=batch.num_rows):
+            self._capture_key_meta(ctx)
+            ts = ctx.in_schemas[0].timestamps(batch)
+            bins = self._bin_of(ts)
+            if self.emitted_up_to is not None:
+                if self.width:
+                    live = (bins + 1) * self.width > self.emitted_up_to
+                else:
+                    live = bins > self.emitted_up_to
+                if not live.all():
+                    if not live.any():
+                        return
+                    batch = batch.filter(pa.array(live))
+                    bins = bins[live]
+            keys = self._key_arrays(batch)
+        self._scatter(batch, bins, keys, ctx)
 
     async def handle_watermark(self, watermark, ctx, collector):
         if watermark.kind != WatermarkKind.EVENT_TIME:
@@ -994,46 +1013,57 @@ class TumblingWindowOperator(WindowOperatorBase):
         # bin — ~30 near-empty mesh.take dispatches per wave on the q5
         # per-window-max stage), then outputs slice back out per bin
         wave = []  # (bin, end, keys, key_arrays, slots)
-        for b in self.dir.bins_up_to(limit):
-            end = self._bin_end(b)
-            if end > t:
-                continue
-            if take_arrays is not None:
-                # native fast path: key columns stay numpy end-to-end
-                key_arrays, slots = take_arrays(b)
-                keys: List[tuple] = []
-            else:
-                keys, slots = self.dir.take_bin(b)
-                key_arrays = None
-            wave.append((b, end, keys, key_arrays, slots))
-        if not wave:
+        due = [b for b in self.dir.bins_up_to(limit)
+               if self._bin_end(b) <= t]
+        if not due:
             return watermark
-        all_slots = (
-            wave[0][4] if len(wave) == 1
-            else np.concatenate([w[4] for w in wave])
-        )
-        gathered = (
-            fused(all_slots) if fused is not None
-            else self.acc.gather(all_slots)
-        )
-        agg_cols = self.acc.finalize(gathered)
-        if fused is not None:
-            self.acc.drop_host_state(all_slots)
-        else:
-            self.acc.reset_slots(all_slots)
+        # the leaves of a close, keyed by the last window's end
+        last_end = self._bin_end(due[-1])
+        with timeline.phase("close.take", key=last_end) as ph:
+            for b in due:
+                if take_arrays is not None:
+                    # native fast path: key columns stay numpy end-to-end
+                    key_arrays, slots = take_arrays(b)
+                    keys: List[tuple] = []
+                else:
+                    keys, slots = self.dir.take_bin(b)
+                    key_arrays = None
+                wave.append((b, self._bin_end(b), keys, key_arrays, slots))
+            all_slots = (
+                wave[0][4] if len(wave) == 1
+                else np.concatenate([w[4] for w in wave])
+            )
+            ph.n = len(all_slots)
+        with timeline.phase("close.combine", key=last_end,
+                            n=len(all_slots)):
+            gathered = (
+                fused(all_slots) if fused is not None
+                else self.acc.gather(all_slots)
+            )
+        with timeline.phase("close.finalize", key=last_end):
+            agg_cols = self.acc.finalize(gathered)
+        with timeline.phase("close.reset", key=last_end):
+            if fused is not None:
+                self.acc.drop_host_state(all_slots)
+            else:
+                self.acc.reset_slots(all_slots)
         off = 0
         for b, end, keys, key_arrays, slots in wave:
             n = len(slots)
-            cols_b = [c[off:off + n] for c in agg_cols]
-            off += n
-            if self.width:
-                out = self._build_output(keys, cols_b, b * self.width, end,
-                                         key_arrays=key_arrays)
-            else:
-                # instant mode: preserve the window's timestamp exactly
-                out = self._build_output(keys, cols_b, b, b, ts_value=b,
-                                         key_arrays=key_arrays)
-            await collector.collect(out)
+            with timeline.phase("close.build", key=end, n=n):
+                cols_b = [c[off:off + n] for c in agg_cols]
+                off += n
+                if self.width:
+                    out = self._build_output(
+                        keys, cols_b, b * self.width, end,
+                        key_arrays=key_arrays)
+                else:
+                    # instant mode: preserve the window's timestamp exactly
+                    out = self._build_output(keys, cols_b, b, b, ts_value=b,
+                                             key_arrays=key_arrays)
+            with timeline.phase("close.emit", key=end, n=out.num_rows,
+                                annotate=False):
+                await collector.collect(out)
             self.emitted_up_to = max(self.emitted_up_to or 0, end)
         return watermark
 
@@ -1107,24 +1137,21 @@ class SlidingWindowOperator(WindowOperatorBase):
             table.put(ctx.task_info.task_index, snap)
 
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
-        self._capture_key_meta(ctx)
-        ts = ctx.in_schemas[0].timestamps(batch)
-        bins = ts // self.slide
-        if self.last_freed_bin is not None:
-            live = bins > self.last_freed_bin
-            if not live.all():
-                if not live.any():
-                    return
-                batch = batch.filter(pa.array(live))
-                bins = bins[live]
-        if self.next_emit is None and len(bins):
-            self.next_emit = (int(bins.min()) + 1) * self.slide
-        keys = self._key_arrays(batch)
-        slots = self.dir.assign(bins, keys)
-        self._ensure_capacity()
-        if ctx.table_manager is not None and self._use_incremental():
-            self._mark_dirty(slots, bins, keys)
-        self.acc.update(slots, self._agg_input_cols(batch))
+        with timeline.phase("win.keys", n=batch.num_rows):
+            self._capture_key_meta(ctx)
+            ts = ctx.in_schemas[0].timestamps(batch)
+            bins = ts // self.slide
+            if self.last_freed_bin is not None:
+                live = bins > self.last_freed_bin
+                if not live.all():
+                    if not live.any():
+                        return
+                    batch = batch.filter(pa.array(live))
+                    bins = bins[live]
+            if self.next_emit is None and len(bins):
+                self.next_emit = (int(bins.min()) + 1) * self.slide
+            keys = self._key_arrays(batch)
+        self._scatter(batch, bins, keys, ctx)
 
     async def handle_watermark(self, watermark, ctx, collector):
         if watermark.kind != WatermarkKind.EVENT_TIME:
@@ -1150,85 +1177,99 @@ class SlidingWindowOperator(WindowOperatorBase):
         # followed by a separate reset program launch per wave.
         key_chunks = []
         slot_chunks = []
-        take_arrays = getattr(self.dir, "take_bin_arrays", None)
-        if take_arrays is not None:
-            fk_cols, freed = take_arrays(lo_bin)
-            if len(freed):
-                key_chunks.append(np.stack(fk_cols, axis=1))
-                slot_chunks.append(freed)
-        else:
-            fk, freed = self.dir.take_bin(lo_bin)
-            if len(freed):
-                key_chunks.append(fk)
-                slot_chunks.append(freed)
-        multi = getattr(self.dir, "bin_entries_multi", None)
-        if multi is not None:
-            # native directories: ONE batched crossing covering every
-            # participating bin (the merge unions keys across bins, so
-            # per-bin identity is irrelevant) instead of k get_bin calls
-            # — k x shards calls on the mesh facade
-            kmat, slots_m = multi(
-                np.arange(lo_bin + 1, end_bin, dtype=np.int64)
-            )
-            if len(slots_m):
-                key_chunks.append(kmat)
-                slot_chunks.append(slots_m)
-        else:
-            for b in range(lo_bin + 1, end_bin):
-                keys_b, slots_b = self.dir.bin_entries(b)
-                if len(slots_b):
-                    key_chunks.append(keys_b)
-                    slot_chunks.append(slots_b)
-        if slot_chunks:
-            all_slots = np.concatenate(slot_chunks)
-            key_arrays = None
-            if isinstance(key_chunks[0], np.ndarray):
-                # native path: vectorized key-union over int64 key matrices
-                # (count, n_keycols); keys stay numpy end-to-end (no python
-                # tuple per key)
-                all_keys = np.concatenate(key_chunks)
-                if all_keys.shape[1] == 1:
-                    # 1-D unique is markedly faster than axis=0
-                    u1, seg_ids = np.unique(
-                        all_keys[:, 0], return_inverse=True
-                    )
-                    uniq = u1[:, None]
-                else:
-                    uniq, seg_ids = np.unique(
-                        all_keys, axis=0, return_inverse=True
-                    )
-                seg_ids = np.asarray(seg_ids).ravel()
-                if self.key_cols:
-                    out_keys = []
-                    # one column per flat key word (struct children ride
-                    # as separate words under the flat layout)
-                    key_arrays = [
-                        uniq[:, j] for j in range(uniq.shape[1])
-                    ]
-                else:
-                    out_keys = [() for _ in range(len(uniq))]
-                n_keys = len(uniq)
+        with timeline.phase("close.take", key=end) as ph:
+            take_arrays = getattr(self.dir, "take_bin_arrays", None)
+            if take_arrays is not None:
+                fk_cols, freed = take_arrays(lo_bin)
+                if len(freed):
+                    key_chunks.append(np.stack(fk_cols, axis=1))
+                    slot_chunks.append(freed)
             else:
-                index: Dict[tuple, int] = {}
-                seg = np.empty(len(all_slots), dtype=np.int64)
-                i = 0
-                for chunk in key_chunks:
-                    for key in chunk:
-                        seg[i] = index.setdefault(key, len(index))
-                        i += 1
-                seg_ids = seg
-                out_keys = list(index.keys())
-                n_keys = len(index)
-            combined = self.acc.combine_for_segments_and_free(
-                all_slots, seg_ids, n_keys, free_n=len(freed)
-            )
-            agg_cols = self.acc.finalize(combined)
-            out_batch = self._build_output(
-                out_keys, agg_cols, end - self.width, end,
-                key_arrays=key_arrays,
-            )
-            await collector.collect(out_batch)
+                fk, freed = self.dir.take_bin(lo_bin)
+                if len(freed):
+                    key_chunks.append(fk)
+                    slot_chunks.append(freed)
+            multi = getattr(self.dir, "bin_entries_multi", None)
+            if multi is not None:
+                # native directories: ONE batched crossing covering every
+                # participating bin (the merge unions keys across bins, so
+                # per-bin identity is irrelevant) instead of k get_bin
+                # calls — k x shards calls on the mesh facade
+                kmat, slots_m = multi(
+                    np.arange(lo_bin + 1, end_bin, dtype=np.int64)
+                )
+                if len(slots_m):
+                    key_chunks.append(kmat)
+                    slot_chunks.append(slots_m)
+            else:
+                for b in range(lo_bin + 1, end_bin):
+                    keys_b, slots_b = self.dir.bin_entries(b)
+                    if len(slots_b):
+                        key_chunks.append(keys_b)
+                        slot_chunks.append(slots_b)
+            if slot_chunks:
+                all_slots = np.concatenate(slot_chunks)
+                ph.n = len(all_slots)
+        if slot_chunks:
+            with timeline.phase("close.union", key=end) as ph:
+                seg_ids, out_keys, key_arrays, n_keys = self._key_union(
+                    key_chunks, len(all_slots))
+                ph.n = n_keys
+            with timeline.phase("close.combine", key=end,
+                                n=len(all_slots)):
+                combined = self.acc.combine_for_segments_and_free(
+                    all_slots, seg_ids, n_keys, free_n=len(freed)
+                )
+            with timeline.phase("close.finalize", key=end, n=n_keys):
+                agg_cols = self.acc.finalize(combined)
+            with timeline.phase("close.build", key=end, n=n_keys):
+                out_batch = self._build_output(
+                    out_keys, agg_cols, end - self.width, end,
+                    key_arrays=key_arrays,
+                )
+            with timeline.phase("close.emit", key=end,
+                                n=out_batch.num_rows, annotate=False):
+                await collector.collect(out_batch)
         self.last_freed_bin = max(self.last_freed_bin or lo_bin, lo_bin)
+
+    def _key_union(self, key_chunks: list, n_slots: int):
+        """Union of the participating bins' keys: (segment id per slot,
+        output keys as tuples, output key columns, distinct keys)."""
+        key_arrays = None
+        if isinstance(key_chunks[0], np.ndarray):
+            # native path: vectorized key-union over int64 key matrices
+            # (count, n_keycols); keys stay numpy end-to-end (no python
+            # tuple per key)
+            all_keys = np.concatenate(key_chunks)
+            if all_keys.shape[1] == 1:
+                # 1-D unique is markedly faster than axis=0
+                u1, seg_ids = np.unique(
+                    all_keys[:, 0], return_inverse=True
+                )
+                uniq = u1[:, None]
+            else:
+                uniq, seg_ids = np.unique(
+                    all_keys, axis=0, return_inverse=True
+                )
+            seg_ids = np.asarray(seg_ids).ravel()
+            if self.key_cols:
+                out_keys = []
+                # one column per flat key word (struct children ride
+                # as separate words under the flat layout)
+                key_arrays = [
+                    uniq[:, j] for j in range(uniq.shape[1])
+                ]
+            else:
+                out_keys = [() for _ in range(len(uniq))]
+            return seg_ids, out_keys, key_arrays, len(uniq)
+        index: Dict[tuple, int] = {}
+        seg = np.empty(n_slots, dtype=np.int64)
+        i = 0
+        for chunk in key_chunks:
+            for key in chunk:
+                seg[i] = index.setdefault(key, len(index))
+                i += 1
+        return seg, list(index.keys()), key_arrays, len(index)
 
 
 def _tolist(col) -> list:

@@ -28,6 +28,7 @@ import numpy as np
 
 from ..config import config
 from ..obs import device as obs_device
+from ..obs import timeline
 
 # jax import deferred so host-only deployments can import the module tree
 from ._jax import get_jax as _get_jax
@@ -462,34 +463,40 @@ class Accumulator:
         if n == 0:
             return
         self._check_signed(signs)
-        self._update_host(slots, cols, signs)
-        if not self.phys:
+        if self.backend == "numpy" or not self.phys:
+            # the host tier: the whole update is host work
+            with timeline.phase("agg.host", n=n):
+                self._update_host(slots, cols, signs)
+                if self.phys:
+                    self._np_update(slots, cols, signs)
             return
-        if self.backend == "numpy":
-            self._np_update(slots, cols, signs)
-            return
-        jnp = _get_jax().numpy
-        padded = _bucket(n, self._buckets)
-        slots_p = np.full(padded, self.capacity - 1, dtype=np.int64)
-        slots_p[:n] = slots
-        valid = np.zeros(padded, dtype=np.int64)
-        valid[:n] = 1 if signs is None else signs
-        inputs = []
-        for op, dt, src, si in self.phys:
-            spec = self.specs[si]
-            if src == "one":
-                vals = valid
-            else:
-                vals = np.zeros(padded, dtype=self._dt(dt))
-                base = _src_values(spec, src, cols)
-                vals[:n] = base if signs is None else base * signs
-                if op != "add":
-                    vals[n:] = self._neutral(op, dt)
-            inputs.append(jnp.asarray(vals))
+        with timeline.phase("agg.pack", n=n):
+            self._update_host(slots, cols, signs)
+            jnp = _get_jax().numpy
+            padded = _bucket(n, self._buckets)
+            slots_p = np.full(padded, self.capacity - 1, dtype=np.int64)
+            slots_p[:n] = slots
+            valid = np.zeros(padded, dtype=np.int64)
+            valid[:n] = 1 if signs is None else signs
+            inputs = []
+            for op, dt, src, si in self.phys:
+                spec = self.specs[si]
+                if src == "one":
+                    vals = valid
+                else:
+                    vals = np.zeros(padded, dtype=self._dt(dt))
+                    base = _src_values(spec, src, cols)
+                    vals[:n] = base if signs is None else base * signs
+                    if op != "add":
+                        vals[n:] = self._neutral(op, dt)
+                inputs.append(jnp.asarray(vals))
+            slots_d = jnp.asarray(slots_p)
         obs_device.note_padding("agg.update", padded, n, padded)
-        self.state = self._update_fn(
-            self.state, jnp.asarray(slots_p), *inputs, rung=padded
-        )
+        # the host side of the call alone: the device runs it later
+        with timeline.phase("agg.enqueue"):
+            self.state = self._update_fn(
+                self.state, slots_d, *inputs, rung=padded, rows=n
+            )
 
     def _check_signed(self, signs: Optional[np.ndarray]):
         if signs is not None and (
@@ -630,15 +637,20 @@ class Accumulator:
             return [s[slots] for s in self.state]
         jnp = _get_jax().numpy
         padded = _bucket(len(slots), self._buckets)
-        slots_p = np.full(padded, self.capacity - 1, dtype=np.int64)
-        slots_p[: len(slots)] = slots
         obs_device.note_padding("agg.gather", padded, len(slots), padded)
-        outs = self._gather_fn(
-            self.state, jnp.asarray(slots_p), rung=padded
-        )
+        # sub-steps of the caller's close.combine, in the ledger only
+        with timeline.phase("agg.gather", annotate=False):
+            slots_p = np.full(padded, self.capacity - 1, dtype=np.int64)
+            slots_p[: len(slots)] = slots
+            outs = self._gather_fn(
+                self.state, jnp.asarray(slots_p), rung=padded,
+                rows=len(slots)
+            )
         if not materialize:
             return [o[: len(slots)] for o in outs]
-        return [np.asarray(o)[: len(slots)] for o in outs]
+        # the device-to-host read: waits for every program queued before it
+        with timeline.phase("agg.read", n=len(slots), annotate=False):
+            return [np.asarray(o)[: len(slots)] for o in outs]
 
     def _make_gather_fn(self):
         jax = _get_jax()
@@ -674,6 +686,10 @@ class Accumulator:
             for (op, dt, _, _), s in zip(self.phys, self.state):
                 s[slots] = self._neutral(op, dt)
             return
+        with timeline.phase("agg.reset", annotate=False):
+            self._reset_device(slots)
+
+    def _reset_device(self, slots: np.ndarray):
         jnp = _get_jax().numpy
         padded = _bucket(len(slots), self._buckets)
         slots_p = np.full(padded, self.capacity - 1, dtype=np.int64)
@@ -692,7 +708,7 @@ class Accumulator:
 
             self._reset_fn = obs_device.InstrumentedJit("agg.reset", reset)
         self.state = self._reset_fn(
-            self.state, jnp.asarray(slots_p), rung=padded
+            self.state, jnp.asarray(slots_p), rung=padded, rows=len(slots)
         )
 
     # -- finalize -----------------------------------------------------------

@@ -98,7 +98,7 @@ def _build_fns():
     # cache of jitted closures instead of a traced argument
     phase2_cache = {}
 
-    def phase2_at(size, order, lo, offs):
+    def phase2_at(size, order, lo, offs, rows=None):
         fn = phase2_cache.get(size)
         if fn is None:
             def impl(order, lo, offs, _size=size):
@@ -117,7 +117,7 @@ def _build_fns():
                 "join.phase2", jax.jit(impl)
             )
             phase2_cache[size] = fn
-        return fn(order, lo, offs, rung=size)
+        return fn(order, lo, offs, rung=size, rows=rows)
 
     from ..obs import device as obs_device
 
@@ -154,28 +154,37 @@ def probe(
     phase1, phase2_at = _build_fns()
     from ..obs import device as obs_device
 
+    from ..obs import timeline
+
     lb, rb = _bucket(n_l), _bucket(n_r)
     obs_device.note_padding("join.phase1", rb, n_l + n_r, lb + rb)
-    l_mat = _pad_matrix(lcols, lb)
-    r_mat = _pad_matrix(rcols, rb)
-    order, lo, offs = phase1(
-        l_mat, r_mat, np.int64(n_l), np.int64(n_r), rung=rb
-    )
-    total = int(offs[-1])
+    # sub-steps of the caller's join.probe, in the ledger only
+    with timeline.phase("join.probe.count", annotate=False):
+        l_mat = _pad_matrix(lcols, lb)
+        r_mat = _pad_matrix(rcols, rb)
+        # rows= the build side's: the side whose padding `rung` names
+        order, lo, offs = phase1(
+            l_mat, r_mat, np.int64(n_l), np.int64(n_r), rung=rb, rows=n_r
+        )
+        # the scalar crosses to the host: waits for phase 1 on the device
+        total = int(offs[-1])
     if total == 0:
         e = np.empty(0, dtype=np.int64)
         return e, e
-    li, ri, valid = phase2_at(_bucket(total), order, lo, offs)
-    li = np.asarray(li)
-    ri = np.asarray(ri)
-    mask = np.asarray(valid) & (li < n_l) & (ri < n_r)
-    li = li[mask]
-    ri = ri[mask]
-    # exact verification of hash-equal candidates on the real key words
-    keep = np.ones(len(li), dtype=bool)
-    for lc, rc in zip(lcols, rcols):
-        keep &= lc[li] == rc[ri]
-    return li[keep], ri[keep]
+    with timeline.phase("join.probe.expand", annotate=False):
+        li, ri, valid = phase2_at(_bucket(total), order, lo, offs, total)
+        li = np.asarray(li)
+        ri = np.asarray(ri)
+        valid = np.asarray(valid)
+    with timeline.phase("join.probe.verify", n=total, annotate=False):
+        mask = valid & (li < n_l) & (ri < n_r)
+        li = li[mask]
+        ri = ri[mask]
+        # exact verification of hash-equal candidates on the real key words
+        keep = np.ones(len(li), dtype=bool)
+        for lc, rc in zip(lcols, rcols):
+            keep &= lc[li] == rc[ri]
+        return li[keep], ri[keep]
 
 
 def _codable(t) -> bool:

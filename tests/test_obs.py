@@ -501,8 +501,9 @@ def test_timeline_ring_bounded_and_phase_totals():
         for i in range(40):
             timeline.note("process", 0.001, job="ring", task="1-0")
         assert len(timeline.snapshot()) == 16
+        # the totals come from the bucket store, which outlives the ring
         totals = timeline.phase_totals("ring")
-        assert totals["process"]["count"] == 16
+        assert totals["process"]["count"] == 40
     with update(obs={"timeline_events": 0}):
         before = len(timeline.snapshot())
         timeline.note("process", 0.001, job="ring")

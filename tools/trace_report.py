@@ -122,7 +122,7 @@ def tree_stats(spans: List[dict]) -> dict:
     }
 
 
-def summarize(events: List[dict], out=sys.stdout) -> None:
+def summarize(events: List[dict], out=None) -> None:
     chaos_fires = [
         ev for ev in events
         if ev.get("ph") == "i" and ev.get("name", "").startswith("chaos.fire")
@@ -145,7 +145,7 @@ def summarize(events: List[dict], out=sys.stdout) -> None:
               f"{ev.get('args')}", file=out)
 
 
-def latency_summary(report: dict, out=sys.stdout) -> None:
+def latency_summary(report: dict, out=None) -> None:
     """Pretty-print one /debug/latency (or REST /jobs/{id}/latency) dump:
     per-operator + end-to-end marker quantiles, per-program XLA compile/
     dispatch stats, padding waste per rung, and the recompile-cause log."""
@@ -195,7 +195,7 @@ def latency_summary(report: dict, out=sys.stdout) -> None:
                   file=out)
 
 
-def doctor_summary(events: List[dict], job_id: str, out=sys.stdout) -> int:
+def doctor_summary(events: List[dict], job_id: str, out=None) -> int:
     """Offline bottleneck doctor: reconstruct signals from a dump's
     phase.* events and render the ranked verdict. Returns 0 when a
     verdict could be produced, 1 when the dump carries no phase ledger
@@ -228,7 +228,7 @@ def doctor_summary(events: List[dict], job_id: str, out=sys.stdout) -> int:
     return 0
 
 
-def audit_report(paths: List[str], out=sys.stdout) -> int:
+def audit_report(paths: List[str], out=None) -> int:
     """Offline conservation reconciliation (ISSUE 19). Accepts two
     artifact shapes per input file:
 

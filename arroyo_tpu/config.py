@@ -249,9 +249,12 @@ class ServeConfig:
 
     # master switch: off = no views are staged at operators, the
     # QueryState rpc answers "serving disabled", and the REST state
-    # routes return 404s. Staging cost when on is one dict write per
-    # emitted aggregate row (measured in the serve bench scenario's
-    # pipeline-impact key).
+    # routes return 404s. Staging cost when on: one list append per
+    # emitted window batch (the Arrow batch is kept as a segment) and
+    # one vectorised keep-last-per-key per checkpoint capture; a Python
+    # object per row only for the rows a read returns, and for rows the
+    # operator stages one at a time (updating aggregates, join row
+    # sets, session partials). PERF.md §5 has the measured shares.
     enabled: bool = True
     # controller-side read-through cache budget in bytes (approximate,
     # LRU by insertion); entries are keyed (job, table, key) and valid

@@ -395,8 +395,9 @@ class ControllerServer:
         """Admin surface: serve-gateway status (cache occupancy, tenant
         quotas + noisy flags, slowest read over the decaying
         serve.slow_read_window); `?job=<id>` adds the job's table
-        registry + published epoch, `?clear=1` empties the slow-read
-        window after reporting it."""
+        registry, published epoch and per-view occupancy (with the rows
+        staged and the rows materialised), `?clear=1` empties the
+        slow-read window after reporting it."""
         from aiohttp import web
 
         doc = self.serve.status()
@@ -412,6 +413,7 @@ class ControllerServer:
                 "published_epoch": job.published_epoch,
                 "schedules": job.schedules,
                 "tables": await self.serve.tables(jid),
+                "views": await self.serve.view_stats(jid),
             }
         return web.json_response(
             doc, dumps=lambda d: json.dumps(d, default=str)

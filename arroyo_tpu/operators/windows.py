@@ -1712,7 +1712,7 @@ class SessionWindowOperator(WindowOperatorBase):
             if view.has_staged(k):
                 continue  # a final landed this interval; keep it
             if view.live_mode:
-                v = view.served.get(k)
+                v = view.read(k, None)[1]
                 if not (isinstance(v, dict) and v.get("partial")):
                     continue
             view.stage_tomb(k)

@@ -8,7 +8,9 @@ manifest) unions ALL subtasks' chains because the follower's TaskInfo
 claims parallelism 1, and `tail_chains` replays only the delta-chain
 suffix per publish, at delta cost through the shared chain cache.
 
-Views are rebuilt from the mirrored rows after every restore/tail and
+Views are rebuilt from the mirrored entries (one segment per sealed epoch
+of a routable view, one entry per key otherwise: `serve/store.py`
+`seed_from_mirror`) after every restore/tail and
 stamped with the manifest epoch they reflect; `read` serves from them
 without touching the compiled program, the workers, or the job's
 generation. The `__serve_meta__` record carries the WORKER-side
@@ -18,10 +20,15 @@ it for worker-ward fallback routing unchanged.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from ..analysis.model.effects import protocol_effect
-from ..serve.store import META_KEY, SERVE_TABLE, ServeView
+from ..serve.store import (
+    META_KEY,
+    SERVE_TABLE,
+    ServeView,
+    seed_from_mirror,
+)
 from ..state import protocol
 from ..state.backend import StateBackend
 from ..state.table_config import global_table
@@ -170,12 +177,7 @@ class Follower:
                 value_names=list(desc["value_fields"]),
                 kind=desc["kind"], live_mode=False,
             )
-            served: Dict[Tuple, Any] = {}
-            for k, v in table.items():
-                if k == META_KEY or not isinstance(k, tuple):
-                    continue
-                served[k] = v
-            view.served = served
+            seed_from_mirror(view, table)
             view.served_epoch = mount.epoch
             views[f"{name}@{node_id}"] = view
             if name in views:
@@ -194,7 +196,7 @@ class Follower:
                 jid: {
                     "epoch": m.epoch,
                     "tables": {
-                        name: len(v.served)
+                        name: v.stats()["keys"]
                         for name, v in m.views.items() if "@" not in name
                     },
                 }

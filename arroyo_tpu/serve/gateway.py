@@ -203,23 +203,37 @@ class StateGateway:
                 self._tables[job_id] = (job.schedules, meta)
                 return meta
         out: Dict[str, dict] = {}
-        ns = f"{job.job_id}@{job.schedules}"
-        for w in job.workers:
-            try:
-                SERVE_WORKER_RPCS.labels(job=job_id).inc()
-                resp = await self.controller._worker_call(
-                    w, "WorkerGrpc", "QueryState",
-                    {"job_id": job_id, "mode": "tables", "data_ns": ns},
-                    timeout=float(config().serve.read_timeout),
-                )
-            except Exception as e:  # noqa: BLE001 - worker may be dying
-                logger.debug("serve tables from worker %s failed: %s",
-                             w.worker_id, e)
-                continue
+        for resp in await self._ask_workers(job, "tables"):
             for d in resp.get("tables", []):
                 out.setdefault(d["table"], d)
         self._tables[job_id] = (job.schedules, out)
         return out
+
+    async def _ask_workers(self, job, mode: str) -> List[dict]:
+        """One QueryState of `mode` to each of the job's workers; a
+        worker that fails to answer (it may be dying) is left out."""
+        out = []
+        for w in job.workers:
+            try:
+                SERVE_WORKER_RPCS.labels(job=job.job_id).inc()
+                out.append(await self.controller._worker_call(
+                    w, "WorkerGrpc", "QueryState",
+                    {"job_id": job.job_id, "mode": mode,
+                     "data_ns": f"{job.job_id}@{job.schedules}"},
+                    timeout=float(config().serve.read_timeout),
+                ))
+            except Exception as e:  # noqa: BLE001 - worker may be dying
+                logger.debug("serve %s from worker %s failed: %s",
+                             mode, w.worker_id, e)
+        return out
+
+    async def view_stats(self, job_id: str) -> List[dict]:
+        """`ServeView.stats()` of every view the job's workers host
+        (`/debug/serve?job=`): occupancy, and how many rows the write
+        side was handed against how many became Python objects."""
+        return [v for resp in await self._ask_workers(
+                    self.controller.jobs[job_id], "stats")
+                for v in resp.get("views", [])]
 
     def _worker_for(self, job, node_id: int, subtask: int):
         wid = job.assignments.get((node_id, subtask))

@@ -297,6 +297,279 @@ def test_session_partial_tomb_never_clobbers_final():
     assert v2.read((8,), 1) == (False, None)        # stale partial gone
 
 
+# -- the mirror: segments through a real chain (ISSUE 25) --------------------
+
+
+def _hop_batch(rng, keys, stamp):
+    import numpy as np
+    import pyarrow as pa
+
+    n = len(keys)
+    end = pa.array(np.full(n, stamp * 2_000_000), pa.timestamp("us"))
+    return pa.RecordBatch.from_pydict({
+        "auction": pa.array(np.asarray(keys, dtype=np.int64)),
+        "window": pa.StructArray.from_arrays([end, end], ["start", "end"]),
+        "count": pa.array(rng.integers(1, 900, n)),
+        "_timestamp": end,
+    })
+
+
+class _Worker:
+    """One subtask of a viewed hop operator with its `__serve__` table
+    and the chain of blobs its captures gave."""
+
+    def __init__(self, task_index, parallelism, chains=()):
+        from types import SimpleNamespace
+
+        import pyarrow as pa
+
+        from arroyo_tpu.operators.windows import WindowOperatorBase
+        from arroyo_tpu.serve.store import SERVE_TABLE, register_op
+        from arroyo_tpu.state.table_config import global_table
+        from arroyo_tpu.state.tables import GlobalTable
+        from arroyo_tpu.types import TaskInfo
+
+        self.table = GlobalTable(global_table(SERVE_TABLE))
+        for chain in chains:  # a restore unions every subtask's chain
+            self.table.load_chain(chain)
+        self.tm = SimpleNamespace(tables={SERVE_TABLE: self.table})
+        self.op = WindowOperatorBase.__new__(WindowOperatorBase)
+        self.op.name = "hop"
+        self.op._key_names = ["auction"]
+        self.op.out_schema = SimpleNamespace(schema=pa.schema([
+            ("auction", pa.int64()),
+            ("window", pa.struct([("start", pa.timestamp("us")),
+                                  ("end", pa.timestamp("us"))])),
+            ("count", pa.int64()),
+            ("_timestamp", pa.timestamp("us")),
+        ]))
+        ctx = SimpleNamespace(
+            task_info=TaskInfo("j", 3, "hop", task_index, parallelism),
+            table_manager=self.tm)
+        self.view = register_op(self.op, ctx)  # restore seeding runs here
+        self.chain = []
+
+    def capture(self, epoch):
+        from arroyo_tpu.serve.store import seal_op
+
+        seal_op(self.op, epoch, self.tm)
+        blob, is_base = self.table.serialize_delta(epoch)
+        if blob is not None:
+            self.chain = [blob] if is_base else self.chain + [blob]
+
+
+def _follower_view(chains, epoch):
+    """The view `Follower._refresh_views` builds from the union of the
+    subtasks' chains at a published epoch."""
+    from types import SimpleNamespace
+
+    from arroyo_tpu.replica.follower import Follower, _Mount
+    from arroyo_tpu.serve.store import SERVE_TABLE
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.tables import GlobalTable
+
+    table = GlobalTable(global_table(SERVE_TABLE))
+    for chain in chains:
+        table.load_chain(chain)
+    mount = _Mount(None)
+    mount.tms[(3, 0)] = SimpleNamespace(tables={SERVE_TABLE: table})
+    mount.epoch = epoch
+    Follower(0)._refresh_views("j", mount)
+    return mount.views["hop"]
+
+
+@pytest.mark.parametrize("restore_parallelism", [1, 4])
+def test_mirror_round_trip_follower_and_restore(restore_parallelism):
+    """Seal on two worker subtasks, capture through real GlobalTable
+    chains, then a follower (`_refresh_views`) and a restore at another
+    parallelism (`register_op`, the owner filter) read back exactly
+    what the workers serve at that epoch; the restored subtasks go on,
+    and a second follower over THEIR chains still agrees."""
+    import numpy as np
+
+    from arroyo_tpu.serve.store import owner_subtask, stage_batch
+
+    rng = np.random.default_rng(25)
+    keys = list(range(5_000, 5_400))
+
+    def run_epochs(workers, epochs, stamp0):
+        p = len(workers)
+        for epoch in epochs:
+            for close in range(3):
+                hit = rng.choice(keys, 150, replace=False)
+                for w in workers:
+                    mine = [k for k in hit if owner_subtask(
+                        (int(k),), ("i",), p) == w.view.task_index]
+                    if mine:
+                        stage_batch(w.view, _hop_batch(
+                            rng, mine, stamp0 + epoch * 3 + close))
+            for w in workers:
+                w.capture(epoch)
+
+    def served(workers, epoch):
+        p = len(workers)
+        return {k: workers[owner_subtask((k,), ("i",), p)].view.read(
+            (k,), epoch) for k in keys + [1, 9_999]}
+
+    old = [_Worker(i, 2) for i in range(2)]
+    run_epochs(old, (1, 2, 3), 0)
+    want = served(old, 3)
+    assert sum(f for f, _ in want.values()) > 300
+    # one entry per sealed epoch beside the meta record, not one per key
+    assert all(len(w.table.data) == 4 for w in old)
+    chains = [w.chain for w in old]
+    fview = _follower_view(chains, 3)
+    assert {k: fview.read((k,), 3) for k in want} == want
+
+    new = [_Worker(i, restore_parallelism, chains)
+           for i in range(restore_parallelism)]
+    assert served(new, 3) == want
+    for w in new:  # each holds what it owns, and only that
+        owned = sum(f and owner_subtask((k,), ("i",), restore_parallelism)
+                    == w.view.task_index for k, (f, _) in want.items())
+        assert w.view.stats()["keys"] == owned
+    run_epochs(new, (4, 5), 100)
+    want5 = served(new, 5)
+    assert want5 != want
+    fview = _follower_view([w.chain for w in new], 5)
+    assert {k: fview.read((k,), 5) for k in want5} == want5
+    # a follower that had mounted the old chains and tails the new ones
+    # applies their tombstones to what the restore replaced
+    fview = _follower_view(chains + [w.chain for w in new], 5)
+    assert {k: fview.read((k,), 5) for k in want5} == want5
+
+
+def test_mirror_restores_a_chain_in_the_per_key_format():
+    """`tests/data/serve_chain_pr24.msgpack` holds three blobs that PR
+    24's code wrote (one `__serve__` entry per key: a hop view's finals
+    through `stage_batch`, two retractions in epoch 3) and the reads
+    PR 24's view answered at epoch 3. A follower and a restore rebuild
+    the same reads from it; the restored worker's first sealed batch
+    folds the entries into a segment, and wins over them."""
+    import os
+
+    import msgpack
+    import numpy as np
+
+    rec = msgpack.unpackb(
+        open(os.path.join(os.path.dirname(__file__), "data",
+                          "serve_chain_pr24.msgpack"), "rb").read(),
+        raw=False, strict_map_key=False)
+    want = {(int(k),): v for k, v in rec["reads"].items()}
+    assert sum(v is not None for v in want.values()) > 100
+
+    def reads(view, epoch):
+        return {k: (view.read(k, epoch)[1] if view.read(k, epoch)[0]
+                    else None) for k in want}
+
+    assert reads(_follower_view([rec["blobs"]], 3), 3) == want
+    w = _Worker(0, 1, [rec["blobs"]])
+    assert reads(w.view, 3) == want
+    # the entries per key stay as they are until the view is handed its
+    # first batch; the capture that mirrors it folds them into the
+    # oldest segment and deletes them through the table's tombstones
+    from arroyo_tpu.serve.store import stage_batch
+    assert sum(isinstance(k, tuple) for k, _ in w.table.items()) == 115
+    stage_batch(w.view, _hop_batch(np.random.default_rng(1), [1001, 1003],
+                                   77))
+    w.capture(4)
+    assert sorted(dict(w.table.items())) == [
+        "__serve_meta__", "__serve_seg__/0/0/0", "__serve_seg__/0/4/0"]
+    want4 = dict(reads(w.view, 4))
+    assert want4[(1001,)]["window"]["end"] == 154_000_000_000
+    assert {k: v for k, v in want4.items() if k not in ((1001,), (1003,))} \
+        == {k: v for k, v in want.items() if k not in ((1001,), (1003,))}
+    # a follower still holding the old blobs tails the new base
+    fview = _follower_view([rec["blobs"], w.chain], 4)
+    assert reads(fview, 4) == want4
+    assert fview.describe()["table"] == "hop"
+
+
+@pytest.mark.parametrize("first", ["rows", "batch"])
+def test_mirror_orders_rows_batches_and_retractions(first):
+    """A session-like view: partials and retractions staged a row at a
+    time, finals as batches, in one sequence. Whatever shape came first
+    (entries per key until the first batch, segments from then on), a
+    follower over the chain answers as the worker does after every
+    epoch, past the merge of the oldest segments (a retraction merged
+    into the oldest segment must not bring back what it retracted)."""
+    import numpy as np
+
+    from arroyo_tpu.serve.store import stage_batch
+
+    rng = np.random.default_rng(3 + (first == "rows"))
+    w = _Worker(0, 1)
+    keys = list(range(40))
+    blobs = []
+    for epoch in range(1, 61):
+        shapes = ["row", "tomb", "batch", "partial"]
+        if epoch <= 3:  # the first epochs hold one shape only
+            shapes = ["row", "tomb"] if first == "rows" else ["batch"]
+        for _ in range(int(rng.integers(1, 6))):
+            shape = rng.choice(shapes)
+            hit = [int(k) for k in rng.choice(keys, 5, replace=False)]
+            if shape == "row":
+                for k in hit:
+                    w.view.stage((k,), {"count": epoch, "partial": True})
+            elif shape == "tomb":
+                for k in hit:
+                    w.view.stage_tomb((k,))
+            else:
+                stage_batch(w.view, _hop_batch(rng, hit, epoch),
+                            partial=(shape == "partial"))
+        w.capture(epoch)
+        blobs.append(w.chain[-1])
+        fview = _follower_view([blobs], epoch)
+        for k in keys:
+            assert fview.read((k,), epoch) == w.view.read((k,), epoch), (
+                epoch, k)
+        assert fview.stats()["keys"] == w.view.stats()["keys"]
+    assert not any(isinstance(k, tuple) for k, _ in w.table.items())
+
+
+def test_mirror_segment_merging_deletes_what_it_merged():
+    """200 epochs of recurring keys: the worker's `__serve__` table and
+    a table that replays its chain both stay at a bounded number of
+    entries (the oldest segments merge and the merged-away ones leave
+    through the table's tombstones), and the reads stay exact."""
+    import numpy as np
+
+    from arroyo_tpu.serve.store import (
+        _MIRROR_SEGMENTS,
+        SERVE_TABLE,
+        seal_op,
+        stage_batch,
+    )
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.tables import GlobalTable
+
+    rng = np.random.default_rng(7)
+    w = _Worker(0, 1)
+    last = {}
+    blobs = []
+    for epoch in range(1, 201):
+        hit = rng.choice(np.arange(300), 40, replace=False)
+        b = _hop_batch(rng, hit, epoch)
+        stage_batch(w.view, b)
+        for k, c in zip(hit.tolist(), b.column(2).to_pylist()):
+            last[k] = c
+        seal_op(w.op, epoch, w.tm)
+        # every delta of the chain, merged-away entries' tombstones too
+        blobs.append(w.table.serialize_delta(epoch)[0])
+        assert len(w.table.data) <= _MIRROR_SEGMENTS + 1, epoch
+    replay = GlobalTable(global_table(SERVE_TABLE))
+    replay.load_chain(blobs)
+    assert len(dict(replay.items())) <= _MIRROR_SEGMENTS + 1
+    assert sorted(dict(replay.items())) == sorted(dict(w.table.items()))
+    fview = _follower_view([blobs], 200)
+    for k in range(300):
+        want = (True, last[k]) if k in last else (False, None)
+        got = w.view.read((k,), 200)
+        assert (got[0], got[1] and got[1]["count"]) == want
+        assert fview.read((k,), 200) == got
+    assert fview.stats()["keys"] == w.view.stats()["keys"] == len(last)
+
+
 # -- end to end: follower-first serving, kill, reattach ----------------------
 
 

@@ -99,6 +99,238 @@ def test_view_live_mode_serves_latest():
     assert v.read((1,), None) == (False, None)
 
 
+# -- the oracle: the dict-based view as a plain model (ISSUE 25) -------------
+
+
+class _ModelView:
+    """The view as it was before ISSUE 25, kept plain: one dict per
+    layer, one Python key tuple and value dict per emitted row. The
+    columnar view must answer exactly as this does."""
+
+    _TOMB = object()
+
+    def __init__(self, key_names, key_kinds, value_names, live_mode,
+                 max_pending):
+        self.key_names, self.key_kinds = key_names, key_kinds
+        self.value_names, self.live_mode = value_names, live_mode
+        self.max_pending = max_pending
+        self.served, self.pending, self._stage = {}, {}, {}
+
+    def stage(self, key, value):
+        (self.served if self.live_mode else self._stage)[key] = value
+
+    def stage_tomb(self, key):
+        if self.live_mode:
+            self.served.pop(key, None)
+        else:
+            self._stage[key] = self._TOMB
+
+    def has_staged(self, key):
+        return key in self._stage
+
+    def stage_batch(self, batch, partial=False):
+        from arroyo_tpu.serve.store import _plain
+
+        staged = []
+        for row in batch.to_pylist():
+            key = tuple(canon_value(row[n], k)
+                        for n, k in zip(self.key_names, self.key_kinds))
+            value = {n: _plain(row[n]) for n in self.value_names
+                     if n in row}
+            if partial:
+                value["partial"] = True
+            self.stage(key, value)
+            staged.append(key)
+        return staged
+
+    def _fold_one(self, epoch):
+        for k, v in self.pending.pop(epoch).items():
+            if v is self._TOMB:
+                self.served.pop(k, None)
+            else:
+                self.served[k] = v
+
+    def seal(self, epoch):
+        if not self._stage:
+            return
+        self.pending.setdefault(epoch, {}).update(self._stage)
+        self._stage = {}
+        while len(self.pending) > self.max_pending:
+            self._fold_one(min(self.pending))
+
+    def read(self, key, epoch):
+        if epoch is not None and not self.live_mode:
+            for e in sorted(self.pending):
+                if e > epoch:
+                    break
+                self._fold_one(e)
+        if key in self.served:
+            return True, self.served[key]
+        return False, None
+
+
+def _oracle_columns(case, rng, n):
+    """(key_names, key_kinds, {name: arrow array}) of `n` rows for one
+    case: few distinct keys, so rows collide within a batch, across
+    batches and across epochs."""
+    import pyarrow as pa
+
+    small = rng.integers(0, 12, n)
+    if case == "u":
+        return ["k"], ("u",), {"k": pa.array(
+            (small.astype(np.uint64) + np.uint64(2**63)), pa.uint64())}
+    if case == "f":
+        return ["k"], ("f",), {"k": pa.array(small / 4.0 - 1.0)}
+    if case == "s":
+        return ["k"], ("s",), {"k": pa.array([f"key-{x}" for x in small])}
+    if case == "two":
+        return ["k", "k2"], ("i", "s"), {
+            "k": pa.array((small % 4).astype(np.int32)),
+            "k2": pa.array([f"b{x // 4}" for x in small])}
+    if case == "ts":
+        return ["k"], ("i",), {"k": pa.array(
+            small.astype(np.int64) * 1_000_000 + 1_700_000_000_000_000,
+            pa.timestamp("us"))}
+    if case == "struct":  # the window-only grouping's key
+        st = pa.array(small.astype(np.int64) * 2_000_000,
+                      pa.timestamp("us"))
+        en = pa.array((small.astype(np.int64) + 5) * 2_000_000,
+                      pa.timestamp("us"))
+        return ["k"], ("o",), {"k": pa.StructArray.from_arrays(
+            [st, en], ["start", "end"])}
+    if case == "list":  # no per-column form: the row-wise entry
+        return ["k"], ("o",), {"k": pa.array(
+            [[int(x), 7] for x in small], pa.list_(pa.int64()))}
+    return ["k"], ("i",), {"k": pa.array(small.astype(np.int64) - 3)}
+
+
+_ORACLE_CASES = ["i", "u", "f", "s", "two", "ts", "window", "one_row",
+                 "empty", "struct", "list"]
+
+
+@pytest.mark.parametrize("live_mode", [False, True])
+@pytest.mark.parametrize("case", _ORACLE_CASES)
+def test_columnar_view_answers_as_the_dict_model(case, live_mode):
+    """Random seeded sequences of stage_batch (finals and partials),
+    stage, stage_tomb, seal (now and then twice under one epoch),
+    reads at published epochs (which fold), pending-cap folding, the
+    served compaction and live mode: the columnar view and the plain
+    dict model answer identically, key for key, after every step."""
+    import pyarrow as pa
+
+    from arroyo_tpu.serve.store import stage_batch
+
+    rng = np.random.default_rng(
+        25_000 + 2 * _ORACLE_CASES.index(case) + live_mode)
+    rows_of = {"one_row": lambda: 1, "empty": lambda: int(rng.integers(0, 2))}
+    rows_of = rows_of.get(case, lambda: int(rng.integers(1, 30)))
+
+    def batch():
+        n = rows_of()
+        key_names, key_kinds, cols = _oracle_columns(case, rng, n)
+        start = pa.array(rng.integers(0, 50, n) * 2_000_000,
+                         pa.timestamp("us"))
+        cols["window"] = pa.StructArray.from_arrays(
+            [start, start], ["start", "end"])
+        cols["cnt"] = pa.array(rng.integers(0, 1000, n))
+        cols["_timestamp"] = start  # not a value column
+        return key_names, key_kinds, pa.RecordBatch.from_pydict(cols)
+
+    key_names, key_kinds, _ = batch()
+    value_names = ["window", "cnt"] if case == "window" else ["cnt"]
+    with update(serve={"max_pending_epochs": 3}):
+        view = _view(key_names=key_names, key_kinds=key_kinds,
+                     value_names=value_names, live_mode=live_mode)
+    model = _ModelView(key_names, key_kinds, value_names, live_mode, 3)
+    universe, epoch, published = set(), 0, 0
+
+    def check():
+        at = None if live_mode else published
+        for k in sorted(universe, key=repr):
+            assert view.read(k, at) == model.read(k, at), (k, at)
+            assert view.has_staged(k) == model.has_staged(k), k
+        assert view.stats()["keys"] == len(model.served)
+
+    for _step in range(160):
+        op = rng.choice(["final", "final", "partial", "stage", "tomb",
+                         "seal", "publish"])
+        if op in ("final", "partial"):
+            _, _, b = batch()
+            got = stage_batch(view, b, partial=(op == "partial"))
+            want = model.stage_batch(b, partial=(op == "partial"))
+            universe.update(want)
+            if op == "partial":
+                assert got == want
+        elif op in ("stage", "tomb") and universe:
+            k = sorted(universe, key=repr)[
+                int(rng.integers(0, len(universe)))]
+            if op == "stage":
+                v = {"cnt": int(rng.integers(0, 1000))}
+                view.stage(k, v)
+                model.stage(k, dict(v))
+            else:
+                view.stage_tomb(k)
+                model.stage_tomb(k)
+        elif op == "seal" and not live_mode:
+            epoch += int(rng.integers(0, 4) > 0)  # 1 in 4: the same epoch
+            if epoch:
+                view.seal(epoch)
+                model.seal(epoch)
+        elif op == "publish":
+            published = int(rng.integers(published, epoch + 1))
+        check()
+    assert universe or case == "empty"
+
+
+def test_write_path_builds_no_python_rows():
+    """ISSUE 25's guard, by count and not by clock: closes, a seal and
+    a capture with no read turn no row into Python objects, the ledger
+    never books `serve.materialize`, and `k` point reads materialise at
+    most `k` rows (an int key: the index is two numpy arrays)."""
+    import pyarrow as pa
+
+    from arroyo_tpu.obs import timeline
+    from arroyo_tpu.serve.store import SERVE_TABLE, seal_op, stage_batch
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.tables import GlobalTable
+
+    timeline.clear()
+    view = _view(key_names=["auction"], value_names=["window", "cnt"])
+    op = type("Op", (), {"_serve_view": view})()
+    tm = type("TM", (), {"tables": {
+        SERVE_TABLE: GlobalTable(global_table(SERVE_TABLE))}})()
+    n, closes = 50_000, 6
+    for c in range(closes):
+        end = pa.array(np.full(n, 2_000_000 * (c + 5)), pa.timestamp("us"))
+        stage_batch(view, pa.RecordBatch.from_pydict({
+            "auction": pa.array(np.arange(n, dtype=np.int64) + 1_000 * c),
+            "window": pa.StructArray.from_arrays([end, end],
+                                                 ["start", "end"]),
+            "cnt": pa.array(np.full(n, c, dtype=np.int64)),
+        }))
+    seal_op(op, 1, tm)
+    blob, is_base = tm.tables[SERVE_TABLE].serialize_delta(1)
+    assert is_base and len(blob) > n * 8
+    # one entry for the epoch beside the meta record, not one per key
+    assert len(tm.tables[SERVE_TABLE].data) == 2
+    totals = timeline.totals()
+    assert view.staged_rows == n * closes
+    assert view.materialized_rows == 0
+    assert "serve.materialize" not in totals
+    assert totals["serve.seal"]["n"] == n * closes
+    assert totals["serve.mirror"]["n"] == n + 1_000 * (closes - 1)
+    k = 7
+    for key in range(2_000, 2_000 + k):
+        found, value = view.read((key,), 1)
+        assert found and value["cnt"] == min(key // 1_000, closes - 1)
+        assert value["window"]["end"] == 2_000_000_000 * (value["cnt"] + 5)
+    assert view.read((-1,), 1) == (False, None)
+    st = view.stats()
+    assert st["materialized_rows"] == k and st["staged_rows"] == n * closes
+    assert st["keys"] == n + 1_000 * (closes - 1)
+    assert timeline.totals()["serve.materialize"]["n"] == k
+
+
 def test_model_faithful_reader_clean_and_mutant_caught():
     """The PR 9 checker with the reader actor: faithful model explores
     exhaustively clean with reads enabled; the mutant's counterexample
@@ -353,6 +585,16 @@ def test_e2e_point_bulk_rest_and_fencing(tmp_path):
                 )
                 assert "stale_route" in resp.get("error", "")
                 assert resp.get("retriable") is True
+                # /debug/serve?job= (ISSUE 25): per-view occupancy with
+                # the write side's two counts — every emitted row went
+                # in as part of a batch, and only rows that reads
+                # returned became Python objects
+                views = await c.serve.view_stats("sv")
+                assert views and all(
+                    v["table"] == "tumbling_window" for v in views)
+                staged = sum(v["staged_rows"] for v in views)
+                made = sum(v["materialized_rows"] for v in views)
+                assert staged >= 8 and 0 < made <= 64, views
                 # GC on stop: cache + routing state expunged, serve
                 # series dropped with the job's metrics
                 assert c.serve.cache.data

@@ -156,11 +156,12 @@ class ApiServer:
         job = self.controller.jobs.get(jid)
         last = None
         while job is not None and not job.state.is_terminal():
+            seen = job.kicks
             if job.state.value != last:
                 last = job.state.value
                 self.db.update_job(jid, last, job.restarts)
                 self.db.set_pipeline_state(pid, last)
-            await job.wait_kick(self.controller.wheel, 30.0)
+            await job.wait_kick(self.controller.wheel, 30.0, seen)
         if job is not None:
             self.db.update_job(jid, job.state.value, job.restarts)
             self.db.set_pipeline_state(pid, job.state.value)

@@ -231,19 +231,31 @@ class JobHandle:
         # predicates kicks them. `wakeups` counts predicate-loop wakeups —
         # the fleet harness and the parked-job regression test read it (a
         # parked RUNNING job must sit at ZERO over a poll interval).
+        # `kicks` is the generation of those events: a predicate loop
+        # reads it BEFORE it evaluates its predicates and hands it to
+        # `wait_kick`, so an event that arrived while the loop was
+        # awaiting something else (no wait parked) is not lost.
         self._waiters: set = set()
+        self.kicks = 0
         self.wakeups = 0
 
     def kick(self):
         """Wake every parked wait of this job (an event arrived)."""
+        self.kicks += 1
         for fut in list(self._waiters):
             if not fut.done():
                 fut.set_result(True)
 
-    async def wait_kick(self, wheel: TimerWheel,
-                        timeout: Optional[float]) -> bool:
+    async def wait_kick(self, wheel: TimerWheel, timeout: Optional[float],
+                        seen: int) -> bool:
         """Park until kicked or until the coarse deadline passes. Returns
-        True when kicked (state possibly changed), False on deadline."""
+        True when kicked (state possibly changed), False on deadline.
+        `seen` is `kicks` as the caller read it before evaluating the
+        predicates it is about to park on: a kick since then returns at
+        once."""
+        if seen != self.kicks:
+            self.wakeups += 1
+            return True
         fut = asyncio.get_event_loop().create_future()
         self._waiters.add(fut)
         if timeout is not None:
@@ -734,6 +746,7 @@ class ControllerServer:
         deadline = time.monotonic() + timeout
         job = self.jobs[job_id]
         while job.state not in states:
+            seen = job.kicks
             remaining = deadline - time.monotonic()
             if remaining <= 0:
                 raise TimeoutError(
@@ -741,7 +754,7 @@ class ControllerServer:
                 )
             # parked on the job's kick list: transition() wakes us, the
             # wheel bounds the wait — zero wakeups while nothing changes
-            await job.wait_kick(self.wheel, remaining)
+            await job.wait_kick(self.wheel, remaining, seen)
         return job.state
 
     # -- worker pool --------------------------------------------------------
@@ -1102,6 +1115,9 @@ class ControllerServer:
         leader_mode = cfg.controller.job_controller_mode == "worker"
         last_checkpoint = time.monotonic()
         while True:
+            # events from here on (a TaskFinished that lands while an
+            # epoch is being published below) end the park at once
+            seen = job.kicks
             if job.failure is not None:
                 # hot-standby failover (ISSUE 17): a task failure while
                 # RUNNING (worker death surfaces as peer connection
@@ -1289,7 +1305,8 @@ class ControllerServer:
                 # wake at the backoff horizon so re-arming isn't starved
                 deadlines.append(rearm_at)
             await job.wait_kick(
-                self.wheel, max(min(deadlines) - time.monotonic(), 0.0)
+                self.wheel, max(min(deadlines) - time.monotonic(), 0.0),
+                seen,
             )
 
     @protocol_effect("ctrl.failover_promote")
@@ -1671,6 +1688,7 @@ class ControllerServer:
         rescale and recovery paths stay strictly drained, exactly as the
         single-inflight design behaved."""
         while job.pending_epochs and job.failure is None:
+            seen = job.kicks
             if self._heartbeat_expired(job):
                 job.failure = "worker heartbeat timeout"
                 return
@@ -1684,7 +1702,7 @@ class ControllerServer:
                     + [self._heartbeat_horizon(job)]
                 )
                 await job.wait_kick(
-                    self.wheel, max(deadline - time.monotonic(), 0.0)
+                    self.wheel, max(deadline - time.monotonic(), 0.0), seen
                 )
 
     async def _fanout_barrier(self, job: JobHandle, epoch: int,
@@ -1734,6 +1752,7 @@ class ControllerServer:
         deadline = time.monotonic() + 60
         with obs.span("await_reports", cat="controller") as wait_span:
             while len(job.checkpoints.get(epoch, {})) < job.n_subtasks:
+                seen = job.kicks
                 if job.failure is not None or time.monotonic() > deadline:
                     logger.warning("checkpoint %d incomplete", epoch)
                     wait_span.set(outcome="incomplete")
@@ -1766,7 +1785,7 @@ class ControllerServer:
                     return
                 park = min(deadline, self._heartbeat_horizon(job))
                 await job.wait_kick(
-                    self.wheel, max(park - time.monotonic(), 0.0)
+                    self.wheel, max(park - time.monotonic(), 0.0), seen
                 )
         await self._publish_epoch(job, epoch, job.checkpoints[epoch])
 
@@ -1871,6 +1890,7 @@ class ControllerServer:
         want = job.n_subtasks if expected is None else expected
         deadline = time.monotonic() + timeout
         while len(job.finished_tasks) < want:
+            seen = job.kicks
             if time.monotonic() > deadline:
                 logger.warning("job %s: tasks did not finish in time",
                                job.job_id)
@@ -1885,7 +1905,7 @@ class ControllerServer:
             # arrivals wake us; the wheel covers the deadline + liveness
             park = min(deadline, self._heartbeat_horizon(job))
             await job.wait_kick(self.wheel,
-                                max(park - time.monotonic(), 0.0))
+                                max(park - time.monotonic(), 0.0), seen)
 
     @protocol_effect("ctrl.recover")
     async def _recover(self, job: JobHandle, n_workers: int):

@@ -190,6 +190,7 @@ class DataPlaneServer:
         # (quads collide across multiplexed jobs)
         self.routes: Dict[tuple, BatchQueue] = {}
         self._server: Optional[asyncio.AbstractServer] = None
+        self._handlers: set = set()  # the open connections' tasks
 
     def register(self, quad: Quad, queue: BatchQueue, ns: str = ""):
         self.routes[(ns, quad)] = queue
@@ -216,6 +217,8 @@ class DataPlaneServer:
         peer = writer.get_extra_info("peername")
         lat_handles: Dict[Quad, object] = {}
         ns = ""  # bound by the connection's hello frame
+        me = asyncio.current_task()
+        self._handlers.add(me)
         try:
             while True:
                 quad, kind, item, sent_ns, trace = await read_frame(reader)
@@ -267,11 +270,17 @@ class DataPlaneServer:
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:
+            self._handlers.discard(me)
             writer.close()
 
     async def stop(self):
         if self._server is not None:
             self._server.close()
+            # `wait_closed` waits for every connection's handler (Python
+            # 3.12), and one blocked on the full queue of a torn-down
+            # incarnation, which nobody drains, never returns by itself
+            for handler in list(self._handlers):
+                handler.cancel()
             await self._server.wait_closed()
 
 

@@ -589,7 +589,10 @@ class SubtaskRunner:
         if self._audit_on:
             tap = self._rx_taps[i]
             if tap is not None:
-                tap.observe(item)
+                with obs.timeline.phase(
+                        "audit.attest", task=self.task_info.task_id,
+                        n=item.num_rows):
+                    tap.observe(item)
             self._op_counts[0][0] += item.num_rows
         t0 = time.perf_counter()
         anchor = obs.device.anchor(

@@ -325,8 +325,12 @@ class WindowOperatorBase(Operator):
         operators: (bin, key) -> slot, room for the new slots, the dirty
         marks of an incremental checkpoint, then the accumulator's scatter.
         Each step is a leaf of the phase ledger."""
+        live = self.dir.n_live
         with timeline.phase("dir.assign", n=len(bins)):
             slots = self.dir.assign(bins, keys)
+        # a count, no duration: the slots this call created (`assign`
+        # frees none), beside the rows `dir.assign` books
+        timeline.note("dir.new", 0.0, n=self.dir.n_live - live)
         self._ensure_capacity()
         if ctx.table_manager is not None and self._use_incremental():
             with timeline.phase("win.dirty", n=len(slots)):
@@ -455,6 +459,8 @@ class WindowOperatorBase(Operator):
         slots, bins, key_cols = self._coalesce_dirty()
         self._dirty_chunks = []
         self._dirty_rows = self._dirty_base = 0
+        # a count, no duration: the rows this capture's delta carries
+        timeline.note("ckpt.delta", 0.0, n=len(slots))
         values = self.acc.snapshot(slots, materialize=False)
 
         def build() -> pa.RecordBatch:

@@ -269,6 +269,42 @@ def test_real_and_padded_rows_are_booked_at_the_device_boundary():
         + t["agg.reset"]["total_s"] + 1e-3)
 
 
+def test_the_audit_fingerprint_of_a_sent_batch_is_a_leaf_inside_emit():
+    """`audit.attest` (ISSUE 26): the conservation ledger's fingerprint of
+    a batch, booked where a sender attests it (`EdgeSender._send_data`,
+    inside `emit`; the receiver books the same name before `process`), so
+    that `emit`'s self time is partition + put again."""
+    import asyncio
+
+    import pyarrow as pa
+
+    from arroyo_tpu.graph.logical import EdgeType
+    from arroyo_tpu.operators.collector import Collector, EdgeSender
+    from arroyo_tpu.operators.queues import BatchQueue
+    from arroyo_tpu.schema import StreamSchema
+
+    schema = StreamSchema.from_fields([("k", pa.int64())])
+    batch = pa.RecordBatch.from_arrays(
+        [pa.array(range(100)), pa.array([0] * 100, pa.timestamp("ns"))],
+        schema=schema.schema)
+
+    async def send():
+        queues = [BatchQueue(8, 1 << 20), BatchQueue(8, 1 << 20)]
+        queues[0].audit_edge = "1:0->2:0"   # the wiring stamped one of two
+        out = Collector([EdgeSender(EdgeType.FORWARD, schema, [q])
+                         for q in queues], task_id="1-0", job_id="att")
+        await out.collect(batch)
+
+    asyncio.run(send())
+    t = timeline.phase_totals()     # the job is the ambient one: none here
+    assert t["audit.attest"]["count"] == 1 and t["audit.attest"]["n"] == 100
+    assert t["emit"]["n"] == 100
+    assert t["emit"]["self_s"] == pytest.approx(
+        t["emit"]["total_s"] - t["audit.attest"]["total_s"], abs=3e-6)
+    by_task = timeline.totals(task="1-0")
+    assert by_task["audit.attest"]["count"] == 1     # the sender's task
+
+
 def test_the_jitted_function_names_the_trace_reduction_maps():
     """`benchmark/trace_reduce.py` finds the device programs by the module
     names XLA derives from these four functions' names: a rename here

@@ -39,6 +39,17 @@ Rows emitted after the last sealed barrier (the trailing segment before
 EndOfData) are unattested symmetrically on both sides — no attestation
 is ever compared against a partial peer, so a clean run is audit-silent
 by construction.
+
+One fingerprint per batch object (ISSUE 27). `batch_fingerprint` is a pure
+function of an immutable Arrow batch, so every tap that observes the SAME
+OBJECT takes one computation: the edges of a fan-out, and both ends of an
+in-process queue. The memo is keyed on the object's identity, never on its
+content, schema or size: a batch that equals another but is another object
+is computed. A receiver of a remote edge decodes a new object from the IPC
+frame, so it always computes, and a torn, altered or duplicated frame is
+seen as before. Every tap still books every observation: what an
+in-process receiver no longer does is hash again the bytes of an object
+that cannot have changed since its sender hashed them.
 """
 
 from __future__ import annotations
@@ -46,11 +57,15 @@ from __future__ import annotations
 import logging
 import threading
 import time
+import weakref
 from collections import deque
+from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 import pyarrow as pa
+
+from . import timeline
 
 logger = logging.getLogger(__name__)
 
@@ -197,6 +212,47 @@ def batch_fingerprint(batch: pa.RecordBatch) -> Tuple[int, int]:
         return n, int(_splitmix64(acc).sum(dtype=np.uint64))
 
 
+# id(batch) -> (weakref to the batch, rows, digest): one entry per LIVE
+# batch object a tap has observed. `pa.RecordBatch` takes weak references
+# and is not hashable, so a WeakKeyDictionary will not do. The reference's
+# callback drops the entry when the batch dies (before its id can be given
+# to a new object), so the memo never outgrows the batches in the queues.
+_FP_MEMO: Dict[int, Tuple[weakref.ref, int, int]] = {}
+_FP_LOCK = threading.Lock()  # the two totals; the memo needs none
+_FP_OBSERVED = 0
+_FP_COMPUTED = 0
+
+
+def _forget(key: int, ref: weakref.ref, memo=_FP_MEMO) -> None:
+    """A memoised batch died: drop its entry, unless the key is already
+    another object's. (`memo` is bound here so that a batch dying while
+    the interpreter shuts down still finds it.)"""
+    cur = memo.get(key)
+    if cur is not None and cur[0] is ref:
+        del memo[key]
+
+
+def _fingerprint_once(batch: pa.RecordBatch) -> Tuple[int, int]:
+    """`batch_fingerprint(batch)`, computed once per batch OBJECT. A hit
+    needs `ref() is batch`: an id can be reused once its object is gone.
+    Each computation books the count `audit.fp` inside the caller's
+    `audit.attest`: reuse share = 1 - count(audit.fp) / count(audit.attest)."""
+    global _FP_OBSERVED, _FP_COMPUTED
+    key = id(batch)
+    hit = _FP_MEMO.get(key)
+    computed = hit is None or hit[0]() is not batch
+    if computed:
+        n, d = batch_fingerprint(batch)
+        _FP_MEMO[key] = (weakref.ref(batch, partial(_forget, key)), n, d)
+        timeline.note("audit.fp", 0.0, n=n)
+    else:
+        _, n, d = hit
+    with _FP_LOCK:
+        _FP_OBSERVED += 1
+        _FP_COMPUTED += computed
+    return n, d
+
+
 class EdgeTap:
     """Running attestation for ONE direction of ONE edge, sealed per
     epoch when the barrier passes. The sender seals every output tap at
@@ -213,7 +269,7 @@ class EdgeTap:
         self.sealed: Dict[int, Tuple[int, int]] = {}
 
     def observe(self, batch: pa.RecordBatch) -> None:
-        n, d = batch_fingerprint(batch)
+        n, d = _fingerprint_once(batch)
         if n:
             self.rows += n
             self.digest = (self.digest + d) % _MOD
@@ -513,8 +569,14 @@ def status(job_id: Optional[str] = None) -> dict:
     if job_id is not None:
         r = recs.get(job_id)
         return r.status() if r is not None else {"job": job_id}
+    with _FP_LOCK:
+        observed, computed = _FP_OBSERVED, _FP_COMPUTED
     return {
         "enabled": enabled(),
+        # process-wide: observations of a batch by a tap, and how many of
+        # them computed a fingerprint (the rest took the batch object's)
+        "fingerprints_observed": observed,
+        "fingerprints_computed": computed,
         "jobs": {jid: r.status() for jid, r in recs.items()},
     }
 
@@ -528,10 +590,14 @@ def expunge_job(job_id: str) -> None:
 
 
 def reset() -> None:
-    """Test hygiene: drop all reconcilers AND the breach ring."""
-    global _SEQ
+    """Test hygiene: drop all reconcilers, the breach ring AND the
+    fingerprint memo with its totals."""
+    global _SEQ, _FP_OBSERVED, _FP_COMPUTED
     with _REG_LOCK:
         _RECONCILERS.clear()
+    _FP_MEMO.clear()
+    with _FP_LOCK:
+        _FP_OBSERVED = _FP_COMPUTED = 0
     with _RING_LOCK:
         _RING.clear()
         _SEQ = 0

@@ -123,6 +123,187 @@ def test_edge_tap_split_vs_whole_attestation_matches():
     assert whole.sealed[1] == split.sealed[1]
 
 
+# -- one fingerprint per batch object (ISSUE 27) -----------------------------
+
+# (rows, digest) of fixed one-column batches and of all columns together,
+# written here from the function as it stood before the memo: the memo must
+# hand on the same numbers, and a later change of the hash shows here
+_FIXED = {
+    "ints": pa.array([1, -2, 3, 2**40, 0], pa.int64()),
+    "floats": pa.array([1.5, -0.0, 0.0, 3.25e10, -7.125], pa.float64()),
+    "strings": pa.array(["a", "", "bid", "channel-7", "a"], pa.string()),
+    "nulls": pa.array([None, 4, None, 6, 7], pa.int64()),
+    "null_strings": pa.array(["x", None, "y", None, ""], pa.string()),
+    "struct": pa.array(
+        [{"s": 1, "e": "p"}, {"s": 2, "e": None}, {"s": 3, "e": "q"},
+         {"s": 4, "e": "r"}, {"s": 5, "e": "p"}],
+        pa.struct([("s", pa.int64()), ("e", pa.string())])),
+    "list": pa.array([[1, 2], [], None, [3], [2, 1]], pa.list_(pa.int64())),
+}
+_FIXED_DIGESTS = {
+    "ints": 1191521663248509046,
+    "floats": 7871750446412350719,
+    "strings": 3236795247269411933,
+    "nulls": 10014177305726986819,
+    "null_strings": 9781650425886190462,
+    "struct": 12137543665217278257,
+    "list": 18358363235676902031,
+    "all": 10000658056212722291,
+}
+
+
+def _fixed_batch(which):
+    names = list(_FIXED) if which == "all" else [which]
+    return pa.RecordBatch.from_arrays([_FIXED[n] for n in names], names=names)
+
+
+def _fp_totals():
+    s = audit.status()
+    return s["fingerprints_observed"], s["fingerprints_computed"]
+
+
+@pytest.mark.parametrize("which", sorted(_FIXED_DIGESTS))
+def test_the_digest_of_a_fixed_batch_is_what_it_was(which):
+    batch = _fixed_batch(which)
+    assert audit.batch_fingerprint(batch) == (5, _FIXED_DIGESTS[which])
+    tap = audit.EdgeTap("1:0->2:0")
+    tap.observe(batch)          # computes
+    tap.observe(batch)          # takes the object's
+    assert (tap.rows, tap.digest) == (
+        10, 2 * _FIXED_DIGESTS[which] % MOD)
+
+
+def test_one_object_seen_by_two_taps_is_computed_once_and_booked_twice():
+    batch = _fixed_batch("all")
+    want = audit.batch_fingerprint(batch)
+    tx, rx = audit.EdgeTap("1:0->2:0"), audit.EdgeTap("1:0->5:0")
+    tx.observe(batch)
+    rx.observe(batch)
+    assert _fp_totals() == (2, 1)
+    assert (tx.rows, tx.digest) == (rx.rows, rx.digest) == want
+    # every observation is booked, whoever computed: a second delivery of
+    # the same object doubles the receiver's attestation
+    rx.observe(batch)
+    assert _fp_totals() == (3, 1)
+    assert (rx.rows, rx.digest) == (10, 2 * want[1] % MOD)
+    assert (tx.rows, tx.digest) == want
+
+
+def _built_again(batch):
+    return pa.RecordBatch.from_arrays(batch.columns, schema=batch.schema)
+
+
+def _ipc_round_trip(batch):
+    sink = pa.BufferOutputStream()
+    with pa.ipc.new_stream(sink, batch.schema) as w:
+        w.write_batch(batch)
+    return pa.ipc.open_stream(sink.getvalue()).read_next_batch()
+
+
+@pytest.mark.parametrize("copy", [
+    _built_again, lambda b: b.slice(0), _ipc_round_trip,
+], ids=["from_arrays", "slice0", "ipc"])
+def test_an_equal_batch_that_is_another_object_is_computed(copy):
+    """Identity, not content: what a remote receiver decodes from a frame
+    is a new object, and is hashed like any batch never seen."""
+    batch = _fixed_batch("all")
+    other = copy(batch)
+    assert other is not batch and other.equals(batch)
+    sender, receiver = audit.EdgeTap("e"), audit.EdgeTap("e")
+    sender.observe(batch)
+    receiver.observe(other)
+    assert _fp_totals() == (2, 2)
+    assert (receiver.rows, receiver.digest) == (sender.rows, sender.digest)
+
+
+def test_a_memo_entry_lives_as_long_as_its_batch():
+    tap = audit.EdgeTap("e")
+    batch = _batch([1, 2, 3])
+    rows, digest = audit.batch_fingerprint(batch)
+    tap.observe(batch)
+    assert list(audit._FP_MEMO) == [id(batch)]
+    del batch
+    assert audit._FP_MEMO == {}
+    # 10,000 short-lived batches, each another object (ids ARE reused
+    # here): every one is computed, none is kept
+    for i in range(10_000):
+        b = _batch([i, i + 1])
+        n, d = audit.batch_fingerprint(b)
+        rows, digest = rows + n, (digest + d) % MOD
+        tap.observe(b)
+        assert len(audit._FP_MEMO) == 1
+    del b
+    assert audit._FP_MEMO == {}
+    assert _fp_totals() == (10_001, 10_001)
+    assert (tap.rows, tap.digest) == (rows, digest)
+
+
+def test_a_reused_id_does_not_hit_and_reset_empties_the_memo():
+    tap = audit.EdgeTap("e")
+    old, new = _batch([1, 2, 3]), _batch([7, 8])
+    tap.observe(old)
+    # as if `new` had been given the id of a batch the memo still holds:
+    # the entry's reference is to another object
+    audit._FP_MEMO[id(new)] = audit._FP_MEMO[id(old)]
+    fresh = audit.EdgeTap("e")
+    fresh.observe(new)
+    assert (fresh.rows, fresh.digest) == audit.batch_fingerprint(new)
+    assert _fp_totals() == (2, 2)
+    assert audit._FP_MEMO[id(new)][0]() is new
+    audit.reset()
+    assert audit._FP_MEMO == {} and _fp_totals() == (0, 0)
+    fresh.observe(new)                  # computed again after a reset
+    assert _fp_totals() == (1, 1)
+
+
+def test_taps_on_many_threads_share_the_memo_and_lose_no_count():
+    """More threads than cores, a short switch interval: each thread's tap
+    observes the same 20 long-lived batches and 200 short-lived ones of
+    its own. Every tap holds exactly what fresh computations give, the
+    totals lose no update, and only the live batches stay in the memo."""
+    import sys
+    import threading
+
+    shared = [_batch([i, i + 1, i + 2]) for i in range(20)]
+    want_rows = want_digest = 0
+    for b in shared:
+        n, d = audit.batch_fingerprint(b)
+        want_rows, want_digest = want_rows + n, (want_digest + d) % MOD
+    own_n, own_d = audit.batch_fingerprint(_batch([7, 7]))
+    n_threads, n_own = 16, 200
+    taps = [audit.EdgeTap(f"e{i}") for i in range(n_threads)]
+    start = threading.Barrier(n_threads)
+
+    def work(tap):
+        start.wait(timeout=30)
+        for i in range(n_own):
+            tap.observe(_batch([7, 7]))
+            tap.observe(shared[i % len(shared)])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(t,)) for t in taps]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    reps = n_own // len(shared)
+    for tap in taps:
+        assert tap.rows == n_own * own_n + reps * want_rows
+        assert tap.digest == (n_own * own_d + reps * want_digest) % MOD
+    observed, computed = _fp_totals()
+    assert observed == n_threads * n_own * 2
+    # every short-lived batch is computed; a shared one at least once and
+    # at most once per thread (two threads may meet on its first sight)
+    own = n_threads * n_own
+    assert own + len(shared) <= computed <= own + n_threads * len(shared)
+    assert set(audit._FP_MEMO) == {id(b) for b in shared}
+
+
 def test_edge_key_shape():
     assert audit.edge_key("3", 0, "5", 1) == "3:0->5:1"
 

@@ -305,6 +305,50 @@ def test_the_audit_fingerprint_of_a_sent_batch_is_a_leaf_inside_emit():
     assert by_task["audit.attest"]["count"] == 1     # the sender's task
 
 
+def test_a_computed_fingerprint_is_a_count_inside_audit_attest():
+    """`audit.fp` (ISSUE 27): one `note` with no duration per fingerprint
+    actually computed, inside the `audit.attest` that observed the batch
+    (the sender's inside `emit`). A fan-out of one batch object to two
+    stamped edges is two observations and one computation; `audit.attest`
+    keeps its count, its rows and its seconds."""
+    import asyncio
+
+    import pyarrow as pa
+
+    from arroyo_tpu.graph.logical import EdgeType
+    from arroyo_tpu.obs import audit
+    from arroyo_tpu.operators.collector import Collector, EdgeSender
+    from arroyo_tpu.operators.queues import BatchQueue
+    from arroyo_tpu.schema import StreamSchema
+
+    schema = StreamSchema.from_fields([("k", pa.int64())])
+    batch = pa.RecordBatch.from_arrays(
+        [pa.array(range(100)), pa.array([0] * 100, pa.timestamp("ns"))],
+        schema=schema.schema)
+
+    async def send():
+        queues = [BatchQueue(8, 1 << 20), BatchQueue(8, 1 << 20)]
+        queues[0].audit_edge, queues[1].audit_edge = "1:0->2:0", "1:0->5:0"
+        out = Collector([EdgeSender(EdgeType.FORWARD, schema, [q])
+                         for q in queues], task_id="1-0", job_id="fp")
+        await out.collect(batch)
+        return [await q.recv() for q in queues]
+
+    got = asyncio.run(send())
+    assert got[0] is batch and got[1] is batch
+    t = timeline.totals(task="1-0")     # inherited from the enclosing `emit`
+    assert t["audit.attest"]["count"] == 2 and t["audit.attest"]["n"] == 200
+    assert t["audit.fp"]["count"] == 1 and t["audit.fp"]["n"] == 100
+    assert t["audit.fp"]["total_s"] == 0.0
+    # a count takes nothing from the self time of the phase around it
+    assert t["audit.attest"]["self_s"] == t["audit.attest"]["total_s"] > 0
+    assert t["emit"]["self_s"] == pytest.approx(
+        t["emit"]["total_s"] - t["audit.attest"]["total_s"], abs=3e-6)
+    status = audit.status()
+    assert (status["fingerprints_observed"],
+            status["fingerprints_computed"]) == (2, 1)
+
+
 def test_the_jitted_function_names_the_trace_reduction_maps():
     """`benchmark/trace_reduce.py` finds the device programs by the module
     names XLA derives from these four functions' names: a rename here

@@ -156,18 +156,6 @@ class TpuConfig:
     # run the join probe even without tpu.enabled (jax on CPU): lets the
     # bench measure the probe's cost model off-TPU
     device_join_force: bool = False
-    # device-resident (bin, key) -> slot group index (sorted hash table +
-    # jitted searchsorted, ops/device_directory.py): slot assignment
-    # stops round-tripping each batch's unique keys through a host hash
-    # table. Prototype tier — groups are identified by 64-bit hash
-    # (collision odds ~n^2/2^65), so off by default; host python/native
-    # C++ directories remain the exact fallbacks.
-    device_directory: bool = False
-    # runtime collision evidence for the device directory: sample found
-    # rows each assign and verify their key against the host bookkeeping
-    # (a detected 64-bit merge raises instead of corrupting aggregates);
-    # <=64 host tuple compares per batch
-    device_directory_audit: bool = False
 
 
 @dataclasses.dataclass

@@ -33,7 +33,8 @@ from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
 from ..schema import TIMESTAMP_FIELD, UPDATING_META_FIELD
 from .base import Operator
-from .windows import WindowOperatorBase, _is_interned_type, _to_py
+from ..ops.directory import _to_py
+from .windows import WindowOperatorBase
 
 
 class UpdatingAggregateOperator(WindowOperatorBase):
@@ -43,16 +44,6 @@ class UpdatingAggregateOperator(WindowOperatorBase):
     # in-step all_to_all (reference incremental_aggregator.rs:77-90 treats
     # the updating aggregate like any keyed operator)
     _mesh_ok = True
-    # the C++ directory now serves every API this operator needs
-    # (assign, slot-valued peek_bin, keys_for_slots via the native
-    # reverse index, items): ~3x cheaper per-batch assignment than the
-    # python np.unique path for int64-able keys
-    _native_ok = True
-    # the DEVICE directory grew the same surface in round 5 (slot-valued
-    # peek_bin, keys_for_slots, slots_for_keys, targeted remove) via its
-    # lazy host reverse index — steady-state assign stays a device
-    # searchsorted hit with zero host dict work
-    _device_ok = True
 
     def __init__(self, config: dict):
         super().__init__(config, "updating_aggregate")
@@ -121,7 +112,7 @@ class UpdatingAggregateOperator(WindowOperatorBase):
                 for i, (key_vals, vals) in enumerate(emitted_rows):
                     if mask is not None and not mask[i]:
                         continue
-                    self.emitted[self._intern_key(key_vals)] = vals
+                    self.emitted[self.codec.key(key_vals)] = vals
                 ls_rows = snap.get("last_seen", [])
                 ls_mask = (
                     self._range_mask([kv for kv, _ in ls_rows], ctx)
@@ -130,7 +121,7 @@ class UpdatingAggregateOperator(WindowOperatorBase):
                 for i, (key_vals, seen) in enumerate(ls_rows):
                     if ls_mask is not None and not ls_mask[i]:
                         continue
-                    self.last_seen[self._intern_key(key_vals)] = seen
+                    self.last_seen[self.codec.key(key_vals)] = seen
                 lv_rows = snap.get("live", [])
                 lv_mask = (
                     self._range_mask([kv for kv, _ in lv_rows], ctx)
@@ -139,7 +130,7 @@ class UpdatingAggregateOperator(WindowOperatorBase):
                 for i, (key_vals, cnt) in enumerate(lv_rows):
                     if lv_mask is not None and not lv_mask[i]:
                         continue
-                    self.live[self._intern_key(key_vals)] = cnt
+                    self.live[self.codec.key(key_vals)] = cnt
             await self._restore_updating_incremental(ctx)
         # everything restored must re-verify against emitted on next flush;
         # it is also checkpoint-dirty so a legacy full snapshot gets
@@ -171,16 +162,16 @@ class UpdatingAggregateOperator(WindowOperatorBase):
         snap = self._snapshot_rows()
         snap["subtask"] = ctx.task_info.task_index
         snap["emitted"] = [
-            [self._key_tuple_to_values(k), v]
+            [self.codec.values(k), v]
             for k, v in self.emitted.items()
         ]
         snap["last_seen"] = [
-            [self._key_tuple_to_values(k), v]
+            [self.codec.values(k), v]
             for k, v in self.last_seen.items()
         ]
         if self.retractable:
             snap["live"] = [
-                [self._key_tuple_to_values(k), v]
+                [self.codec.values(k), v]
                 for k, v in self.live.items()
             ]
         table.put(ctx.task_info.task_index, snap)
@@ -213,8 +204,8 @@ class UpdatingAggregateOperator(WindowOperatorBase):
         )
         arrays = [pa.array(ts)]
         names = ["__ts"]
-        key_rows = [tuple(self._key_tuple_to_values(k)) for k in all_keys]
-        for i, arr in enumerate(self._key_delta_arrays(key_rows)):
+        key_rows = [tuple(self.codec.values(k)) for k in all_keys]
+        for i, arr in enumerate(self.codec.delta_arrays_from_values(key_rows)):
             arrays.append(arr)
             names.append(f"__k{i}")
         for j in range(n_phys):
@@ -261,7 +252,7 @@ class UpdatingAggregateOperator(WindowOperatorBase):
         for b in table.all_batches():
             names = b.schema.names
             ts = np.asarray(b.column(names.index("__ts")))
-            key_cols = self._decode_delta_keys(b)
+            key_cols = self.codec.columns_from_delta(b)
             vals = [
                 np.asarray(b.column(names.index(f"__v{j}")))
                 for j in range(n_phys)
@@ -305,26 +296,18 @@ class UpdatingAggregateOperator(WindowOperatorBase):
             ctx,
         )
         for kv, (ts_, _, em, lv) in rows:
-            key = self._intern_key(list(kv))
+            key = self.codec.key(list(kv))
             self.last_seen[key] = ts_
             if em is not None:
                 self.emitted[key] = msgpack.unpackb(em, raw=False)
             if self.retractable:
                 self.live[key] = lv
 
-    def _intern_key(self, key_vals: list) -> tuple:
-        from ..ops.directory import intern_value
-
-        return tuple(
-            intern_value(v) if _is_interned_type(self._key_types[i]) else v
-            for i, v in enumerate(key_vals)
-        )
-
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
         self._capture_key_meta(ctx)
         ts = ctx.in_schemas[0].timestamps(batch)
         bins = np.zeros(batch.num_rows, dtype=np.int64)  # single bin
-        keys = self._key_arrays(batch)
+        keys = self.codec.columns(batch, self.key_cols)
         slots = self.dir.assign(bins, keys)
         self._ensure_capacity()
         signs = None
@@ -358,15 +341,9 @@ class UpdatingAggregateOperator(WindowOperatorBase):
 
     def _dirty_slot_map(self, key_set) -> dict:
         """slot per live key for the (usually small) dirty set — point
-        lookups, O(dirty), on every directory tier (python dict / native
-        C++ probe / device bin index / mesh per-shard dispatch); the
-        peek_bin fallback remains for any directory without the
-        point-lookup surface."""
-        lookup = getattr(self.dir, "slots_for_keys", None)
-        if lookup is not None:
-            return lookup(0, list(key_set))
-        bin_map = self.dir.peek_bin(0) or {}
-        return {k: bin_map[k] for k in key_set if k in bin_map}
+        lookups, O(dirty), on every table (python dict / native C++
+        probe / mesh per-shard dispatch)."""
+        return self.dir.slots_for_keys(0, list(key_set))
 
     async def handle_tick(self, tick, ctx, collector):
         await self._flush(ctx, collector)
@@ -444,13 +421,13 @@ class UpdatingAggregateOperator(WindowOperatorBase):
             view = self._serve_view
             for key, vals in zip(append_keys, append_vals):
                 view.stage(
-                    view.canon_key(self._key_tuple_to_values(key)),
+                    view.canon_key(self.codec.values(key)),
                     dict(zip(view.value_names, vals)),
                 )
             for key, old in zip(retract_keys, retract_vals):
                 if key not in self.emitted:  # final retraction (dead key)
                     view.stage_tomb(
-                        view.canon_key(self._key_tuple_to_values(key))
+                        view.canon_key(self.codec.values(key))
                     )
         if not retract_keys and not append_keys:
             return
@@ -470,8 +447,6 @@ class UpdatingAggregateOperator(WindowOperatorBase):
     def _build_updating(
         self, keys: List[tuple], vals: List[List], is_retract: bool, ts: int
     ) -> pa.RecordBatch:
-        from ..ops.directory import unintern_value
-
         n = len(keys)
         arrays = []
         for f in self.out_schema.schema:
@@ -484,19 +459,8 @@ class UpdatingAggregateOperator(WindowOperatorBase):
 
                 arrays.append(updating_meta_array(n, is_retract))
             elif f.name in (self._key_names or []):
-                ki = self._key_names.index(f.name)
-                kt = self._key_types[ki]
-                kv = [_to_py(k[ki]) for k in keys]
-                if _is_interned_type(kt):
-                    arrays.append(
-                        pa.array([unintern_value(v) for v in kv], type=kt)
-                    )
-                elif pa.types.is_unsigned_integer(kt):
-                    arrays.append(
-                        pa.array([v % (1 << 64) for v in kv], type=kt)
-                    )
-                else:
-                    arrays.append(pa.array(kv, type=kt))
+                arrays.append(self.codec.arrow_from_keys(
+                    self._key_names.index(f.name), keys))
             else:
                 ai = next(
                     j for j, s in enumerate(self.specs) if s.name == f.name

@@ -32,7 +32,7 @@ from ..ops.aggregates import (
     float_state_stays_on_host,
     make_accumulator,
 )
-from ..ops.directory import SlotDirectory, unintern_value
+from ..ops.directory import KeyCodec, _to_py, make_directory
 from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
 from ..utils.logging import get_logger
@@ -95,12 +95,7 @@ class WindowOperatorBase(Operator):
                 # off the mesh they keep their numpy accumulator
                 self.backend = self._offmesh_backend
         if mesh_n >= 2:
-            from ..parallel import (
-                MeshSlotDirectory,
-                ShardedAccumulator,
-                SharedMeshSlotDirectory,
-                key_mesh,
-            )
+            from ..parallel import ShardedAccumulator, key_mesh
 
             from ..config import config as config_fn
 
@@ -111,13 +106,15 @@ class WindowOperatorBase(Operator):
                 salted=salted,
                 flush_rows=config_fn().tpu.mesh_flush_rows,
             )
-            self.dir = (
-                SharedMeshSlotDirectory(mesh_n) if salted
-                else MeshSlotDirectory(mesh_n)
-            )
         else:
             self.acc = make_accumulator(self.specs, backend=self.backend)
-            self.dir = SlotDirectory()
+        self._mesh_n, self._salted = mesh_n, salted
+        # the (bin, key) -> slot table and the codec of its keys: both are
+        # settled by the key types, which arrive with the first schema
+        # (_capture_key_meta). A table that never sees a key needs none:
+        # sessions allocate slots from theirs before that.
+        self.dir = None if self._uses_assign else self._make_directory(None)
+        self.codec: Optional[KeyCodec] = None
         self._key_types: Optional[List[pa.DataType]] = None
         self._key_names: Optional[List[str]] = None
         # columnar chunks of (slots, bins, key columns) touched since the
@@ -133,23 +130,11 @@ class WindowOperatorBase(Operator):
         # touched every batch over a long checkpoint interval
         self._dirty_rows = 0
         self._dirty_base = 0
-        # native flat-key layout: when a struct key is flattened into its
-        # int64 child words for the native directory, _flat_widths[i] is
-        # the word count of key column i and _flat_offsets the prefix sums
-        self._flat_widths: Optional[List[int]] = None
-        self._flat_offsets: Optional[List[int]] = None
 
-    # operators that only use assign/take_bin/bin_entries/items can swap in
-    # the C++ directory for single-integer keys (tumbling, sliding, and —
-    # with the slot-valued peek_bin / keys_for_slots / remove surface —
-    # updating aggregates)
-    _native_ok = False
-    # the DEVICE directory now serves the full native surface (round 5:
-    # keys_for_slots, slots_for_keys, targeted remove, slot-valued
-    # peek_bin); the gate remains per-operator because the swap is only
-    # worthwhile where assignment is the hot path — session windows
-    # allocate slots imperatively and never call assign()
-    _device_ok = False
+    # whether rows reach their slots through the table's assign() (tumbling,
+    # sliding, updating aggregates); session windows allocate slots
+    # imperatively and keep their keys themselves
+    _uses_assign = True
     # operators whose state protocol is slot-based end to end can run on
     # the mesh-sharded accumulator (tumbling, sliding; session bookkeeping
     # allocates slots imperatively and stays host-side)
@@ -211,53 +196,25 @@ class WindowOperatorBase(Operator):
             return tier == "mesh"
         return not mesh_is_virtual(key_mesh(self._mesh_device_list(mesh_n)))
 
+    def _make_directory(self, key_types):
+        return make_directory(
+            key_types, mesh_shards=self._mesh_n, salted=self._salted,
+            uses_assign=self._uses_assign,
+        )
+
     def _capture_key_meta(self, ctx):
         if self._key_types is None:
             in_schema = ctx.in_schemas[0].schema
             self._key_types = [in_schema.field(i).type for i in self.key_cols]
             self._key_names = [in_schema.field(i).name for i in self.key_cols]
-            self._maybe_swap_mesh_native()
-            if (
-                self._native_ok
-                and isinstance(self.dir, SlotDirectory)
-                and self.dir.n_live == 0
-            ):
-                from ..config import config as config_fn
-                from ..ops.native import (
-                    NativeSlotDirectory,
-                    flat_key_widths,
-                    key_word_widths,
-                    load_native,
-                )
-
-                from ..ops._jax import device_tier_active
-
-                cfg = config_fn().tpu
-                use_device = (self._device_ok and device_tier_active()
-                              and cfg.device_directory)
-                widths = (
-                    key_word_widths(self._key_types) if use_device
-                    else flat_key_widths(self._key_types)
-                )
-                if widths is not None:
-                    # struct keys (window structs) flatten into their int64
-                    # child words; everything rides the flat N-key table
-                    self._set_flat_layout(widths)
-                    if use_device:
-                        from ..ops.device_directory import (
-                            DeviceSlotDirectory,
-                        )
-
-                        self.dir = DeviceSlotDirectory(n_keys=sum(widths))
-                    else:
-                        self.dir = NativeSlotDirectory(
-                            load_native(), n_keys=sum(widths)
-                        )
+            if self.dir is None:
+                self.dir = self._make_directory(self._key_types)
+            self.codec = KeyCodec(self._key_types, self.dir.key_encoding)
             self._log_tier()
 
     def _log_tier(self):
-        """The line a window operator logs once, when its first batch
-        has settled the directory: which tiers it took, on what
+        """The line a window operator logs once, when its key types
+        have settled the directory: which tiers it took, on what
         platform."""
         from ..ops import _jax
 
@@ -274,43 +231,6 @@ class WindowOperatorBase(Operator):
             self.name, acc.backend, note,
             type(self.dir).__name__, _jax.platform(), acc.capacity,
         )
-
-    def _set_flat_layout(self, widths: List[int]):
-        """Record the flat native key layout when struct keys flatten
-        into int64 child words (shared by the single-process swap and
-        the mesh per-shard swap — one definition, no drift)."""
-        if any(pa.types.is_struct(t) for t in self._key_types):
-            self._flat_widths = widths
-            self._flat_offsets = [0]
-            for w in widths:
-                self._flat_offsets.append(self._flat_offsets[-1] + w)
-
-    def _maybe_swap_mesh_native(self):
-        """Mesh mode: swap the facade's PYTHON directories (per-shard,
-        or the salted flat directory) to the native C++ table when the
-        operator's keys flatten to int64 words — the round-5 mesh
-        profile's largest host cost was the per-shard python assigns
-        plus tuple-per-key emission; the round-6 profile's was the
-        salted stage's per-row window-struct interning. Same eligibility
-        gate as the single-process native swap."""
-        from ..parallel.sharded_state import (
-            MeshSlotDirectory,
-            SharedMeshSlotDirectory,
-        )
-
-        if not (self._native_ok
-                and isinstance(self.dir, (MeshSlotDirectory,
-                                          SharedMeshSlotDirectory))
-                and self.dir.n_live == 0):
-            return
-        from ..ops.native import flat_key_widths, load_native
-
-        widths = flat_key_widths(self._key_types)
-        if widths is None:
-            return
-        if not self.dir.swap_to_native(load_native(), sum(widths)):
-            return
-        self._set_flat_layout(widths)
 
     def _ensure_capacity(self):
         need = self.dir.required_capacity()
@@ -392,47 +312,6 @@ class WindowOperatorBase(Operator):
         keep = len(slots) - 1 - idx_rev
         return slots[keep], bins[keep], [c[keep] for c in key_cols]
 
-    def _key_delta_cols(self, key_cols: List[np.ndarray]) -> List[pa.Array]:
-        """Columnar variant of _key_delta_arrays: key columns arrive as the
-        normalized numpy arrays _mark_dirty captured (object arrays for
-        interned types, int64-viewable otherwise)."""
-        out = []
-        for i, kt in enumerate(self._key_types):
-            c = key_cols[i]
-            if _is_interned_type(kt):
-                out.append(pa.array(c.tolist(), type=kt))
-            else:
-                out.append(pa.array(c.astype(np.int64, copy=False)))
-        return out
-
-    def _key_delta_arrays(self, key_rows: List[tuple]) -> List[pa.Array]:
-        """Portable key tuples -> one arrow array per key column (interned
-        types keep their values/types; the rest are int64 codes whose hash
-        matches the shuffle's)."""
-        out = []
-        for i, kt in enumerate(self._key_types):
-            vals = [k[i] for k in key_rows]
-            if _is_interned_type(kt):
-                out.append(pa.array(vals, type=kt))
-            else:
-                out.append(
-                    pa.array(np.asarray(vals, dtype=np.int64))
-                )
-        return out
-
-    def _decode_delta_keys(self, batch: pa.RecordBatch) -> List[np.ndarray]:
-        """__k* columns -> numpy arrays in the form _restore_rows expects
-        (object arrays for interned types, int64 codes otherwise)."""
-        names = batch.schema.names
-        out = []
-        for i, kt in enumerate(self._key_types):
-            col = batch.column(names.index(f"__k{i}"))
-            if _is_interned_type(kt):
-                out.append(np.array(col.to_pylist(), dtype=object))
-            else:
-                out.append(np.asarray(col.cast(pa.int64())))
-        return out
-
     def _use_incremental(self) -> bool:
         """Struct keys (window structs) hash differently in the parquet
         snapshot than on the shuffle, and host-state aggregates (UDAF
@@ -466,7 +345,7 @@ class WindowOperatorBase(Operator):
         def build() -> pa.RecordBatch:
             arrays = [pa.array(bin_ts(bins)), pa.array(bins)]
             names = ["__ts", "__bin"]
-            for i, arr in enumerate(self._key_delta_cols(key_cols)):
+            for i, arr in enumerate(self.codec.delta_arrays(key_cols)):
                 arrays.append(arr)
                 names.append(f"__k{i}")
             for j, v in enumerate(values):
@@ -499,7 +378,7 @@ class WindowOperatorBase(Operator):
         for b in table.all_batches():
             names = b.schema.names
             bins = np.asarray(b.column(names.index("__bin")))
-            key_cols = self._decode_delta_keys(b)
+            key_cols = self.codec.columns_from_delta(b)
             vals = [
                 np.asarray(b.column(names.index(f"__v{j}")))
                 for j in range(n_phys)
@@ -521,50 +400,6 @@ class WindowOperatorBase(Operator):
         )
         # conduit table: in-memory source of truth is the accumulator
         table.clear_batches()
-
-    def _key_arrays(self, batch: pa.RecordBatch) -> List[np.ndarray]:
-        out = []
-        for i in self.key_cols:
-            col = batch.column(i)
-            if pa.types.is_struct(col.type) and self._flat_widths is not None:
-                # native flat layout: struct children ride as separate
-                # int64 key words — no python tuple per row
-                for j in range(col.type.num_fields):
-                    out.append(
-                        np.asarray(col.field(j).cast(pa.int64()))
-                    )
-                continue
-            if pa.types.is_struct(col.type):
-                # struct keys (window structs) become tuples of child values;
-                # tuples are built per UNIQUE row (batches share few windows)
-                children = [
-                    np.asarray(col.field(j).cast(pa.int64()))
-                    if _is_temporal_or_int(col.type.field(j).type)
-                    else np.array(col.field(j).to_pylist(), dtype=object)
-                    for j in range(col.type.num_fields)
-                ]
-                if all(c.dtype != object for c in children):
-                    mat = np.stack(children, axis=1)
-                    uniq, inverse = np.unique(mat, axis=0, return_inverse=True)
-                    tuples = np.empty(len(uniq), dtype=object)
-                    tuples[:] = [tuple(int(x) for x in row) for row in uniq]
-                    out.append(tuples[inverse.ravel()])
-                else:
-                    out.append(
-                        np.fromiter(
-                            (tuple(int(c[r]) if isinstance(c[r], np.integer)
-                                   else c[r] for c in children)
-                             for r in range(batch.num_rows)),
-                            dtype=object,
-                            count=batch.num_rows,
-                        )
-                    )
-                continue
-            try:
-                out.append(col.to_numpy(zero_copy_only=False))
-            except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
-                out.append(np.array(col.to_pylist(), dtype=object))
-        return out
 
     def _agg_input_cols(self, batch: pa.RecordBatch) -> Dict:
         """Column arrays for the accumulator. Numeric (device-phys) specs
@@ -659,65 +494,11 @@ class WindowOperatorBase(Operator):
                 )
             elif f.name in (self._key_names or []):
                 ki = self._key_names.index(f.name)
-                kt = self._key_types[ki]
-                if key_arrays is not None:
-                    off = (self._flat_offsets[ki]
-                           if self._flat_offsets is not None else ki)
-                    if pa.types.is_struct(kt):
-                        # flat layout: regroup the struct's child words
-                        children = [
-                            pa.array(key_arrays[off + j]).cast(
-                                kt.field(j).type
-                            )
-                            for j in range(kt.num_fields)
-                        ]
-                        arrays.append(
-                            pa.StructArray.from_arrays(
-                                children,
-                                names=[kt.field(j).name
-                                       for j in range(kt.num_fields)],
-                            )
-                        )
-                    elif pa.types.is_unsigned_integer(kt):
-                        arrays.append(
-                            pa.array(key_arrays[off].view(np.uint64),
-                                     type=kt)
-                        )
-                    else:  # signed ints and timestamps cast directly
-                        arrays.append(pa.array(key_arrays[off]).cast(kt))
-                    continue
-                vals = [_to_py(k[ki]) for k in keys]
-                if pa.types.is_struct(kt):
-                    tuples = [unintern_value(v) for v in vals]
-                    children = [
-                        pa.array(
-                            [t[j] for t in tuples], type=pa.int64()
-                        ).cast(kt.field(j).type)
-                        if _is_temporal_or_int(kt.field(j).type)
-                        else pa.array([t[j] for t in tuples],
-                                      type=kt.field(j).type)
-                        for j in range(kt.num_fields)
-                    ]
-                    arrays.append(
-                        pa.StructArray.from_arrays(
-                            children,
-                            names=[kt.field(j).name
-                                   for j in range(kt.num_fields)],
-                        )
-                    )
-                elif _is_interned_type(kt):
-                    arrays.append(
-                        pa.array([unintern_value(v) for v in vals], type=kt)
-                    )
-                elif pa.types.is_unsigned_integer(kt):
-                    # directory codes are bit-preserving int64; normalize back
-                    arrays.append(
-                        pa.array([v % (1 << 64) for v in vals], type=kt)
-                    )
-                elif pa.types.is_timestamp(kt):
-                    arrays.append(pa.array(vals, type=pa.int64()).cast(kt))
-                else:
-                    arrays.append(pa.array(vals, type=kt))
+                arrays.append(
+                    self.codec.arrow_from_words(ki, key_arrays)
+                    if key_arrays is not None
+                    else self.codec.arrow_from_keys(ki, keys)
+                )
             else:
                 ai = next(
                     j for j, s in enumerate(self.specs) if s.name == f.name
@@ -750,28 +531,6 @@ class WindowOperatorBase(Operator):
 
     # -- checkpoint form ----------------------------------------------------
 
-    def _key_tuple_to_values(self, key: tuple) -> list:
-        """Directory key tuple (codes) -> portable key values."""
-        if self._flat_widths is not None:
-            # native flat layout: struct child words regroup into the
-            # portable tuple form (plain ints — nothing is interned here)
-            out = []
-            off = 0
-            for ki, w in enumerate(self._flat_widths):
-                if pa.types.is_struct(self._key_types[ki]):
-                    out.append(tuple(int(x) for x in key[off:off + w]))
-                else:
-                    out.append(_to_py(key[off]))
-                off += w
-            return out
-        out = []
-        for ki, k in enumerate(key):
-            if _is_interned_type(self._key_types[ki]):
-                out.append(unintern_value(_to_py(k)))
-            else:
-                out.append(_to_py(k))
-        return out
-
     def _snapshot_rows(self) -> dict:
         """Directory + accumulator values as plain lists (checkpoint form).
         Interned key codes are resolved to their values: codes are
@@ -779,7 +538,7 @@ class WindowOperatorBase(Operator):
         bins, keys, slots = [], [], []
         for b, key, slot in self.dir.items():
             bins.append(int(b))
-            keys.append(self._key_tuple_to_values(key))
+            keys.append(self.codec.values(key))
             slots.append(int(slot))
         slots_arr = np.asarray(slots, dtype=np.int64)
         values = self.acc.snapshot(slots_arr) if len(slots) else []
@@ -800,22 +559,7 @@ class WindowOperatorBase(Operator):
             keys = [k for k, m in zip(keys, mask) if m]
             if not bins:
                 return
-        n_keycols = len(keys[0]) if keys else 0
-        key_cols = []
-        for i in range(n_keycols):
-            vals = [k[i] for k in keys]
-            kt = self._key_types[i]
-            if self._flat_widths is not None and pa.types.is_struct(kt):
-                # flat native layout: portable struct tuples -> child words
-                mat = np.asarray([list(v) for v in vals], dtype=np.int64)
-                key_cols.extend(
-                    mat[:, j] for j in range(self._flat_widths[i])
-                )
-            elif _is_interned_type(kt):
-                # dtype=object routes through the interning path in assign()
-                key_cols.append(np.asarray(vals, dtype=object))
-            else:
-                key_cols.append(np.asarray(vals, dtype=np.int64))
+        key_cols = self.codec.columns_from_values(keys)
         bins_arr = np.asarray(bins, dtype=np.int64)
         slots = self.dir.assign(bins_arr, key_cols)
         self._ensure_capacity()
@@ -851,47 +595,14 @@ class WindowOperatorBase(Operator):
             return None
         if not self.key_cols:
             return None
-        from ..types import hash_arrays, hash_column, server_for_hash_array
+        from ..types import hash_arrays, server_for_hash_array
 
-        cols = []
-        for i in range(len(keys[0])):
-            vals = [k[i] for k in keys]
-            kt = self._key_types[i]
-            # dtype must match what the shuffle hashed (schema.hash_keys)
-            if pa.types.is_struct(kt):
-                # shuffle hashes struct children in order. Portable
-                # snapshot values are the tuples themselves (msgpack may
-                # hand them back as lists); in-process session bookkeeping
-                # passes interned codes
-                tuples = [
-                    unintern_value(v) if isinstance(v, (int, np.integer))
-                    else tuple(v)
-                    for v in (_to_py(v) for v in vals)
-                ]
-                for j in range(kt.num_fields):
-                    cols.append(hash_column(
-                        np.asarray([t[j] for t in tuples], dtype=np.int64)
-                    ))
-                continue
-            if pa.types.is_floating(kt):
-                arr = np.asarray(vals, dtype=np.float64)
-            elif _is_interned_type(kt):
-                arr = np.asarray(vals, dtype=object)
-            else:
-                arr = np.asarray(vals, dtype=np.int64)
-            cols.append(hash_column(arr))
+        # dtypes must match what the shuffle hashed (schema.hash_keys)
+        cols = self.codec.hash_columns(keys)
         owners = server_for_hash_array(
             hash_arrays(cols), ctx.task_info.parallelism
         )
         return list(owners == ctx.task_info.task_index)
-
-
-def _to_py(v):
-    return v.item() if isinstance(v, np.generic) else v
-
-
-def _is_temporal_or_int(t: pa.DataType) -> bool:
-    return pa.types.is_integer(t) or pa.types.is_timestamp(t)
 
 
 def _snaps_for_me(table, ctx, keyed: bool):
@@ -907,21 +618,11 @@ def _snaps_for_me(table, ctx, keyed: bool):
             yield snap
 
 
-def _is_interned_type(t: pa.DataType) -> bool:
-    return not (
-        pa.types.is_integer(t)
-        or pa.types.is_boolean(t)
-        or pa.types.is_timestamp(t)
-    )
-
-
 def _ceil_div(a: int, b: int) -> int:
     return -(-a // b)
 
 
 class TumblingWindowOperator(WindowOperatorBase):
-    _native_ok = True
-    _device_ok = True
     _mesh_ok = True
 
     """Fixed-width windows: bin = ts // width; emit at watermark >= end
@@ -1000,7 +701,7 @@ class TumblingWindowOperator(WindowOperatorBase):
                         return
                     batch = batch.filter(pa.array(live))
                     bins = bins[live]
-            keys = self._key_arrays(batch)
+            keys = self.codec.columns(batch, self.key_cols)
         self._scatter(batch, bins, keys, ctx)
 
     async def handle_watermark(self, watermark, ctx, collector):
@@ -1008,7 +709,6 @@ class TumblingWindowOperator(WindowOperatorBase):
             return watermark
         t = watermark.timestamp
         limit = _ceil_div(t, self.width) if self.width else t + 1
-        take_arrays = getattr(self.dir, "take_bin_arrays", None)
         # mesh accumulators fuse gather+reset into one device program
         # (halves the per-wave emission dispatches); host-state drops
         # then happen after finalize has read the stores
@@ -1027,9 +727,9 @@ class TumblingWindowOperator(WindowOperatorBase):
         last_end = self._bin_end(due[-1])
         with timeline.phase("close.take", key=last_end) as ph:
             for b in due:
-                if take_arrays is not None:
-                    # native fast path: key columns stay numpy end-to-end
-                    key_arrays, slots = take_arrays(b)
+                if self.codec.words:
+                    # key columns stay numpy end-to-end
+                    key_arrays, slots = self.dir.take_bin_arrays(b)
                     keys: List[tuple] = []
                 else:
                     keys, slots = self.dir.take_bin(b)
@@ -1079,8 +779,6 @@ class SlidingWindowOperator(WindowOperatorBase):
     merges width/slide bins (reference sliding_aggregating_window.rs:64-753).
     Requires width % slide == 0."""
 
-    _native_ok = True
-    _device_ok = True
     _mesh_ok = True
 
     def __init__(self, config: dict):
@@ -1156,7 +854,7 @@ class SlidingWindowOperator(WindowOperatorBase):
                     bins = bins[live]
             if self.next_emit is None and len(bins):
                 self.next_emit = (int(bins.min()) + 1) * self.slide
-            keys = self._key_arrays(batch)
+            keys = self.codec.columns(batch, self.key_cols)
         self._scatter(batch, bins, keys, ctx)
 
     async def handle_watermark(self, watermark, ctx, collector):
@@ -1184,30 +882,26 @@ class SlidingWindowOperator(WindowOperatorBase):
         key_chunks = []
         slot_chunks = []
         with timeline.phase("close.take", key=end) as ph:
-            take_arrays = getattr(self.dir, "take_bin_arrays", None)
-            if take_arrays is not None:
-                fk_cols, freed = take_arrays(lo_bin)
+            if self.codec.words:
+                fk_cols, freed = self.dir.take_bin_arrays(lo_bin)
                 if len(freed):
                     key_chunks.append(np.stack(fk_cols, axis=1))
                     slot_chunks.append(freed)
-            else:
-                fk, freed = self.dir.take_bin(lo_bin)
-                if len(freed):
-                    key_chunks.append(fk)
-                    slot_chunks.append(freed)
-            multi = getattr(self.dir, "bin_entries_multi", None)
-            if multi is not None:
-                # native directories: ONE batched crossing covering every
-                # participating bin (the merge unions keys across bins, so
-                # per-bin identity is irrelevant) instead of k get_bin
-                # calls — k x shards calls on the mesh facade
-                kmat, slots_m = multi(
+                # ONE batched crossing covering every participating bin
+                # (the merge unions keys across bins, so per-bin identity
+                # is irrelevant) instead of k get_bin calls — k x shards
+                # calls on the mesh facade
+                kmat, slots_m = self.dir.bin_entries_multi(
                     np.arange(lo_bin + 1, end_bin, dtype=np.int64)
                 )
                 if len(slots_m):
                     key_chunks.append(kmat)
                     slot_chunks.append(slots_m)
             else:
+                fk, freed = self.dir.take_bin(lo_bin)
+                if len(freed):
+                    key_chunks.append(fk)
+                    slot_chunks.append(freed)
                 for b in range(lo_bin + 1, end_bin):
                     keys_b, slots_b = self.dir.bin_entries(b)
                     if len(slots_b):
@@ -1242,8 +936,8 @@ class SlidingWindowOperator(WindowOperatorBase):
         """Union of the participating bins' keys: (segment id per slot,
         output keys as tuples, output key columns, distinct keys)."""
         key_arrays = None
-        if isinstance(key_chunks[0], np.ndarray):
-            # native path: vectorized key-union over int64 key matrices
+        if self.codec.words:
+            # vectorized key-union over int64 key matrices
             # (count, n_keycols); keys stay numpy end-to-end (no python
             # tuple per key)
             all_keys = np.concatenate(key_chunks)
@@ -1323,6 +1017,7 @@ class SessionWindowOperator(WindowOperatorBase):
     (reference treats all window types uniformly)."""
 
     _mesh_ok = True
+    _uses_assign = False
     _offmesh_backend = "numpy"
 
     def __init__(self, config: dict):
@@ -1458,7 +1153,7 @@ class SessionWindowOperator(WindowOperatorBase):
     def _sess_key(self, key: tuple) -> tuple:
         """Portable per-session-key table key ("sk", *values) — msgpack
         round-trips it as a list, GlobalTable re-tuples on load."""
-        return ("sk", *self._key_tuple_to_values(key))
+        return ("sk", *self.codec.values(key))
 
     def _restore_per_key(self, items: list, ctx) -> set:
         """Replay per-key entries owned by this subtask; returns the set
@@ -1497,7 +1192,7 @@ class SessionWindowOperator(WindowOperatorBase):
         values = self.acc.snapshot(slots_arr) if slots else []
         return {
             "sessions": [
-                [self._key_tuple_to_values(key), [[int(x) for x in s] for s in v]]
+                [self.codec.values(key), [[int(x) for x in s] for s in v]]
                 for key, v in self.sessions.items()
             ],
             "slots": [int(s) for s in slots],
@@ -1508,14 +1203,6 @@ class SessionWindowOperator(WindowOperatorBase):
         """Replay one pre-restart subtask's sessions, remapping slots (old
         slot ids collide across subtasks) and skipping keys outside this
         subtask's range."""
-        from ..ops.directory import intern_value
-
-        def to_key(vals: list) -> tuple:
-            return tuple(
-                intern_value(v) if _is_interned_type(self._key_types[i]) else v
-                for i, v in enumerate(vals)
-            )
-
         slot_pos = {s: i for i, s in enumerate(snap["slots"])}
         # trailing host-state columns are ragged per-slot lists (same
         # object-array discipline as _restore_rows)
@@ -1535,7 +1222,7 @@ class SessionWindowOperator(WindowOperatorBase):
         for si, (key_vals, sess_list) in enumerate(snap["sessions"]):
             if mask is not None and not mask[si]:
                 continue
-            key = to_key(key_vals)
+            key = self.codec.key(key_vals)
             cur = self.sessions.setdefault(key, [])
             for s in sess_list:
                 new_slot = self._alloc_slot()
@@ -1556,7 +1243,7 @@ class SessionWindowOperator(WindowOperatorBase):
         self._capture_key_meta(ctx)
         ts = ctx.in_schemas[0].timestamps(batch)
         wm = ctx.watermarks.current_nanos()
-        keys = self._key_arrays(batch)
+        keys = self.codec.columns(batch, self.key_cols)
         cols = self._agg_input_cols(batch)
         n = len(ts)
         row_slots = np.full(n, -1, dtype=np.int64)

@@ -1,10 +1,19 @@
 """Host-side slot directory: maps (bin, key) groups to accumulator slots.
 
 This is the "hash table on TPU" compromise documented in SURVEY.md §7:
-slot assignment is a host dict over the *unique* (bin, key) pairs of each
-batch (vectorized uniquing via numpy), while the O(rows) arithmetic runs on
-device. A pallas open-addressing kernel can replace this later without
-changing the operator contract.
+slot assignment is a host table over the *unique* (bin, key) pairs of each
+batch, while the O(rows) arithmetic runs on device.
+
+The layer's two entry points, and the only places that know which table
+holds the groups and how a key is encoded for it:
+
+- `make_directory`: the one constructor the operators call. A python
+  `SlotDirectory` where a key does not flatten to int64 words (or the
+  C++ module is switched off), `ops/native.py`'s table otherwise, behind
+  `parallel/sharded_state.py`'s facades on a mesh.
+- `KeyCodec`: every conversion between a batch's key columns, the
+  table's keys, Arrow arrays of the declared types and the portable
+  checkpoint forms, for the `key_encoding` the chosen table declares.
 """
 
 from __future__ import annotations
@@ -12,10 +21,23 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+import pyarrow as pa
+
+from ..types import hash_column
+from .native import (
+    NativeSlotDirectory,
+    _i64able,
+    flat_key_widths,
+    load_native,
+)
 
 
 class SlotDirectory:
-    def __init__(self, scratch_slot_reserved: bool = True):
+    # non-integer key values reach the table as process-local interned
+    # codes (`_unique_pairs`); see KeyCodec
+    key_encoding = "codes"
+
+    def __init__(self):
         self.by_bin: Dict[int, Dict[tuple, int]] = {}
         self.free: List[int] = []
         self.next_slot = 0
@@ -203,3 +225,327 @@ def _factorize_to_codes(col: np.ndarray, _cols) -> np.ndarray:
     return np.fromiter(
         (intern_value(v) for v in col), dtype=np.int64, count=len(col)
     )
+
+
+# -- the seam: which table, and how its keys are encoded --------------------
+
+
+def make_directory(key_types, *, mesh_shards: int = 0, salted: bool = False,
+                   uses_assign: bool = True):
+    """The (bin, key) -> slot table for an operator, chosen ONCE from what
+    it can observe: its key types, the mesh it runs on (`mesh_shards` >= 2:
+    a facade over per-shard tables, or over one flat table when
+    `salted`), and whether it maps rows to slots through `assign` at all
+    (sessions allocate slots imperatively and their keys never reach the
+    table: `key_types` may be None then).
+
+    Keys that flatten to at most 16 int64 words ride the C++ table; any
+    other key type, or a host without the C++ module, the python one.
+    What came back declares its `key_encoding`, fixed from here on:
+    "words" (bins come back as int64 word arrays: `take_bin_arrays`,
+    `bin_entries_multi`, a matrix from `bin_entries`), "codes" (tuples of
+    ints, non-integer values interned) or "values" (no key in the
+    table)."""
+    widths = flat_key_widths(key_types) if uses_assign else None
+    if mesh_shards >= 2:
+        from ..parallel.sharded_state import (
+            MeshSlotDirectory,
+            SharedMeshSlotDirectory,
+        )
+
+        table = (SharedMeshSlotDirectory if salted
+                 else MeshSlotDirectory)(mesh_shards)
+        if widths is not None:
+            table.swap_to_native(load_native(), sum(widths))
+    elif widths is not None:
+        table = NativeSlotDirectory(load_native(), n_keys=sum(widths))
+    else:
+        table = SlotDirectory()
+    if not uses_assign:
+        table.key_encoding = "values"
+    return table
+
+
+def _to_py(v):
+    return v.item() if isinstance(v, np.generic) else v
+
+
+def _is_interned_type(t: pa.DataType) -> bool:
+    return not (
+        pa.types.is_integer(t)
+        or pa.types.is_boolean(t)
+        or pa.types.is_timestamp(t)
+    )
+
+
+class KeyCodec:
+    """A grouping key's encodings, for one operator's key types and the
+    `key_encoding` of the table `make_directory` chose:
+
+    - "words": every key column is one int64 word, a struct (window) key
+      one word per child; table keys are the flat word tuples.
+    - "codes": integer-like columns are their int64 bit patterns, every
+      other value (strings, floats, struct keys as tuples of their
+      children) a process-local interned code.
+    - "values": the operator keeps the keys itself, as plain values.
+
+    Portable forms (never a code: codes are process-local): a snapshot
+    holds one list of values per key, a struct as the tuple of its
+    children; a delta batch holds one `__k{i}` column per key column."""
+
+    def __init__(self, key_types, key_encoding: str):
+        self.types: List[pa.DataType] = list(key_types)
+        self.words = key_encoding == "words"
+        self._coded = key_encoding == "codes"
+        # word layout: widths[i] words for key column i, from offsets[i]
+        self._widths = [
+            t.num_fields if pa.types.is_struct(t) else 1 for t in self.types
+        ]
+        self._offsets = [0]
+        for w in self._widths:
+            self._offsets.append(self._offsets[-1] + w)
+
+    # -- batch -> key columns for assign() ----------------------------------
+
+    def columns(self, batch: pa.RecordBatch, key_cols) -> List[np.ndarray]:
+        out = []
+        for i in key_cols:
+            col = batch.column(i)
+            if pa.types.is_struct(col.type) and self.words:
+                # struct children ride as separate int64 key words — no
+                # python tuple per row
+                for j in range(col.type.num_fields):
+                    out.append(
+                        np.asarray(col.field(j).cast(pa.int64()))
+                    )
+                continue
+            if pa.types.is_struct(col.type):
+                # struct keys (window structs) become tuples of child values;
+                # tuples are built per UNIQUE row (batches share few windows)
+                children = [
+                    np.asarray(col.field(j).cast(pa.int64()))
+                    if _i64able(col.type.field(j).type)
+                    else np.array(col.field(j).to_pylist(), dtype=object)
+                    for j in range(col.type.num_fields)
+                ]
+                if all(c.dtype != object for c in children):
+                    mat = np.stack(children, axis=1)
+                    uniq, inverse = np.unique(mat, axis=0, return_inverse=True)
+                    tuples = np.empty(len(uniq), dtype=object)
+                    tuples[:] = [tuple(int(x) for x in row) for row in uniq]
+                    out.append(tuples[inverse.ravel()])
+                else:
+                    out.append(
+                        np.fromiter(
+                            (tuple(int(c[r]) if isinstance(c[r], np.integer)
+                                   else c[r] for c in children)
+                             for r in range(batch.num_rows)),
+                            dtype=object,
+                            count=batch.num_rows,
+                        )
+                    )
+                continue
+            try:
+                out.append(col.to_numpy(zero_copy_only=False))
+            except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+                out.append(np.array(col.to_pylist(), dtype=object))
+        return out
+
+    # -- table keys -> Arrow arrays of the declared types -------------------
+
+    def arrow_from_words(self, ki: int, word_cols) -> pa.Array:
+        """Key column `ki` from the table's int64 word columns (raw bit
+        patterns) — the vectorized emission path of a "words" table."""
+        kt = self.types[ki]
+        off = self._offsets[ki]
+        if pa.types.is_struct(kt):
+            # regroup the struct's child words
+            children = [
+                pa.array(word_cols[off + j]).cast(kt.field(j).type)
+                for j in range(kt.num_fields)
+            ]
+            return pa.StructArray.from_arrays(
+                children,
+                names=[kt.field(j).name for j in range(kt.num_fields)],
+            )
+        if pa.types.is_unsigned_integer(kt):
+            return pa.array(word_cols[off].view(np.uint64), type=kt)
+        # signed ints and timestamps cast directly
+        return pa.array(word_cols[off]).cast(kt)
+
+    def arrow_from_keys(self, ki: int, keys: List[tuple]) -> pa.Array:
+        """Key column `ki` from key tuples as the table (or, under
+        "values", the operator) holds them."""
+        kt = self.types[ki]
+        if self.words:
+            n = len(keys)
+            lo, hi = self._offsets[ki], self._offsets[ki + 1]
+            return self.arrow_from_words(ki, {
+                w: np.fromiter((k[w] for k in keys), np.int64, n)
+                for w in range(lo, hi)
+            })
+        vals = [_to_py(k[ki]) for k in keys]
+        if self._coded and _is_interned_type(kt):
+            vals = [unintern_value(v) for v in vals]
+        if pa.types.is_struct(kt):
+            children = [
+                pa.array(
+                    [t[j] for t in vals], type=pa.int64()
+                ).cast(kt.field(j).type)
+                if _i64able(kt.field(j).type)
+                else pa.array([t[j] for t in vals],
+                              type=kt.field(j).type)
+                for j in range(kt.num_fields)
+            ]
+            return pa.StructArray.from_arrays(
+                children,
+                names=[kt.field(j).name for j in range(kt.num_fields)],
+            )
+        if _is_interned_type(kt):
+            return pa.array(vals, type=kt)
+        if pa.types.is_unsigned_integer(kt):
+            # int64 bit patterns; normalize back
+            return pa.array([v % (1 << 64) for v in vals], type=kt)
+        if pa.types.is_timestamp(kt):
+            return pa.array(vals, type=pa.int64()).cast(kt)
+        return pa.array(vals, type=kt)
+
+    # -- table key <-> portable values ---------------------------------------
+
+    def values(self, key: tuple) -> list:
+        """Table key tuple -> portable key values."""
+        if self.words:
+            # struct child words regroup into the portable tuple form
+            # (plain ints — nothing is interned here)
+            out = []
+            off = 0
+            for ki, w in enumerate(self._widths):
+                if pa.types.is_struct(self.types[ki]):
+                    out.append(tuple(int(x) for x in key[off:off + w]))
+                else:
+                    out.append(_to_py(key[off]))
+                off += w
+            return out
+        out = []
+        for ki, k in enumerate(key):
+            if self._coded and _is_interned_type(self.types[ki]):
+                out.append(unintern_value(_to_py(k)))
+            else:
+                out.append(_to_py(k))
+        return out
+
+    def key(self, values: list) -> tuple:
+        """One key's portable values -> the key tuple `values` came from."""
+        if self.words:
+            out: list = []
+            for ki, v in enumerate(values):
+                if pa.types.is_struct(self.types[ki]):
+                    out.extend(v)
+                else:
+                    out.append(v)
+            return tuple(out)
+        if self._coded:
+            return tuple(
+                intern_value(v) if _is_interned_type(self.types[i]) else v
+                for i, v in enumerate(values)
+            )
+        # msgpack round-trips a struct's tuple as a list
+        return tuple(tuple(v) if isinstance(v, list) else v for v in values)
+
+    # -- portable forms -> key columns for assign() -------------------------
+
+    def columns_from_values(self, keys: List[list]) -> List[np.ndarray]:
+        """A snapshot's key rows (portable values) -> key columns."""
+        key_cols = []
+        for i in range(len(keys[0]) if keys else 0):
+            vals = [k[i] for k in keys]
+            kt = self.types[i]
+            if self.words and pa.types.is_struct(kt):
+                # portable struct tuples -> child words
+                mat = np.asarray([list(v) for v in vals], dtype=np.int64)
+                key_cols.extend(mat[:, j] for j in range(self._widths[i]))
+            elif _is_interned_type(kt):
+                # dtype=object routes through the interning path in
+                # assign(); filled in place, or a struct's tuples (lists
+                # after msgpack) would make the array 2-d
+                col = np.empty(len(vals), dtype=object)
+                col[:] = [tuple(v) if isinstance(v, list) else v
+                          for v in vals]
+                key_cols.append(col)
+            else:
+                key_cols.append(np.asarray(vals, dtype=np.int64))
+        return key_cols
+
+    def columns_from_delta(self, batch: pa.RecordBatch) -> List[np.ndarray]:
+        """A delta batch's __k* columns -> key columns (object arrays of
+        values for non-integer types, int64 bit patterns otherwise)."""
+        names = batch.schema.names
+        out = []
+        for i, kt in enumerate(self.types):
+            col = batch.column(names.index(f"__k{i}"))
+            if _is_interned_type(kt):
+                out.append(np.array(col.to_pylist(), dtype=object))
+            else:
+                out.append(np.asarray(col.cast(pa.int64())))
+        return out
+
+    # -- key columns / portable rows -> a delta batch's __k* columns --------
+
+    def delta_arrays(self, key_cols: List[np.ndarray]) -> List[pa.Array]:
+        """Key columns as `columns` made them (normalized to int64 views
+        where integer-like) -> one Arrow array per __k* column."""
+        out = []
+        for i, kt in enumerate(self.types):
+            c = key_cols[i]
+            if _is_interned_type(kt):
+                out.append(pa.array(c.tolist(), type=kt))
+            else:
+                out.append(pa.array(c.astype(np.int64, copy=False)))
+        return out
+
+    def delta_arrays_from_values(self, key_rows: List[tuple]) -> List[pa.Array]:
+        """Portable key rows -> one Arrow array per __k* column (values
+        keep their types; the rest are int64 bit patterns whose hash
+        matches the shuffle's)."""
+        out = []
+        for i, kt in enumerate(self.types):
+            vals = [k[i] for k in key_rows]
+            if _is_interned_type(kt):
+                out.append(pa.array(vals, type=kt))
+            else:
+                out.append(
+                    pa.array(np.asarray(vals, dtype=np.int64))
+                )
+        return out
+
+    # -- portable rows -> the columns the shuffle hashed --------------------
+
+    def hash_columns(self, keys: List[list]) -> list:
+        """hash_column per key column (a struct's children in order) of
+        portable key rows, dtypes as schema.hash_keys hashed them."""
+        cols = []
+        for i in range(len(keys[0])):
+            vals = [k[i] for k in keys]
+            kt = self.types[i]
+            if pa.types.is_struct(kt):
+                # portable snapshot values are the tuples themselves
+                # (msgpack may hand them back as lists); a "codes" key
+                # passed in-process is the interned code
+                tuples = [
+                    unintern_value(v) if isinstance(v, (int, np.integer))
+                    else tuple(v)
+                    for v in (_to_py(v) for v in vals)
+                ]
+                for j in range(kt.num_fields):
+                    cols.append(hash_column(
+                        np.asarray([t[j] for t in tuples], dtype=np.int64)
+                    ))
+                continue
+            if pa.types.is_floating(kt):
+                arr = np.asarray(vals, dtype=np.float64)
+            elif _is_interned_type(kt):
+                arr = np.asarray(vals, dtype=object)
+            else:
+                arr = np.asarray(vals, dtype=np.int64)
+            cols.append(hash_column(arr))
+        return cols

@@ -81,6 +81,8 @@ class NativeSlotDirectory:
     and the 2-D `bin_entries` matrix are the vectorized emission paths
     (no python tuple per key)."""
 
+    key_encoding = "words"  # ops/directory.py KeyCodec
+
     def __init__(self, native_mod, n_keys: int = 1):
         # n_keys 0 = unkeyed: one synthetic zero key word, empty tuples out
         self.n_keys = n_keys
@@ -257,19 +259,13 @@ def _i64able(t) -> bool:
 
 def flat_key_widths(key_types):
     """Per-key-column int64 word counts for the native directory, or None
-    when any column can't ride it (or the native module is absent)."""
-    if load_native() is None:
-        return None
-    return key_word_widths(key_types)
-
-
-def key_word_widths(key_types):
-    """Per-key-column int64 word counts for flat-word directories (native
-    C++ and device), or None when any column can't be int64-flattened.
+    when any column can't ride it (or the native module is absent).
     Struct columns (window structs) flatten into their child words when
     every child is integer/timestamp."""
     import pyarrow as pa
 
+    if load_native() is None:
+        return None
     widths = []
     for t in key_types:
         if pa.types.is_struct(t):

@@ -84,6 +84,7 @@ from ..ops.aggregates import (
 )
 from ..ops._jax import get_jax
 from ..ops.directory import SlotDirectory
+from ..ops.native import NativeSlotDirectory
 from ..types import hash_arrays, hash_column, server_for_hash_array
 
 # global slot encoding: slot = shard * STRIDE + local. The stride is fixed
@@ -107,34 +108,32 @@ class MeshSlotDirectory:
     owning shard (same splitmix64 hashing as the host shuffle), the shard's
     directory assigns a local slot, and callers see global slots.
 
-    Per-shard directories default to the python SlotDirectory; operators
-    whose keys flatten to int64 words swap them to the native C++ table
-    (`swap_to_native`) — round-5 mesh profile showed the python per-shard
-    assigns + tuple-per-key emission as the largest host cost on the
-    mesh path. Session windows keep python shards (imperative
-    alloc_slot/free lists live there)."""
+    Per-shard directories are python SlotDirectories until
+    `swap_to_native` (ops/directory.py `make_directory` calls it, before
+    the first key, for keys that flatten to int64 words) — round-5 mesh
+    profile showed the python per-shard assigns + tuple-per-key emission
+    as the largest host cost on the mesh path. Session windows keep
+    python shards (imperative alloc_slot/free lists live there).
+    `key_encoding` is the shards'; `take_bin_arrays` and
+    `bin_entries_multi` are for "words" shards only."""
 
     def __init__(self, n_shards: int):
         self.n_shards = n_shards
         self.dirs = [SlotDirectory() for _ in range(n_shards)]
         self._native = False
+        self.key_encoding = SlotDirectory.key_encoding
 
     def swap_to_native(self, native_mod, n_keys: int) -> bool:
         """Replace the per-shard python directories with C++ tables
         (callable only while empty). Returns True on swap."""
         if native_mod is None or any(d.n_live for d in self.dirs):
             return False
-        from ..ops.native import NativeSlotDirectory
-
         self.dirs = [
             NativeSlotDirectory(native_mod, n_keys=n_keys)
             for _ in range(self.n_shards)
         ]
         self._native = True
-        # bound as instance attributes so the window operators' array
-        # fast paths (attribute probes) engage exactly when arrays exist
-        self.take_bin_arrays = self._take_bin_arrays
-        self.bin_entries_multi = self._bin_entries_multi
+        self.key_encoding = NativeSlotDirectory.key_encoding
         return True
 
     @property
@@ -239,11 +238,9 @@ class MeshSlotDirectory:
             else np.empty(0, dtype=np.int64)
         )
 
-    def _take_bin_arrays(self, b: int):
-        """Vectorized take (native shards only — bound as
-        `take_bin_arrays` by swap_to_native so the attribute probe in
-        the window watermark path engages exactly when arrays exist).
-        One C call per shard; outputs fill preallocated buffers."""
+    def take_bin_arrays(self, b: int):
+        """Vectorized take (native shards only). One C call per shard;
+        outputs fill preallocated buffers."""
         per_shard: List[tuple] = []  # (shard, key cols, local slots)
         total = 0
         for shard, d in enumerate(self.dirs):
@@ -266,11 +263,11 @@ class MeshSlotDirectory:
             off += n
         return out_cols, out_slots
 
-    def _bin_entries_multi(self, bins) -> Tuple[np.ndarray, np.ndarray]:
+    def bin_entries_multi(self, bins) -> Tuple[np.ndarray, np.ndarray]:
         """Concatenated (key matrix, global slots) over SEVERAL bins in
         one native C call per shard (the sliding merge reads width/slide
         bins per emission; per-bin calls cost S x k crossings). Native
-        shards only — bound by swap_to_native like take_bin_arrays."""
+        shards only."""
         bins_arr = np.ascontiguousarray(np.asarray(bins, dtype=np.int64))
         mats: List[np.ndarray] = []
         slot_chunks: List[np.ndarray] = []
@@ -627,6 +624,7 @@ class SharedMeshSlotDirectory:
     def __init__(self, n_shards: int):
         self.n_shards = n_shards
         self._flat = SlotDirectory()
+        self.key_encoding = SlotDirectory.key_encoding
 
     def swap_to_native(self, native_mod, n_keys: int) -> bool:
         """Swap the flat python directory for the C++ table (callable
@@ -637,20 +635,16 @@ class SharedMeshSlotDirectory:
         their imperative alloc_slot/free lists live python-side."""
         if native_mod is None or self._flat.n_live:
             return False
-        from ..ops.native import NativeSlotDirectory
-
         self._flat = NativeSlotDirectory(native_mod, n_keys=n_keys)
-        # bound as instance attributes so the window operators' array
-        # fast paths (attribute probes) engage exactly when arrays exist
-        self.take_bin_arrays = self._take_bin_arrays
-        self.bin_entries_multi = self._bin_entries_multi
+        self.key_encoding = NativeSlotDirectory.key_encoding
         return True
 
-    def _take_bin_arrays(self, b: int):
+    # "words" table only, like the native directory's own
+    def take_bin_arrays(self, b: int):
         cols, slots = self._flat.take_bin_arrays(b)
         return cols, self._g(slots)
 
-    def _bin_entries_multi(self, bins) -> Tuple[np.ndarray, np.ndarray]:
+    def bin_entries_multi(self, bins) -> Tuple[np.ndarray, np.ndarray]:
         kmat, slots = self._flat.bin_entries_multi(bins)
         return kmat, self._g(slots)
 
@@ -703,6 +697,10 @@ class SharedMeshSlotDirectory:
         return self._flat.keys_for_slots(
             np.asarray(slots, dtype=np.int64) % STRIDE
         )
+
+    def slots_for_keys(self, b, keys):
+        return {k: self._g1(s)
+                for k, s in self._flat.slots_for_keys(b, keys).items()}
 
     def remove(self, b, keys):
         return self._g(self._flat.remove(b, keys))

@@ -766,7 +766,7 @@ def register_op(op, ctx) -> Optional[ServeView]:
         # mirror-seeded copy
         for k, vals in op.emitted.items():
             try:
-                key = view.canon_key(op._key_tuple_to_values(k))
+                key = view.canon_key(op.codec.values(k))
             except Exception:  # noqa: BLE001 - exotic key shape
                 continue
             view.stage_restored(key, {

@@ -1,8 +1,9 @@
 """Device execution tiers under faults (VERDICT r5 item 2, scoped slice):
 a representative golden subset — ≥6 queries including one session window
 and one updating query — with `tpu.require_accelerator` forced OFF (device
-kernels engage on the CPU-jax backend) and the device directory on, plus
-one checkpoint/kill/restore cycle through the device-tier paths.
+kernels engage on the CPU-jax backend) over the native slot directory,
+which is what the chip runs, plus one checkpoint/kill/restore cycle
+through the device-tier paths.
 
 Gated behind ARROYO_DEVICE_TIER_FAULTS=1 (or `-m device_tier` after
 setting it): the XLA compiles make this subset too heavy for tier-1, and
@@ -33,8 +34,8 @@ pytestmark = [
 
 # ≥6 goldens: windowed aggregates (tumble/hop), one SESSION window, one
 # UPDATING query, a join, and a distinct aggregate — the surfaces the
-# device kernels (scatter-reduce accumulators, device directory, device
-# join probe) actually specialize
+# device kernels (scatter-reduce accumulators, device join probe)
+# actually specialize
 DEVICE_TIER_QUERIES = (
     "hourly_by_event_type",    # tumbling window aggregate
     "sliding_window_end",      # hopping window
@@ -48,8 +49,6 @@ DEVICE_TIER_QUERIES = (
 DEVICE_TIER_CONFIG = {
     "enabled": True,
     "require_accelerator": False,  # engage device kernels on CPU-jax
-    "device_directory": True,
-    "device_directory_audit": True,  # catch 64-bit hash merges loudly
 }
 
 

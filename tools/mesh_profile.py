@@ -125,8 +125,7 @@ def child(events: int, mesh: int, linger: float) -> None:
 # split its rows by function name so "directory work" and "host packing"
 # stay separate stages
 _DIR_FUNCS = {
-    "assign", "owners_for", "take_bin", "_take_bin_arrays",
-    "take_bin_arrays", "bin_entries", "_bin_entries_multi",
+    "assign", "owners_for", "take_bin", "take_bin_arrays", "bin_entries",
     "bin_entries_multi", "items", "keys_for_slots", "slots_for_keys",
     "remove", "peek_bin", "bins_up_to", "live_bins", "alloc_slot",
     "alloc_slots", "free_slot", "free_slots", "required_capacity",

@@ -109,13 +109,24 @@ def _col_salts(n: int) -> np.ndarray:
     return _SALTS
 
 
-def _col_u64(col: pa.Array) -> np.ndarray:
+def _col_u64(col: pa.Array, tally: List[int]) -> np.ndarray:
     """Raw uint64 view of one column (nulls -> type sentinel, -0.0
     normalized) WITHOUT per-column mixing — the audit fold mixes once
     per row after the linear combine, which is what keeps the always-on
-    tap cheap enough for every data-plane edge."""
+    tap cheap enough for every data-plane edge. A string column is the
+    pandas hash of each row, taken once per DISTINCT value where those
+    are few (`types.hash_string_array`: a hash depends on the value's
+    bytes alone, so the rows' uint64s are the same bit for bit);
+    `tally` counts [string columns hashed, of them through a
+    dictionary]."""
     from ..schema import _null_sentinel, _to_numpy
+    from ..types import hash_column, hash_string_array, is_string_type
 
+    if is_string_type(col.type):
+        hashes, via_dictionary = hash_string_array(col)
+        tally[0] += 1
+        tally[1] += via_dictionary
+        return hashes
     if col.null_count:
         col = col.fill_null(_null_sentinel(col.type))
     arr = _to_numpy(col)
@@ -128,9 +139,7 @@ def _col_u64(col: pa.Array) -> np.ndarray:
                 else arr.astype(np.float64).view(np.uint64))
     if kind == "M":
         return arr.view("i8").astype(np.uint64)
-    from ..types import hash_column  # strings/objects: pandas hash
-
-    return hash_column(arr)
+    return hash_column(arr)  # binary and other objects: pandas hash
 
 
 # extra odd salts for nested shapes: list length (so [a, b]+[] and
@@ -140,17 +149,19 @@ _LIST_LEN_SALT = np.uint64(0xD6E8FEB86659FD93)
 _NULL_LIST = np.uint64(0xA5A5A5A5A5A5A5A5)
 
 
-def _row_u64(col: pa.Array) -> np.ndarray:
+def _row_u64(col: pa.Array, tally: List[int]) -> np.ndarray:
     """One uint64 per row for any column type, recursing into nested
     shapes: struct children combine linearly under the column salts
     (+ one mix), list elements get one mix each and sum within the row
     (order-insensitive, like the batch fold) with the length salted in.
-    Flat columns stay on the raw-view fast path (`_col_u64`)."""
+    Flat columns stay on the raw-view fast path (`_col_u64`, which keeps
+    `tally`)."""
     t = col.type
     from ..types import _splitmix64
 
     if pa.types.is_struct(t):
-        kids = [_row_u64(col.field(j)) for j in range(t.num_fields)]
+        kids = [_row_u64(col.field(j), tally)
+                for j in range(t.num_fields)]
         salts = _col_salts(len(kids))
         with np.errstate(over="ignore"):
             acc = kids[0] * salts[0]
@@ -164,7 +175,7 @@ def _row_u64(col: pa.Array) -> np.ndarray:
 
         lens = np.asarray(
             pc.list_value_length(col).fill_null(0), dtype=np.int64)
-        h = _splitmix64(_row_u64(col.flatten()))
+        h = _splitmix64(_row_u64(col.flatten(), tally))
         c = np.zeros(len(h) + 1, dtype=np.uint64)
         if len(h):
             np.cumsum(h, dtype=np.uint64, out=c[1:])  # wraps mod 2^64
@@ -175,10 +186,11 @@ def _row_u64(col: pa.Array) -> np.ndarray:
         if col.null_count:
             rows = np.where(np.asarray(col.is_valid()), rows, _NULL_LIST)
         return rows
-    return _col_u64(col)
+    return _col_u64(col, tally)
 
 
-def batch_fingerprint(batch: pa.RecordBatch) -> Tuple[int, int]:
+def batch_fingerprint(batch: pa.RecordBatch,
+                      tally: Optional[List[int]] = None) -> Tuple[int, int]:
     """(rows, digest) of one batch. Every column (struct children
     flattened in order) contributes its raw uint64 view to a per-row
     linear combine under distinct per-column odd salts; ONE splitmix
@@ -189,17 +201,26 @@ def batch_fingerprint(batch: pa.RecordBatch) -> Tuple[int, int]:
     mixing pass (instead of two per column) is what holds the always-on
     overhead down; the linear pre-combine admits only contrived
     cancellations, far below the accidental-corruption signal this
-    ledger exists to catch."""
+    ledger exists to catch.
+
+    A string column's uint64s are its rows' pandas hashes, computed once
+    per distinct value where those are few and row by row where they are
+    not (`types.hash_string_array` decides from the column itself): the
+    same bits by either route, so the digest does not depend on it. A
+    caller's `tally` gets [string columns hashed, of them through a
+    dictionary] added."""
     n = batch.num_rows
     if n == 0:
         return 0, 0
+    if tally is None:
+        tally = [0, 0]
     cols: List[np.ndarray] = []
     for col in batch.columns:
         if pa.types.is_struct(col.type):
             for j in range(col.type.num_fields):
-                cols.append(_row_u64(col.field(j)))
+                cols.append(_row_u64(col.field(j), tally))
             continue
-        cols.append(_row_u64(col))
+        cols.append(_row_u64(col, tally))
     if not cols:
         return n, (n * _EMPTY_ROW) % _MOD
     from ..types import _splitmix64
@@ -218,9 +239,13 @@ def batch_fingerprint(batch: pa.RecordBatch) -> Tuple[int, int]:
 # callback drops the entry when the batch dies (before its id can be given
 # to a new object), so the memo never outgrows the batches in the queues.
 _FP_MEMO: Dict[int, Tuple[weakref.ref, int, int]] = {}
-_FP_LOCK = threading.Lock()  # the two totals; the memo needs none
+_FP_LOCK = threading.Lock()  # the totals; the memo needs none
 _FP_OBSERVED = 0
 _FP_COMPUTED = 0
+# string columns the computed fingerprints hashed, and how many of them
+# went through a dictionary (dictionary share = the second over the first)
+_FP_STRINGS = 0
+_FP_STRINGS_VIA_DICT = 0
 
 
 def _forget(key: int, ref: weakref.ref, memo=_FP_MEMO) -> None:
@@ -236,20 +261,29 @@ def _fingerprint_once(batch: pa.RecordBatch) -> Tuple[int, int]:
     """`batch_fingerprint(batch)`, computed once per batch OBJECT. A hit
     needs `ref() is batch`: an id can be reused once its object is gone.
     Each computation books the count `audit.fp` inside the caller's
-    `audit.attest`: reuse share = 1 - count(audit.fp) / count(audit.attest)."""
-    global _FP_OBSERVED, _FP_COMPUTED
+    `audit.attest`: reuse share = 1 - count(audit.fp) / count(audit.attest).
+    One that hashed string columns books `audit.fp.str` beside it, `n` of
+    `padded` = through a dictionary of all: dictionary share = n / padded."""
+    global _FP_OBSERVED, _FP_COMPUTED, _FP_STRINGS, _FP_STRINGS_VIA_DICT
     key = id(batch)
     hit = _FP_MEMO.get(key)
     computed = hit is None or hit[0]() is not batch
+    strings = via_dict = 0
     if computed:
-        n, d = batch_fingerprint(batch)
+        tally = [0, 0]
+        n, d = batch_fingerprint(batch, tally)
+        strings, via_dict = tally
         _FP_MEMO[key] = (weakref.ref(batch, partial(_forget, key)), n, d)
         timeline.note("audit.fp", 0.0, n=n)
+        if strings:
+            timeline.note("audit.fp.str", 0.0, n=via_dict, padded=strings)
     else:
         _, n, d = hit
     with _FP_LOCK:
         _FP_OBSERVED += 1
         _FP_COMPUTED += computed
+        _FP_STRINGS += strings
+        _FP_STRINGS_VIA_DICT += via_dict
     return n, d
 
 
@@ -571,12 +605,17 @@ def status(job_id: Optional[str] = None) -> dict:
         return r.status() if r is not None else {"job": job_id}
     with _FP_LOCK:
         observed, computed = _FP_OBSERVED, _FP_COMPUTED
+        strings, via_dict = _FP_STRINGS, _FP_STRINGS_VIA_DICT
     return {
         "enabled": enabled(),
         # process-wide: observations of a batch by a tap, and how many of
         # them computed a fingerprint (the rest took the batch object's)
         "fingerprints_observed": observed,
         "fingerprints_computed": computed,
+        # string columns those computations hashed, and how many of them
+        # once per distinct value (through the column's dictionary)
+        "string_columns_hashed": strings,
+        "string_columns_via_dictionary": via_dict,
         "jobs": {jid: r.status() for jid, r in recs.items()},
     }
 
@@ -592,12 +631,13 @@ def expunge_job(job_id: str) -> None:
 def reset() -> None:
     """Test hygiene: drop all reconcilers, the breach ring AND the
     fingerprint memo with its totals."""
-    global _SEQ, _FP_OBSERVED, _FP_COMPUTED
+    global _SEQ, _FP_OBSERVED, _FP_COMPUTED, _FP_STRINGS, _FP_STRINGS_VIA_DICT
     with _REG_LOCK:
         _RECONCILERS.clear()
     _FP_MEMO.clear()
     with _FP_LOCK:
         _FP_OBSERVED = _FP_COMPUTED = 0
+        _FP_STRINGS = _FP_STRINGS_VIA_DICT = 0
     with _RING_LOCK:
         _RING.clear()
         _SEQ = 0

@@ -206,13 +206,14 @@ def open_phase() -> Optional[phase]:
 
 
 def note(phase: str, dur_s: float, *, job: Optional[str] = None,
-         task: str = "", n: int = 0, key=None) -> None:
+         task: str = "", n: int = 0, key=None, padded: int = 0) -> None:
     """Record one phase instant (duration ending now) for a caller that
     has the duration in hand. `job` defaults to the ambient attribution
-    context; inside an open `phase` the duration counts as its child."""
+    context; inside an open `phase` the duration counts as its child.
+    `padded`, as on a `phase`: the whole that `n` is the real part of."""
     if _capacity() <= 0:
         return
-    _book(phase, dur_s, 0.0, job, task, n, key, 0)
+    _book(phase, dur_s, 0.0, job, task, n, key, padded)
 
 
 def snapshot(job: Optional[str] = None) -> List[dict]:

@@ -15,7 +15,10 @@ from typing import Iterable, Optional, Sequence
 import numpy as np
 import pyarrow as pa
 
-from .types import hash_arrays, hash_column, server_for_hash_array
+from .types import (
+    NULL_STRING, hash_arrays, hash_column, hash_string_array, is_string_type,
+    server_for_hash_array,
+)
 
 TIMESTAMP_FIELD = "_timestamp"
 TIMESTAMP_TYPE = pa.timestamp("ns")
@@ -151,6 +154,8 @@ class StreamSchema:
 
 
 def _hash_one(col: pa.Array) -> np.ndarray:
+    if is_string_type(col.type):
+        return hash_string_array(col)[0]
     if col.null_count:
         # nulls hash as a fixed sentinel: substitute before hashing
         col = col.fill_null(_null_sentinel(col.type))
@@ -173,4 +178,4 @@ def _null_sentinel(t: pa.DataType):
         return False
     if pa.types.is_timestamp(t):
         return 0
-    return "\x00__null__"
+    return NULL_STRING

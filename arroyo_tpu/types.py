@@ -18,6 +18,7 @@ import uuid
 from typing import Optional, Union
 
 import numpy as np
+import pyarrow as pa
 
 # ---------------------------------------------------------------------------
 # Ids
@@ -263,6 +264,14 @@ def server_for_hash_array(hashes: np.ndarray, n: int) -> np.ndarray:
 # finalizer over per-column hashes, combined with multiply-rotate; columns of
 # string/binary type are hashed via pandas' vectorized siphash
 # (pandas.util.hash_array) which is deterministic for a fixed hash_key.
+#
+# A string's hash is a function of its bytes and the key alone, so an Arrow
+# string column is hashed once per DISTINCT value (`hash_string_array`: the
+# column's dictionary through `hash_column`, then a take by the indices)
+# where the distinct values are few, and row by row through an object array
+# where they are not. Both routes give every row the same uint64, bit for
+# bit: digests, shuffle owners and state key ranges do not depend on the
+# route, only the cost does.
 # ---------------------------------------------------------------------------
 
 HASH_SEED = np.uint64(0x243F6A8885A308D3)  # fixed so checkpoints are portable
@@ -307,3 +316,73 @@ def hash_column(values) -> np.ndarray:
     return pandas.util.hash_array(
         arr.astype(object), hash_key=_PANDAS_HASH_KEY, categorize=False
     ).astype(np.uint64)
+
+
+# what a null hashes as in a column of strings (and of any other type with
+# no numeric sentinel: `schema._null_sentinel`)
+NULL_STRING = "\x00__null__"
+
+# `hash_string_array` encodes the first sixteenth of a column before the
+# whole: values drawn evenly from n/2 distinct ones show about 15/16 of such
+# a prefix distinct, and from there on a dictionary costs more than it saves
+# (on 8,192 rows the two routes meet near 3,500 distinct values); the probe
+# costs a few hundredths of the row-by-row route
+_PROBE_SHARE = 16
+_PROBE_DISTINCT = 15 / 16
+# under this many rows the encoding's fixed cost (tens of microseconds)
+# is more than the per-row hashes it could save
+_DICTIONARY_MIN_ROWS = 256
+
+
+def is_string_type(t: pa.DataType) -> bool:
+    """`string` / `large_string`, plain or as a dictionary's values: the
+    columns `hash_string_array` takes."""
+    if pa.types.is_dictionary(t):
+        t = t.value_type
+    return pa.types.is_string(t) or pa.types.is_large_string(t)
+
+
+def hash_string_array(col: pa.Array) -> tuple[np.ndarray, bool]:
+    """(`hash_column` of every row of a string column with its nulls
+    replaced by `NULL_STRING`, whether it went through a dictionary).
+
+    Through the dictionary: one siphash per distinct value, then a take
+    by the indices; a null takes the index of `NULL_STRING`, appended to
+    the values. A column that arrives dictionary-typed uses its own
+    dictionary. The dictionary pays only while it is short, which is
+    decided from the data, a prefix first: a column of a few rows, one
+    whose prefix is nearly all distinct, or one more than half distinct
+    goes row by row through an object array. Same key, same bytes per
+    value: the same uint64s either way, so the choice moves a cost and
+    never a result."""
+    n = len(col)
+    if n < _DICTIONARY_MIN_ROWS:
+        return _hash_strings_by_row(col), False
+    if pa.types.is_dictionary(col.type):
+        enc = col
+    else:
+        head = col.slice(0, n // _PROBE_SHARE).dictionary_encode()
+        if len(head.dictionary) > _PROBE_DISTINCT * len(head):
+            return _hash_strings_by_row(col), False
+        enc = col.dictionary_encode()
+    values = enc.dictionary
+    if 2 * len(values) >= n:
+        return _hash_strings_by_row(col), False
+    if values.null_count:  # only a dictionary made elsewhere holds nulls
+        values = values.fill_null(NULL_STRING)
+    distinct = np.empty(len(values) + 1, dtype=object)
+    distinct[:-1] = values.to_numpy(zero_copy_only=False)
+    distinct[-1] = NULL_STRING
+    hashes = hash_column(distinct)
+    idx = enc.indices
+    if idx.null_count:
+        idx = idx.fill_null(len(values))
+    return hashes.take(idx.to_numpy()), True
+
+
+def _hash_strings_by_row(col: pa.Array) -> np.ndarray:
+    if pa.types.is_dictionary(col.type):
+        col = col.dictionary_decode()
+    if col.null_count:
+        col = col.fill_null(NULL_STRING)
+    return hash_column(col.to_numpy(zero_copy_only=False))

@@ -158,6 +158,16 @@ def test_a_fingerprint_is_computed_once_per_batch_object(
         batch.num_rows for _edge, batch in watch.seen)
     assert t["audit.fp"]["count"] == computed
     assert t["audit.fp"]["total_s"] == 0.0
+    # string columns are counted where they are hashed (ISSUE 29): once per
+    # computed fingerprint of a batch that has one, never on a memo hit
+    seen = {id(b): b for _edge, b in watch.seen}
+    strings = sum(pa.types.is_string(f.type)
+                  for b in seen.values() for f in b.schema)
+    assert strings > 100
+    assert status["string_columns_hashed"] == strings
+    assert t["audit.fp.str"]["padded"] == strings
+    assert (t["audit.fp.str"]["n"]
+            == status["string_columns_via_dictionary"] <= strings)
     if n_workers == 1:
         # an in-process receiver never computes: the sink's task books its
         # `audit.attest` and no `audit.fp`; a task in the middle computes

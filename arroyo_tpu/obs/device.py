@@ -244,16 +244,22 @@ class InstrumentedJit:
         )
 
     def __call__(self, *args, rung: Optional[int] = None,
-                 rows: Optional[int] = None):
+                 rows: Optional[int] = None,
+                 padded: Optional[int] = None):
+        """`rows` of the call's buffers are real, `padded` is what they
+        hold: the rung itself unless the caller says otherwise (a mesh
+        program's buffers hold S or S x S cells of its rung)."""
         if not enabled():
             return self.fn(*args)
         if rows is not None and rung is not None:
+            if padded is None:
+                padded = rung
             self._rows[0] += rows
-            self._rows[1] += rung
+            self._rows[1] += padded
             open_phase = timeline.open_phase()
             if open_phase is not None:
                 open_phase.n += rows
-                open_phase.padded += rung
+                open_phase.padded += padded
         key = signature_key(args)
         fresh = key not in self.seen
         start_us = time.time() * 1e6

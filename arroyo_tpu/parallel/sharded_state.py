@@ -76,6 +76,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..obs import device as obs_device
+from ..obs import timeline
 from ..ops.aggregates import (
     Accumulator,
     AggSpec,
@@ -98,9 +99,11 @@ STRIDE = 1 << 32
 # the dispatch amortization (device steps per engine update call).
 # flushes_elided counts state reads that skipped the pre-read flush
 # because no pending update row touched the slots being read.
+# rows_busiest: of rows_sent, the rows whose owner was their step's
+# busiest destination shard (rows_sent / n_shards when keys spread evenly).
 MESH_STATS = {"rows_sent": 0, "rows_padded": 0,
               "dispatches": 0, "updates": 0, "flushes_elided": 0,
-              "rows_combined": 0}
+              "rows_combined": 0, "rows_busiest": 0}
 
 
 class MeshSlotDirectory:
@@ -971,39 +974,48 @@ class ShardedAccumulator(Accumulator):
         if n == 0:
             return
         self._check_signed(signs)
-        self._update_host(slots, cols, signs)
-        if not self.phys:
-            return
-        MESH_STATS["updates"] += 1
-        slots = np.asarray(slots)
-        max_local = int((slots % STRIDE).max())
-        if max_local >= self.capacity - 1:
-            # jit scatters silently drop out-of-bounds updates — callers
-            # must grow() first (windows.py _ensure_capacity does);
-            # checked at update() time (capacity only ever grows before a
-            # deferred flush, so the buffered check stays valid)
-            raise ValueError(
-                f"shard accumulator capacity exceeded: local slot "
-                f"{max_local} >= capacity-1={self.capacity - 1}"
-            )
-        from ..ops.aggregates import _src_values
+        # the leaves of ops/aggregates.py's one-device update, so that one
+        # table reads both paths: `agg.pack` is every host step between
+        # the operator's scatter and the jitted call (here the batch's
+        # part; the flush books the rows), `agg.enqueue` the call
+        with timeline.phase("agg.pack"):
+            self._update_host(slots, cols, signs)
+            if not self.phys:
+                return
+            MESH_STATS["updates"] += 1
+            slots = np.asarray(slots)
+            max_local = int((slots % STRIDE).max())
+            if max_local >= self.capacity - 1:
+                # jit scatters silently drop out-of-bounds updates —
+                # callers must grow() first (windows.py _ensure_capacity
+                # does); checked at update() time (capacity only ever
+                # grows before a deferred flush, so the buffered check
+                # stays valid)
+                raise ValueError(
+                    f"shard accumulator capacity exceeded: local slot "
+                    f"{max_local} >= capacity-1={self.capacity - 1}"
+                )
+            from ..ops.aggregates import _src_values
 
-        vals = [
-            np.asarray(_src_values(self.specs[si], src, cols))
-            for op, dt, src, si in self.phys if src != "one"
-        ]
-        self._ewma_rows = (
-            n if not self._ewma_rows else (self._ewma_rows * 7 + n) // 8
-        )
-        thr = self._flush_threshold()
-        if thr <= n and not self._pending:
+            vals = [
+                np.asarray(_src_values(self.specs[si], src, cols))
+                for op, dt, src, si in self.phys if src != "one"
+            ]
+            self._ewma_rows = (
+                n if not self._ewma_rows
+                else (self._ewma_rows * 7 + n) // 8
+            )
+            thr = self._flush_threshold()
+            buffered = thr > n or bool(self._pending)
+            if buffered:
+                self._pending.append(
+                    (slots, vals,
+                     None if signs is None else np.asarray(signs))
+                )
+                self._pending_rows += n
+        if not buffered:
             self._dispatch_rows(slots, vals, signs)
-            return
-        self._pending.append(
-            (slots, vals, None if signs is None else np.asarray(signs))
-        )
-        self._pending_rows += n
-        if self._pending_rows >= thr:
+        elif self._pending_rows >= thr:
             self.flush()
 
     def _flush_threshold(self) -> int:
@@ -1050,22 +1062,26 @@ class ShardedAccumulator(Accumulator):
         if len(self._pending) == 1:
             slots, vals, signs = self._pending[0]
         else:
-            slots = np.concatenate([p[0] for p in self._pending])
-            vals = [
-                np.concatenate([p[1][i] for p in self._pending])
-                for i in range(len(self._pending[0][1]))
-            ]
-            if any(p[2] is not None for p in self._pending):
-                signs = np.concatenate([
-                    p[2] if p[2] is not None
-                    else np.ones(len(p[0]), dtype=np.int64)
-                    for p in self._pending
-                ])
-            else:
-                signs = None
+            with timeline.phase("agg.pack"):
+                slots, vals, signs = self._concat_pending()
         self._pending = []
         self._pending_rows = 0
         self._dispatch_rows(slots, vals, signs)
+
+    def _concat_pending(self):
+        slots = np.concatenate([p[0] for p in self._pending])
+        vals = [
+            np.concatenate([p[1][i] for p in self._pending])
+            for i in range(len(self._pending[0][1]))
+        ]
+        signs = None
+        if any(p[2] is not None for p in self._pending):
+            signs = np.concatenate([
+                p[2] if p[2] is not None
+                else np.ones(len(p[0]), dtype=np.int64)
+                for p in self._pending
+            ])
+        return slots, vals, signs
 
     def _prereduce(self, slots: np.ndarray, vals: List[np.ndarray],
                    signs: Optional[np.ndarray]):
@@ -1143,10 +1159,23 @@ class ShardedAccumulator(Accumulator):
             # duplicate-slot reduction — no host combiner on this path
             self._dispatch_rows_device(slots, vals, signs)
             return
-        slots, vals, signs = self._prereduce(slots, vals, signs)
+        with timeline.phase("mesh.combine", n=len(slots)):
+            slots, vals, signs = self._prereduce(slots, vals, signs)
+        # the layout of every step this flush takes, then the steps
+        with timeline.phase("agg.pack"):
+            steps = self._layout_steps(slots)
+            locals_ = slots % STRIDE
+        for program, step, shape, rows, flat, busiest in steps:
+            self._note_traffic(len(rows), int(np.prod(shape)), program,
+                               shape[-1], busiest)
+            self._dispatch(step, shape, rows, flat, locals_, vals, signs)
+
+    def _layout_steps(self, slots: np.ndarray) -> List[tuple]:
+        """(program, step, buffer shape, row indices, flat positions, rows
+        of the busiest destination) of each step that ships `slots`."""
         n = len(slots)
         S, R = self.n_shards, self.rows_per_shard
-        owners, locals_ = self._decompose(slots)
+        owners = slots // STRIDE
         if self.salted:
             # balanced spread: every shard takes ~n/S rows of each group;
             # the cross-shard fold happens at gather
@@ -1155,6 +1184,7 @@ class ShardedAccumulator(Accumulator):
         so = owners[order]
         starts = np.searchsorted(so, so, side="left")
         pos = np.arange(n, dtype=np.int64) - starts   # rank within owner
+        steps = []
         if self.host_fed:
             # dst-major [S, R] direct layout: the host already sees every
             # row, so the key shuffle happens at packing time and the
@@ -1163,15 +1193,15 @@ class ShardedAccumulator(Accumulator):
             chunk = pos // r_cap
             for c in range(int(chunk.max()) + 1):
                 in_chunk = chunk == c
-                rows = order[in_chunk]
                 pm = pos[in_chunk] - c * r_cap
+                dst = so[in_chunk]
                 r_c = self._rung_direct.fit(int(pm.max()) + 1)
-                flat = so[in_chunk] * r_c + pm
-                self._note_traffic(len(rows), S * r_c,
-                                   "mesh.step_direct", r_c)
-                self._dispatch(self._direct_step(), (S, r_c), rows, flat,
-                               locals_, vals, signs)
-            return
+                steps.append((
+                    "mesh.step_direct", self._direct_step(), (S, r_c),
+                    order[in_chunk], dst * r_c + pm,
+                    int(np.bincount(dst).max()),
+                ))
+            return steps
         # Balanced packing into the [src, dst, row] all_to_all layout:
         # each destination shard's rows are dealt round-robin across the
         # S source positions, so every (src, dst) cell carries
@@ -1185,13 +1215,15 @@ class ShardedAccumulator(Accumulator):
         chunk = cell // R
         for c in range(int(chunk.max()) + 1):
             in_chunk = chunk == c
-            rows = order[in_chunk]
             cm = cell[in_chunk] - c * R
+            dst = so[in_chunk]
             r_c = self._rung_a2a.fit(int(cm.max()) + 1)
-            flat = (srcs[in_chunk] * S + so[in_chunk]) * r_c + cm
-            self._note_traffic(len(rows), S * S * r_c, "mesh.step", r_c)
-            self._dispatch(self._step(), (S, S, r_c), rows, flat, locals_,
-                           vals, signs)
+            steps.append((
+                "mesh.step", self._step(), (S, S, r_c), order[in_chunk],
+                (srcs[in_chunk] * S + dst) * r_c + cm,
+                int(np.bincount(dst).max()),
+            ))
+        return steps
 
     def _dispatch_rows_device(self, slots: np.ndarray,
                               vals: List[np.ndarray],
@@ -1204,58 +1236,69 @@ class ShardedAccumulator(Accumulator):
         n = len(slots)
         S = self.n_shards
         cap = self.capacity
-        C = self._rung_chunk.fit(-(-n // S))      # rows per source shard
-        N = C * S
-        # padding rows: owner spread evenly, local = scratch, valid 0
-        enc = np.empty(N, dtype=np.int64)
-        enc[:n] = slots
-        pad_pos = np.arange(n, N, dtype=np.int64)
-        enc[n:] = (pad_pos % S) * STRIDE + (cap - 1)
-        valid = np.zeros(N, dtype=np.int64)
-        valid[:n] = 1 if signs is None else signs
-        if self.salted:
-            # positional round-robin spread: every (src, dst) cell holds
-            # exactly ceil(C / S) rows — no skew, no bincount
-            R = -(-C // S)
-        else:
-            owners = enc // STRIDE
-            srcs = np.arange(N, dtype=np.int64) // C
-            R = self._rung_cell.fit(
-                int(np.bincount(srcs * S + owners,
-                                minlength=S * S).max())
-            )
-            R = min(R, C)
-        inputs = []
-        vi = 0
-        for op, dt, src, si in self.phys:
-            if src == "one":
-                continue
-            v = np.full(
-                N,
-                0 if op == "add" else self._neutral(op, dt),
-                dtype=self._dt(dt),
-            )
-            v[:n] = vals[vi]
-            vi += 1
-            inputs.append(self._to_dev(v.reshape(S, C), True))
+        with timeline.phase("agg.pack", n=n):
+            C = self._rung_chunk.fit(-(-n // S))  # rows per source shard
+            N = C * S
+            # padding rows: owner spread evenly, local = scratch, valid 0
+            enc = np.empty(N, dtype=np.int64)
+            enc[:n] = slots
+            pad_pos = np.arange(n, N, dtype=np.int64)
+            enc[n:] = (pad_pos % S) * STRIDE + (cap - 1)
+            valid = np.zeros(N, dtype=np.int64)
+            valid[:n] = 1 if signs is None else signs
+            if self.salted:
+                # positional round-robin spread: every (src, dst) cell
+                # holds exactly ceil(C / S) rows — no skew, no bincount
+                R = -(-C // S)
+                busiest = -(-n // S)
+            else:
+                owners = enc // STRIDE
+                srcs = np.arange(N, dtype=np.int64) // C
+                cells = np.bincount(srcs * S + owners, minlength=S * S)
+                R = min(self._rung_cell.fit(int(cells.max())), C)
+                # real rows per destination: the cells' less the filler's
+                to = np.arange(S)
+                filler = (N - 1 - to) // S - (n - 1 - to) // S
+                busiest = int((cells.reshape(S, S).sum(axis=0)
+                               - filler).max())
+            inputs = []
+            vi = 0
+            for op, dt, src, si in self.phys:
+                if src == "one":
+                    continue
+                v = np.full(
+                    N,
+                    0 if op == "add" else self._neutral(op, dt),
+                    dtype=self._dt(dt),
+                )
+                v[:n] = vals[vi]
+                vi += 1
+                inputs.append(self._to_dev(v.reshape(S, C), True))
+            enc_d = self._to_dev(enc.reshape(S, C), True)
+            valid_d = self._to_dev(valid.reshape(S, C), True)
         MESH_STATS["dispatches"] += 1
         # exchange-layer filler: rung padding (N - n) plus all_to_all
         # cell padding (S*S*R - N); both ride the collective
-        self._note_traffic(n, max(S * S * R, N), "mesh.route", R)
-        self.state = self._route_step(C, R)(
-            self.state,
-            self._to_dev(enc.reshape(S, C), True),
-            self._to_dev(valid.reshape(S, C), True),
-            *inputs,
-            rung=R,
-        )
+        shipped = max(S * S * R, N)
+        self._note_traffic(n, shipped, "mesh.route", R, busiest)
+        with timeline.phase("agg.enqueue"):
+            self.state = self._route_step(C, R)(
+                self.state, enc_d, valid_d, *inputs,
+                rung=R, rows=n, padded=shipped,
+            )
 
-    def _note_traffic(self, sent: int, shipped: int,
-                      program: str = "mesh.step", rung: int = 0):
+    def _note_traffic(self, sent: int, shipped: int, program: str,
+                      rung: int, busiest: int):
+        """One exchange step's counts: `sent` real rows in buffers of
+        `shipped`, `busiest` of them owned by the destination shard that
+        took the most."""
         self.rows_sent += sent
         self.rows_padded += shipped - sent
         MESH_STATS["rows_sent"] += sent
         MESH_STATS["rows_padded"] += shipped - sent
+        MESH_STATS["rows_busiest"] += busiest
+        timeline.note("mesh.ship", 0.0, n=sent, padded=shipped)
+        timeline.note("mesh.ship.hot", 0.0, n=busiest, padded=sent)
         # per-(program, rung) waste gauge: which packing rungs the
         # exchange actually hits and how much filler each ships
         obs_device.note_padding(program, rung, sent, shipped)
@@ -1268,32 +1311,33 @@ class ShardedAccumulator(Accumulator):
         at update() time so buffered flushes just concatenate."""
         MESH_STATS["dispatches"] += 1
         total = int(np.prod(shape))
-        slots_l = np.full(total, self.capacity - 1, dtype=np.int64)
-        slots_l[flat] = locals_[rows]
-        valid = np.zeros(total, dtype=np.int64)
-        valid[flat] = 1 if signs is None else signs[rows]
-        inputs = []
-        vi = 0
-        for op, dt, src, si in self.phys:
-            if src == "one":
-                continue
-            v = np.full(
-                total,
-                0 if op == "add" else self._neutral(op, dt),
-                dtype=self._dt(dt),
+        with timeline.phase("agg.pack", n=len(rows)):
+            slots_l = np.full(total, self.capacity - 1, dtype=np.int64)
+            slots_l[flat] = locals_[rows]
+            valid = np.zeros(total, dtype=np.int64)
+            valid[flat] = 1 if signs is None else signs[rows]
+            inputs = []
+            vi = 0
+            for op, dt, src, si in self.phys:
+                if src == "one":
+                    continue
+                v = np.full(
+                    total,
+                    0 if op == "add" else self._neutral(op, dt),
+                    dtype=self._dt(dt),
+                )
+                # sign application happens in-kernel: add-sources
+                # multiply by valid (0 padding / ±1 append-retract)
+                v[flat] = vals[vi][rows]
+                vi += 1
+                inputs.append(self._to_dev(v.reshape(shape), True))
+            slots_d = self._to_dev(slots_l.reshape(shape), True)
+            valid_d = self._to_dev(valid.reshape(shape), True)
+        with timeline.phase("agg.enqueue"):
+            self.state = step(
+                self.state, slots_d, valid_d, *inputs,
+                rung=shape[-1], rows=len(rows), padded=total,
             )
-            # sign application happens in-kernel: add-sources multiply by
-            # valid (0 padding / ±1 append-retract)
-            v[flat] = vals[vi][rows]
-            vi += 1
-            inputs.append(self._to_dev(v.reshape(shape), True))
-        self.state = step(
-            self.state,
-            self._to_dev(slots_l.reshape(shape), True),
-            self._to_dev(valid.reshape(shape), True),
-            *inputs,
-            rung=shape[-1],
-        )
 
     def _step(self):
         return self._program("step", self._make_step)
@@ -1332,7 +1376,7 @@ class ShardedAccumulator(Accumulator):
         n_state = len(self.phys)
 
         @partial(jax.jit, donate_argnums=(0,), static_argnums=())
-        def step(state, slots, valid, *vals):
+        def mesh_step(state, slots, valid, *vals):
             from jax.sharding import PartitionSpec as P
 
             f = jax.shard_map(
@@ -1348,7 +1392,8 @@ class ShardedAccumulator(Accumulator):
             )
             return list(f(tuple(state), slots, valid, *vals))
 
-        return obs_device.InstrumentedJit("mesh.step", step, exchange=True)
+        return obs_device.InstrumentedJit("mesh.step", mesh_step,
+                                          exchange=True)
 
     def _make_direct_step(self):
         """Step for host-fed dst-major [S, R] batches: rows were routed to
@@ -1373,7 +1418,7 @@ class ShardedAccumulator(Accumulator):
         n_state = len(self.phys)
 
         @partial(jax.jit, donate_argnums=(0,), static_argnums=())
-        def step(state, slots, valid, *vals):
+        def mesh_step_direct(state, slots, valid, *vals):
             from jax.sharding import PartitionSpec as P
 
             f = jax.shard_map(
@@ -1389,8 +1434,8 @@ class ShardedAccumulator(Accumulator):
             )
             return list(f(tuple(state), slots, valid, *vals))
 
-        return obs_device.InstrumentedJit("mesh.step_direct", step,
-                                          exchange=True)
+        return obs_device.InstrumentedJit(
+            "mesh.step_direct", mesh_step_direct, exchange=True)
 
     def _make_route_step(self, C: int, R: int):
         """The fused route+scatter+reduce program of the device-resident
@@ -1504,7 +1549,7 @@ class ShardedAccumulator(Accumulator):
         n_state = len(self.phys)
 
         @partial(jax.jit, donate_argnums=(0,), static_argnums=())
-        def step(state, enc, valid, *vals):
+        def mesh_route(state, enc, valid, *vals):
             from jax.sharding import PartitionSpec as P
 
             f = jax.shard_map(
@@ -1520,7 +1565,8 @@ class ShardedAccumulator(Accumulator):
             )
             return list(f(tuple(state), enc, valid, *vals))
 
-        return obs_device.InstrumentedJit("mesh.route", step, exchange=True)
+        return obs_device.InstrumentedJit("mesh.route", mesh_route,
+                                          exchange=True)
 
     # -- drain --------------------------------------------------------------
     #
@@ -1613,7 +1659,7 @@ class ShardedAccumulator(Accumulator):
                 return tuple(s[0][loc[0]][None, :] for s in state_shards)
 
             @jax.jit
-            def fn(state, loc):
+            def mesh_sgather(state, loc):
                 from jax.sharding import PartitionSpec as P
 
                 f = jax.shard_map(
@@ -1627,7 +1673,7 @@ class ShardedAccumulator(Accumulator):
                 )
                 return list(f(tuple(state), loc))
 
-            return obs_device.InstrumentedJit("mesh.sgather", fn)
+            return obs_device.InstrumentedJit("mesh.sgather", mesh_sgather)
 
         return self._program("sgather", build)
 
@@ -1659,7 +1705,7 @@ class ShardedAccumulator(Accumulator):
                 return tuple(outs), tuple(new)
 
             @partial(jax.jit, donate_argnums=(0,))
-            def fn(state, loc, free):
+            def mesh_stake(state, loc, free):
                 from jax.sharding import PartitionSpec as P
 
                 f = jax.shard_map(
@@ -1678,7 +1724,7 @@ class ShardedAccumulator(Accumulator):
                 outs, new = f(tuple(state), loc, free)
                 return list(outs), list(new)
 
-            return obs_device.InstrumentedJit("mesh.stake", fn)
+            return obs_device.InstrumentedJit("mesh.stake", mesh_stake)
 
         return self._program("stake", build)
 
@@ -1698,7 +1744,7 @@ class ShardedAccumulator(Accumulator):
                 )
 
             @partial(jax.jit, donate_argnums=(0,))
-            def fn(state, loc):
+            def mesh_sreset(state, loc):
                 from jax.sharding import PartitionSpec as P
 
                 f = jax.shard_map(
@@ -1712,7 +1758,7 @@ class ShardedAccumulator(Accumulator):
                 )
                 return list(f(tuple(state), loc))
 
-            return obs_device.InstrumentedJit("mesh.sreset", fn)
+            return obs_device.InstrumentedJit("mesh.sreset", mesh_sreset)
 
         return self._program("sreset", build)
 
@@ -1730,7 +1776,7 @@ class ShardedAccumulator(Accumulator):
                 )
 
             @partial(jax.jit, donate_argnums=(0,))
-            def fn(state, loc, *vals):
+            def mesh_srestore(state, loc, *vals):
                 from jax.sharding import PartitionSpec as P
 
                 f = jax.shard_map(
@@ -1745,7 +1791,7 @@ class ShardedAccumulator(Accumulator):
                 )
                 return list(f(tuple(state), loc, *vals))
 
-            return obs_device.InstrumentedJit("mesh.srestore", fn)
+            return obs_device.InstrumentedJit("mesh.srestore", mesh_srestore)
 
         return self._program("srestore", build)
 
@@ -1754,24 +1800,31 @@ class ShardedAccumulator(Accumulator):
         """Owner-sliced gather (free=None) or fused gather+masked-reset,
         returning host arrays in the wave's original order."""
         n = len(slots)
-        if free is None:
-            loc_sl, _, flat_pos, L = self._slice_pack(slots)
-            obs_device.note_padding("mesh.sgather", L, n,
-                                    self.n_shards * L)
-            outs = self._sliced_gather_program()(
-                self.state, self._to_dev(loc_sl, True), rung=L,
-            )
-        else:
-            loc_sl, (free_sl,), flat_pos, L = self._slice_pack(
-                slots, (np.asarray(free, dtype=np.int64),), (0,)
-            )
-            obs_device.note_padding("mesh.stake", L, n,
-                                    self.n_shards * L)
-            outs, self.state = self._sliced_take_program()(
-                self.state, self._to_dev(loc_sl, True),
-                self._to_dev(free_sl, True), rung=L,
-            )
-        return [np.asarray(o).reshape(-1)[flat_pos] for o in outs]
+        # sub-steps of the caller's close.combine, in the ledger only, as
+        # in ops/aggregates.py: the pack and the call, then the read
+        with timeline.phase("agg.gather", annotate=False):
+            if free is None:
+                loc_sl, _, flat_pos, L = self._slice_pack(slots)
+                obs_device.note_padding("mesh.sgather", L, n,
+                                        self.n_shards * L)
+                outs = self._sliced_gather_program()(
+                    self.state, self._to_dev(loc_sl, True),
+                    rung=L, rows=n, padded=self.n_shards * L,
+                )
+            else:
+                loc_sl, (free_sl,), flat_pos, L = self._slice_pack(
+                    slots, (np.asarray(free, dtype=np.int64),), (0,)
+                )
+                obs_device.note_padding("mesh.stake", L, n,
+                                        self.n_shards * L)
+                outs, self.state = self._sliced_take_program()(
+                    self.state, self._to_dev(loc_sl, True),
+                    self._to_dev(free_sl, True),
+                    rung=L, rows=n, padded=self.n_shards * L,
+                )
+        # the device-to-host read: waits for every program queued before
+        with timeline.phase("agg.read", n=n, annotate=False):
+            return [np.asarray(o).reshape(-1)[flat_pos] for o in outs]
 
     def _gather_program(self):
         def build():
@@ -1781,7 +1834,7 @@ class ShardedAccumulator(Accumulator):
 
             if self.salted:
 
-                def gather_fn(state, sh, loc):
+                def mesh_gather(state, sh, loc):
                     # fold across the shard axis; padding rows point at
                     # the scratch slot, neutral on every shard
                     out = []
@@ -1796,7 +1849,7 @@ class ShardedAccumulator(Accumulator):
                     return out
             else:
 
-                def gather_fn(state, sh, loc):
+                def mesh_gather(state, sh, loc):
                     return [s[sh, loc] for s in state]
 
             if self._multiproc:
@@ -1806,13 +1859,13 @@ class ShardedAccumulator(Accumulator):
                 from jax.sharding import NamedSharding
                 from jax.sharding import PartitionSpec as P
 
-                gather_fn = jax.jit(
-                    gather_fn,
+                mesh_gather = jax.jit(
+                    mesh_gather,
                     out_shardings=NamedSharding(self.mesh, P()),
                 )
             else:
-                gather_fn = jax.jit(gather_fn)
-            return obs_device.InstrumentedJit("mesh.gather", gather_fn)
+                mesh_gather = jax.jit(mesh_gather)
+            return obs_device.InstrumentedJit("mesh.gather", mesh_gather)
 
         return self._program("gather", build)
 
@@ -1824,7 +1877,7 @@ class ShardedAccumulator(Accumulator):
             salted = self.salted
             neutral = self._neutral
 
-            def take_fn(state, sh, loc):
+            def mesh_take(state, sh, loc):
                 outs, new = [], []
                 for (op, dt, _, _), s in zip(phys, state):
                     if salted:
@@ -1848,7 +1901,7 @@ class ShardedAccumulator(Accumulator):
             return obs_device.InstrumentedJit(
                 "mesh.take",
                 jax.jit(
-                    take_fn,
+                    mesh_take,
                     donate_argnums=(0,),
                     # outs replicated (each process reads its local
                     # copy), state stays row-sharded
@@ -1871,7 +1924,7 @@ class ShardedAccumulator(Accumulator):
 
             @partial(jax.jit, donate_argnums=(0,),
                      out_shardings=self._sharding)
-            def reset_fn(state, sh, loc):
+            def mesh_reset(state, sh, loc):
                 if salted:
                     # a salted slot's state lives on EVERY shard
                     return [
@@ -1883,7 +1936,7 @@ class ShardedAccumulator(Accumulator):
                     for s, (op, dt, _, _) in zip(state, phys)
                 ]
 
-            return obs_device.InstrumentedJit("mesh.reset", reset_fn)
+            return obs_device.InstrumentedJit("mesh.reset", mesh_reset)
 
         return self._program("reset", build)
 
@@ -1897,7 +1950,7 @@ class ShardedAccumulator(Accumulator):
 
             @partial(jax.jit, donate_argnums=(0,),
                      out_shardings=self._sharding)
-            def restore_fn(state, sh, loc, *vals):
+            def mesh_restore(state, sh, loc, *vals):
                 if salted:
                     # restored value lands whole on the nominal shard;
                     # the other shards go neutral so the cross-shard
@@ -1911,7 +1964,7 @@ class ShardedAccumulator(Accumulator):
                     s.at[sh, loc].set(v) for s, v in zip(state, vals)
                 ]
 
-            return obs_device.InstrumentedJit("mesh.restore", restore_fn)
+            return obs_device.InstrumentedJit("mesh.restore", mesh_restore)
 
         return self._program("restore", build)
 
@@ -1929,20 +1982,38 @@ class ShardedAccumulator(Accumulator):
             ]
         if self._sliced_ok():
             return self._sliced_read(np.asarray(slots), None)
+        return self._replicated_read(
+            self._gather_program(), np.asarray(slots),
+            materialize=materialize,
+        )
+
+    def _replicated_read(self, prog, slots: np.ndarray,
+                         free: Optional[np.ndarray] = None,
+                         materialize: bool = True) -> List[np.ndarray]:
+        """Chunked read through a replicated-index program (salted and
+        multi-process meshes): `mesh.gather` reads, the others also write
+        the state they hand back; `free` is `mesh.gather_free`'s mask."""
         from .multihost import to_host
 
-        prog = self._gather_program()
-        sh, loc = self._decompose(np.asarray(slots))
+        n = len(slots)
+        sh, loc = self._decompose(slots)
         chunks = self._chunk_bounds(n)
         pieces = []
         for lo, hi in chunks:
-            rung = self._emit_rung(hi - lo)
-            sh_p, loc_p = self._pad_slots(sh, loc, lo, hi, rung)
-            obs_device.note_padding("mesh.gather", rung, hi - lo, rung)
-            outs = prog(
-                self.state, self._to_dev(sh_p, False),
-                self._to_dev(loc_p, False), rung=rung,
-            )
+            # ledger-only sub-steps of the close, as in _sliced_read
+            with timeline.phase("agg.gather", annotate=False):
+                rung = self._emit_rung(hi - lo)
+                sh_p, loc_p = self._pad_slots(sh, loc, lo, hi, rung)
+                args = [self._to_dev(sh_p, False),
+                        self._to_dev(loc_p, False)]
+                if free is not None:
+                    free_p = np.zeros(rung, dtype=np.int64)
+                    free_p[: hi - lo] = free[lo:hi]
+                    args.append(self._to_dev(free_p, False))
+                obs_device.note_padding(prog.program, rung, hi - lo, rung)
+                outs = prog(self.state, *args, rung=rung, rows=hi - lo)
+                if prog.program != "mesh.gather":
+                    outs, self.state = outs
             if len(chunks) == 1 and not materialize:
                 if self._multiproc:
                     # replicated outputs span remote devices; hand back
@@ -1950,7 +2021,8 @@ class ShardedAccumulator(Accumulator):
                     # np.asarray work
                     outs = [o.addressable_data(0) for o in outs]
                 return [o[:n] for o in outs]
-            pieces.append([to_host(o)[: hi - lo] for o in outs])
+            with timeline.phase("agg.read", n=hi - lo, annotate=False):
+                pieces.append([to_host(o)[: hi - lo] for o in outs])
         if len(pieces) == 1:
             return pieces[0]
         return [
@@ -1981,31 +2053,10 @@ class ShardedAccumulator(Accumulator):
             return self._sliced_read(
                 np.asarray(slots), np.ones(n, dtype=np.int64)
             )
-        from .multihost import to_host
-
-        prog = self._take_program()
-        sh, loc = self._decompose(np.asarray(slots))
-        chunks = self._chunk_bounds(n)
-        pieces = []
-        for lo, hi in chunks:
-            rung = self._emit_rung(hi - lo)
-            sh_p, loc_p = self._pad_slots(sh, loc, lo, hi, rung)
-            obs_device.note_padding("mesh.take", rung, hi - lo, rung)
-            outs, self.state = prog(
-                self.state, self._to_dev(sh_p, False),
-                self._to_dev(loc_p, False), rung=rung,
-            )
-            if len(chunks) == 1 and not materialize:
-                if self._multiproc:
-                    outs = [o.addressable_data(0) for o in outs]
-                return [o[:n] for o in outs]
-            pieces.append([to_host(o)[: hi - lo] for o in outs])
-        if len(pieces) == 1:
-            return pieces[0]
-        return [
-            np.concatenate([p[i] for p in pieces])
-            for i in range(len(self.phys))
-        ]
+        return self._replicated_read(
+            self._take_program(), np.asarray(slots),
+            materialize=materialize,
+        )
 
     def _gather_free_program(self):
         """Fused sliding drain: gather the window union AND reset the
@@ -2020,7 +2071,7 @@ class ShardedAccumulator(Accumulator):
             salted = self.salted
             neutral = self._neutral
 
-            def gf_fn(state, sh, loc, free):
+            def mesh_gather_free(state, sh, loc, free):
                 outs, new = [], []
                 # masked-out rows redirect their reset to the scratch
                 # slot (already neutral), so one program serves every
@@ -2050,7 +2101,7 @@ class ShardedAccumulator(Accumulator):
             return obs_device.InstrumentedJit(
                 "mesh.gather_free",
                 jax.jit(
-                    gf_fn,
+                    mesh_gather_free,
                     donate_argnums=(0,),
                     out_shardings=(
                         [NamedSharding(self.mesh, P())] * len(self.phys),
@@ -2080,31 +2131,8 @@ class ShardedAccumulator(Accumulator):
         if self._sliced_ok():
             gathered = self._sliced_read(slots, free)
         else:
-            from .multihost import to_host
-
-            prog = self._gather_free_program()
-            sh, loc = self._decompose(slots)
-            pieces = []
-            for lo, hi in self._chunk_bounds(n):
-                rung = self._emit_rung(hi - lo)
-                sh_p, loc_p = self._pad_slots(sh, loc, lo, hi, rung)
-                free_p = np.zeros(rung, dtype=np.int64)
-                free_p[: hi - lo] = free[lo:hi]
-                obs_device.note_padding("mesh.gather_free", rung,
-                                        hi - lo, rung)
-                outs, self.state = prog(
-                    self.state, self._to_dev(sh_p, False),
-                    self._to_dev(loc_p, False),
-                    self._to_dev(free_p, False),
-                    rung=rung,
-                )
-                pieces.append([to_host(o)[: hi - lo] for o in outs])
-            gathered = (
-                pieces[0] if len(pieces) == 1
-                else [
-                    np.concatenate([p[i] for p in pieces])
-                    for i in range(len(self.phys))
-                ]
+            gathered = self._replicated_read(
+                self._gather_free_program(), slots, free
             )
         combined = self._combine_gathered(gathered, slots, seg_ids,
                                           n_segments)
@@ -2119,15 +2147,19 @@ class ShardedAccumulator(Accumulator):
         n = len(slots)
         if n == 0 or not self.phys:
             return
+        with timeline.phase("agg.reset", annotate=False):
+            self._reset_device(np.asarray(slots))
+
+    def _reset_device(self, slots: np.ndarray):
         if self._sliced_ok():
-            loc_sl, _, _, L = self._slice_pack(np.asarray(slots))
+            loc_sl, _, _, L = self._slice_pack(slots)
             self.state = self._sliced_reset_program()(
                 self.state, self._to_dev(loc_sl, True), rung=L,
             )
             return
         prog = self._reset_program()
-        sh, loc = self._decompose(np.asarray(slots))
-        for lo, hi in self._chunk_bounds(n):
+        sh, loc = self._decompose(slots)
+        for lo, hi in self._chunk_bounds(len(slots)):
             rung = self._emit_rung(hi - lo)
             sh_p, loc_p = self._pad_slots(sh, loc, lo, hi, rung)
             self.state = prog(

@@ -32,7 +32,7 @@ import time
 from typing import Dict, List, Optional
 
 from .. import chaos, obs
-from ..obs import audit
+from ..obs import audit, timeline
 from ..analysis.model.effects import protocol_effect
 from ..analysis.races import shared_state
 from ..analysis.races.sanitizer import set_task_root
@@ -694,7 +694,19 @@ class ControllerServer:
         if graph is None:
             from ..sql import plan_query
 
-            graph = plan_query(sql, parallelism=parallelism).graph
+            plan = plan_query(sql, parallelism=parallelism)
+            graph = plan.graph
+            # what each source table sends of what it declares (leaf
+            # fields; less where the planner narrowed it, sql/pruning.py)
+            for table, (kept, declared) in plan.source_fields.items():
+                timeline.note("plan.prune", 0.0, job=job_id, task=table,
+                              n=kept, padded=declared)
+            logger.info(
+                "job %s planned: %d nodes at parallelism %d; source fields "
+                "sent of declared: %s", job_id, len(graph.nodes), parallelism,
+                ", ".join(f"{t} {k}/{d}"
+                          for t, (k, d) in plan.source_fields.items()),
+            )
         # shared-plan admission (ISSUE 16): an eligible scan mounts onto
         # the shared host instead of spawning a copy. The mount directive
         # rides StartExecution so workers re-planning the canonical SQL

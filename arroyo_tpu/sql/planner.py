@@ -207,10 +207,14 @@ class RelOutput:
 
 
 class Planner:
-    def __init__(self, provider: SchemaProvider, parallelism: int = 1):
+    def __init__(self, provider: SchemaProvider, parallelism: int = 1,
+                 narrowed: Optional[Dict[str, StreamSchema]] = None):
         self.provider = provider
         self.graph = LogicalGraph()
         self.parallelism = parallelism
+        # source table (lowercased) -> the fields its readers read, where
+        # its row would cross an edge whole (sql/pruning.py)
+        self._narrowed = narrowed or {}
         self._source_cache: Dict[str, RelOutput] = {}
         self._select_plan_cache: Dict[tuple, RelOutput] = {}
         self._cache_epoch = getattr(provider, "epoch", 0)
@@ -478,6 +482,16 @@ class Planner:
                 "watermark",
             )
         )
+        # required-field pruning: the chain ends in one stateless
+        # projection to the fields the statements read, behind the ops
+        # whose state a checkpoint names by position (source, watermark);
+        # everything downstream binds against the narrowed schema
+        kept = self._narrowed.get(cache_key)
+        if kept is not None:
+            from .pruning import prune_op
+
+            chain.append(prune_op(source_schema, kept))
+            source_schema = kept
         node = self.graph.add_node(
             LogicalNode(self._next_id(), t.name, chain, parallelism=1)
         )
@@ -2433,6 +2447,12 @@ class PlanResult:
     graph: LogicalGraph
     provider: SchemaProvider
     sink_nodes: List[int]
+    # source table -> (leaf fields it hands its readers, leaf fields it
+    # declares), `_timestamp` counted: fewer where plan_query narrowed the
+    # table (sql/pruning.py)
+    source_fields: Dict[str, Tuple[int, int]] = dataclasses.field(
+        default_factory=dict
+    )
 
 
 def plan_query(
@@ -2445,7 +2465,76 @@ def plan_query(
     into a LogicalGraph (reference: parse_and_get_arrow_program)."""
     provider = provider or SchemaProvider()
     statements = parse_statements(sql)
-    planner = Planner(provider, parallelism)
+    planner, sinks = _plan_script(
+        statements, provider, parallelism, preview_results, {}
+    )
+    # required-field pruning engages from what that plan shows: a source
+    # whose node still ends at its watermark op has no consumer chained
+    # behind it, so its whole row crosses every out-edge (a fan-out, a
+    # consumer at another parallelism, chaining off). Where the statements
+    # read less than such a table declares, the script is planned again
+    # with the table narrowed; every other plan is left op for op
+    narrowed = _narrowable_sources(planner.graph, provider, statements)
+    if narrowed:
+        planner, sinks = _plan_script(
+            statements, provider, parallelism, preview_results, narrowed
+        )
+    from .pruning import leaf_count
+
+    fields = {}
+    for node in planner.graph.nodes.values():
+        if not node.is_source:
+            continue
+        declared = node.head.config["schema"]
+        sent = narrowed.get(node.head.description.lower(), declared)
+        fields[node.head.description] = (
+            leaf_count(sent.schema), leaf_count(declared.schema)
+        )
+    return PlanResult(planner.graph, provider, sinks, fields)
+
+
+def _narrowable_sources(
+    graph: LogicalGraph, provider: SchemaProvider, statements
+) -> Dict[str, StreamSchema]:
+    """Source table (lowercased) -> the fields its readers read, for the
+    tables of `graph` whose whole row crosses an edge and declares more."""
+    wide = [
+        n.head for n in graph.nodes.values()
+        if n.is_source
+        and n.chain[-1].operator is OperatorName.EXPRESSION_WATERMARK
+    ]
+    if not wide:
+        return {}
+    from .pruning import direct_readers, kept_schema
+
+    readers, given_up = direct_readers(
+        [st.query if isinstance(st, Insert) else st for st in statements
+         if isinstance(st, (Insert, Select))]
+        + list(provider.views.values())
+    )
+    narrowed: Dict[str, StreamSchema] = {}
+    for head in wide:
+        key = head.description.lower()
+        if key in given_up or key not in readers:
+            continue
+        t = provider.get_table(key)
+        # what the table's own DDL names stays whole
+        ddl = [*t.generated, *t.metadata_fields]
+        if t.options.get("event_time_field"):
+            ddl.append(t.options["event_time_field"])
+        for gexpr in t.generated.values():
+            ddl.extend(_column_names(gexpr))
+        kept = kept_schema(head.config["schema"], t.name, readers[key], ddl)
+        if kept is not None:
+            narrowed[key] = kept
+    return narrowed
+
+
+def _plan_script(
+    statements, provider: SchemaProvider, parallelism: int,
+    preview_results: Optional[list], narrowed: Dict[str, StreamSchema],
+) -> Tuple[Planner, List[int]]:
+    planner = Planner(provider, parallelism, narrowed)
     sinks: List[int] = []
     queries: List[Select] = []
     inserts: List[Insert] = []
@@ -2502,9 +2591,10 @@ def plan_query(
     # operator chaining at compile time, like the reference
     # (arroyo-planner/src/lib.rs:935-937 behind pipeline.chaining.enabled):
     # fused Forward chains execute in ONE subtask with direct calls, which
-    # also guarantees they can never be scheduled onto different workers —
-    # unchained, a forward edge crossing workers ships full pre-projection
-    # rows (e.g. nexmark structs) over the TCP data plane
+    # also guarantees they can never be scheduled onto different workers.
+    # A source left unchained (a fan-out, chaining off) would ship full
+    # pre-projection rows (e.g. nexmark structs) over its edges: plan_query
+    # sees that here and narrows the table to the fields read
     from ..config import config as _config
 
     if _config().pipeline.chaining_enabled:
@@ -2519,4 +2609,10 @@ def plan_query(
     from ..engine.segments import SegmentFusionPass
 
     SegmentFusionPass().optimize(planner.graph)
-    return PlanResult(planner.graph, provider, sinks)
+    return planner, sinks
+
+
+def _column_names(e: Expr) -> List[str]:
+    if isinstance(e, Column):
+        return [e.name] + ([e.table] if e.table else [])
+    return [n for c in _expr_children(e) for n in _column_names(c)]

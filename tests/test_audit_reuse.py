@@ -1,7 +1,9 @@
 """One fingerprint per batch object, through the engine (ISSUE 27).
 
 A q7-shaped plan on the CPU: one source whose raw batches fan out to two
-window subqueries that read different fields, joined per window; an
+window subqueries that read different fields (between them every declared
+column, the string too: a column nobody reads no longer crosses a fan-out,
+ISSUE 31), joined per window; an
 embedded cluster, several checkpoint epochs. The conservation ledger's
 taps observe every batch at both ends of every edge, and compute a
 fingerprint once per batch OBJECT: in one process the source's batch is
@@ -57,7 +59,7 @@ def q7_shaped_sql(tmp_path):
     INSERT INTO out
     SELECT W.k, W.v, W.c FROM (
       SELECT k, v, tumble(interval '10 second') as w, count(*) as c
-      FROM src GROUP BY 1, 2, w
+      FROM src WHERE note IS NOT NULL GROUP BY 1, 2, w
     ) AS W JOIN (
       SELECT max(v) as maxv, tumble(interval '10 second') as w
       FROM src GROUP BY w

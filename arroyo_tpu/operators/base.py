@@ -69,7 +69,8 @@ class Operator:
         self, barrier: CheckpointBarrier, ctx: OperatorContext, collector
     ):
         """Snapshot in-memory state into ctx state tables; called after
-        barrier alignment, before the table flush."""
+        barrier alignment, before the table flush. May return the number
+        of rows it serialised: the caller's `ckpt.capture` carries it."""
 
     async def handle_commit(
         self, epoch: int, commit_data: Dict[int, list], ctx: OperatorContext

@@ -756,13 +756,14 @@ class SubtaskRunner:
         cap_span = self._barrier_span("checkpoint.capture", barrier)
         with cap_span, obs.timeline.phase(
                 "ckpt.capture", task=self.task_info.task_id,
-                key=barrier.epoch):
+                key=barrier.epoch) as capture:
             from ..serve import seal_op
 
             captured = []
             commit_data = None
             for idx, (op, ctx) in enumerate(zip(self.ops, self.ctxs)):
-                await op.handle_checkpoint(barrier, ctx, self.collectors[idx])
+                capture.n += await op.handle_checkpoint(
+                    barrier, ctx, self.collectors[idx]) or 0
                 # StateServe: seal the view's staged rows under this
                 # epoch at the same synchronization point the state
                 # capture stamps dirty entries — reads at published

@@ -8,6 +8,14 @@ _timestamp); when the watermark passes a bin, the window functions
 evaluate over the bin's rows and the augmented rows emit. The reference
 runs a DataFusion BoundedWindowAggExec per bin; here the ranking kernels
 are numpy lexsort-based.
+
+The phase ledger (`obs/timeline.py`): `rank.buffer` per batch (`n` = rows
+taken in); per bin closed, `key` = the bin's timestamp: `rank.sort` (`n` =
+rows ranked: the bin's table, the lexsort, the rank), `rank.build` (`n` =
+rows of the output batch) and `rank.emit` (self time: the operators behind
+it run inside). `handle_checkpoint` returns the buffered rows it serialised,
+which the runner books as `n` of the task's `ckpt.capture`. What survives a
+filter on the rank downstream is not this operator's to count.
 """
 
 from __future__ import annotations
@@ -19,6 +27,7 @@ import pyarrow as pa
 
 from ..engine.construct import register_operator
 from ..graph.logical import OperatorName
+from ..obs import timeline
 from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
 from .base import Operator
@@ -78,25 +87,27 @@ class WindowFunctionOperator(Operator):
                     },
                 },
             )
+            return sum(b.num_rows for bs in self.bins.values() for b in bs)
 
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
-        ts = np.asarray(
-            batch.column(batch.schema.names.index(TIMESTAMP_FIELD)).cast(
-                pa.int64()
+        with timeline.phase("rank.buffer", n=batch.num_rows):
+            ts = np.asarray(
+                batch.column(batch.schema.names.index(TIMESTAMP_FIELD)).cast(
+                    pa.int64()
+                )
             )
-        )
-        if self.emitted_up_to is not None:
-            live = ts > self.emitted_up_to
-            if not live.all():
-                if not live.any():
-                    return
-                batch = batch.filter(pa.array(live))
-                ts = ts[live]
-        for t in np.unique(ts):
-            mask = ts == t
-            self.bins.setdefault(int(t), []).append(
-                batch.filter(pa.array(mask)) if not mask.all() else batch
-            )
+            if self.emitted_up_to is not None:
+                live = ts > self.emitted_up_to
+                if not live.all():
+                    if not live.any():
+                        return
+                    batch = batch.filter(pa.array(live))
+                    ts = ts[live]
+            for t in np.unique(ts):
+                mask = ts == t
+                self.bins.setdefault(int(t), []).append(
+                    batch.filter(pa.array(mask)) if not mask.all() else batch
+                )
 
     async def handle_watermark(self, watermark, ctx, collector):
         if watermark.kind != WatermarkKind.EVENT_TIME:
@@ -104,31 +115,30 @@ class WindowFunctionOperator(Operator):
         t = watermark.timestamp
         for ts in sorted(b for b in self.bins if b <= t):
             batches = self.bins.pop(ts)
-            table = pa.Table.from_batches(batches).combine_chunks()
-            out = self._evaluate(table)
-            if out is not None and out.num_rows:
-                await collector.collect(out)
+            with timeline.phase("rank.sort", key=ts) as ph:
+                table = pa.Table.from_batches(batches).combine_chunks()
+                ph.n = table.num_rows
+                values = self._rank_values(table) if ph.n else None
+            if values is not None:
+                with timeline.phase("rank.build", key=ts, n=len(values)):
+                    out = self._build_output(table, values)
+                with timeline.phase("rank.emit", key=ts, n=out.num_rows,
+                                    annotate=False):
+                    await collector.collect(out)
             self.emitted_up_to = max(self.emitted_up_to or 0, ts)
         return watermark
 
-    def _evaluate(self, table: pa.Table) -> Optional[pa.RecordBatch]:
+    def _rank_values(self, table: pa.Table) -> np.ndarray:
+        """The window function's value for every row of one bin."""
         n = table.num_rows
-        if n == 0:
-            return None
-        # partition ids
         if self.partition_cols:
-            import pandas.util
+            from .windows import _batch_group_codes
 
-            parts = None
-            for c in self.partition_cols:
-                col = np.asarray(
-                    table.column(c).to_numpy(zero_copy_only=False)
-                )
-                h = pandas.util.hash_array(
-                    col.astype(object), categorize=False
-                )
-                parts = h if parts is None else parts * np.uint64(31) + h
-            _, part_ids = np.unique(parts, return_inverse=True)
+            # exact partition ids (a hash of the columns could merge two
+            # partitions in silence)
+            part_ids = _batch_group_codes(
+                [table.column(c).to_numpy(zero_copy_only=False)
+                 for c in self.partition_cols], n)
         else:
             part_ids = np.zeros(n, dtype=np.int64)
         # order keys (last key = primary in lexsort)
@@ -145,6 +155,10 @@ class WindowFunctionOperator(Operator):
         ranks = self._rank(part_ids[order], sort_keys, order)
         values = np.empty(n, dtype=np.int64)
         values[order] = ranks
+        return values
+
+    def _build_output(self, table: pa.Table,
+                      values: np.ndarray) -> pa.RecordBatch:
         arrays = [table.column(f.name).combine_chunks()
                   if f.name != self.out_field else pa.array(values, type=f.type)
                   for f in self.out_schema.schema]

@@ -996,7 +996,10 @@ def _batch_group_codes(key_cols: List[np.ndarray], n: int) -> np.ndarray:
         elif c.dtype == np.uint64:
             c = c.view(np.int64)
         if c.dtype.kind not in "iub":
-            c = pd.factorize(c)[0].astype(np.int64)
+            try:
+                c = pd.factorize(c)[0]
+            except TypeError:   # unhashable values (a list column)
+                c = pd.factorize(pd.Series(c).astype(str))[0]
         norm.append(c.astype(np.int64, copy=False))
     if len(norm) == 1:
         _, inverse = np.unique(norm[0], return_inverse=True)

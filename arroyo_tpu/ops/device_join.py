@@ -3,12 +3,12 @@
 TPU-native replacement for the reference's in-engine join probe
 (/root/reference/crates/arroyo-worker/src/arrow/instant_join.rs:1-412,
 join_with_expiration.rs:1-264): instead of a host hash join, the probe
-runs as XLA programs — per-row key hashing (splitmix64 over the int64
-key words), a device sort of the build side, a searchsorted range probe,
-and vectorized pair expansion into a padded output bucket. Hash-equal
-candidate pairs are verified against the full key words host-side, so
-the join is exact even under 64-bit hash collisions (a collision only
-costs spurious candidates, never wrong results).
+runs as XLA programs: per-row key hashing (splitmix64 over the int64
+key words), a device sort of the build side, the rank of every probe
+hash in it, and vectorized pair expansion into a padded output bucket.
+Hash-equal candidate pairs are verified against the full key words
+host-side, so the join is exact even under 64-bit hash collisions (a
+collision only costs spurious candidates, never wrong results).
 
 Dynamic output size meets XLA's static-shape rule in two phases:
 phase 1 computes per-probe-row match counts and their prefix sums on
@@ -16,6 +16,33 @@ device; only the scalar total crosses to host to pick a padded output
 bucket; phase 2 expands the pair indices at that bucket size. All
 arrays are padded to power-of-two buckets, so the compiled program
 count stays O(log sizes) per key width.
+
+What the two programs cost is set by two shapes, and each has a rule
+that reads the shape alone (times are a TPU v5e's: PERF.md section 6,
+PR 37):
+
+* Phase 1 ranks every probe hash in the sorted build side with
+  `jnp.searchsorted`, a binary search by gather: one dependent round of
+  gathers over the whole probe side per level of the build bucket. At
+  262,144 probe rows that is 73 ms against a 1,024-row bucket, 64 ms
+  against 128 and 1.0 ms against 8. So a build side of up to
+  `_SMALL_BUILD` (8) rows is padded to 8, not to 1,024: one bucket more
+  per probe bucket and key width, not a ladder of them (a build side
+  that grows from 1 row to 1,024 compiles phase 1 twice). The
+  benchmark's three cells with a join (NEXmark q5, q7, q5 on the mesh)
+  all probe a window's rows with its ONE max row.
+* Phase 2 finds each output position's probe row: how many of the
+  candidate counts' prefix sums are at or below it. In the floor bucket
+  (1,024 positions: q7, one or two pairs) that is the same binary
+  search, 0.7-0.8 ms whatever the probe side. A larger bucket takes the
+  histogram of the prefix sums over the bucket and sums it from the
+  left, one scatter-add and one prefix sum: q5's equi-key is the window
+  alone, so all ~60,000 rows of a window are candidates of its max row,
+  and 65,536 positions from 65,536 rows take 2.1 ms, not 17.6.
+
+`probe()` books what it chose in the phase ledger: `join.probe.rank`
+(`padded` = the build bucket) and `join.probe.fill` (`padded` = the
+output bucket, `key` "search" or "hist").
 """
 
 from __future__ import annotations
@@ -70,8 +97,8 @@ def _build_fns():
 
     @jax.jit
     def phase1(l_mat, r_mat, n_l, n_r):
-        """Sort the build side by hash, range-probe it with the probe
-        side. Returns (order, lo, offs): build-side sort order, first
+        """Sort the build side by hash, rank the probe side's hashes in
+        it. Returns (order, lo, offs): build-side sort order, first
         candidate position per probe row, inclusive prefix sums of the
         candidate counts (offs[-1] = total candidate pairs)."""
         hl = hash_rows(l_mat)
@@ -101,9 +128,17 @@ def _build_fns():
     def phase2_at(size, order, lo, offs, rows=None):
         fn = phase2_cache.get(size)
         if fn is None:
+            fill = _fill(size)
+
             def impl(order, lo, offs, _size=size):
                 pos = jnp.arange(_size)
-                li = jnp.searchsorted(offs, pos, side="right")
+                # li = the probe rows whose candidates end at or before pos
+                if fill == "hist":
+                    ends = jnp.zeros(_size, jnp.int32).at[offs].add(
+                        1, mode="drop")
+                    li = jnp.cumsum(ends)
+                else:
+                    li = jnp.searchsorted(offs, pos, side="right")
                 li_c = jnp.clip(li, 0, offs.shape[0] - 1)
                 start = jnp.where(li_c > 0, offs[li_c - 1], 0)
                 rpos = lo[li_c] + (pos - start)
@@ -132,6 +167,18 @@ def _bucket(n: int, lo: int = 1024) -> int:
     return b
 
 
+# the two shape rules of the module docstring
+_SMALL_BUILD = 8
+
+
+def _build_bucket(n_r: int) -> int:
+    return _SMALL_BUILD if n_r <= _SMALL_BUILD else _bucket(n_r)
+
+
+def _fill(size: int) -> str:
+    return "hist" if size > _bucket(0) else "search"
+
+
 def _pad_matrix(cols: List[np.ndarray], bucket: int) -> np.ndarray:
     mat = np.zeros((bucket, len(cols)), dtype=np.int64)
     n = len(cols[0])
@@ -156,7 +203,7 @@ def probe(
 
     from ..obs import timeline
 
-    lb, rb = _bucket(n_l), _bucket(n_r)
+    lb, rb = _bucket(n_l), _build_bucket(n_r)
     obs_device.note_padding("join.phase1", rb, n_l + n_r, lb + rb)
     # sub-steps of the caller's join.probe, in the ledger only
     with timeline.phase("join.probe.count", annotate=False):
@@ -166,13 +213,17 @@ def probe(
         order, lo, offs = phase1(
             l_mat, r_mat, np.int64(n_l), np.int64(n_r), rung=rb, rows=n_r
         )
+        timeline.note("join.probe.rank", 0.0, n=n_l, padded=rb)
         # the scalar crosses to the host: waits for phase 1 on the device
         total = int(offs[-1])
     if total == 0:
         e = np.empty(0, dtype=np.int64)
         return e, e
     with timeline.phase("join.probe.expand", annotate=False):
-        li, ri, valid = phase2_at(_bucket(total), order, lo, offs, total)
+        size = _bucket(total)
+        li, ri, valid = phase2_at(size, order, lo, offs, total)
+        timeline.note("join.probe.fill", 0.0, n=total, padded=size,
+                      key=_fill(size))
         li = np.asarray(li)
         ri = np.asarray(ri)
         valid = np.asarray(valid)

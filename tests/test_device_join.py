@@ -198,3 +198,233 @@ def test_prepare_join_keys_string_nullable_multi():
     assert _probe_pairs(lt, rt, ["a", "b"]) == _pairs_via_arrow_tables(
         lt, rt, ["a", "b"]
     )
+
+
+# --- the two programs against the parent's arithmetic (ISSUE 37) ----------
+# The oracle is the parent's arithmetic in numpy: np.searchsorted over the
+# same splitmix64 hashes, the same expansion. Whatever the build bucket and
+# whichever way phase 2 fills, the programs must hand back its arrays, not
+# just its pairs.
+
+_M64 = (1 << 64) - 1
+_SENTINEL = np.uint64(_M64)
+
+
+def _np_hash_rows(mat):
+    from arroyo_tpu.types import _splitmix64
+
+    h = np.zeros(mat.shape[0], dtype=np.uint64)
+    for j in range(mat.shape[1]):
+        h = _splitmix64(h ^ mat[:, j].astype(np.uint64))
+    return h
+
+
+def _key_hashing_to(h: int) -> int:
+    """The one-word int64 key whose row hash is `h`: splitmix64's
+    finaliser run backwards."""
+    z = h
+    z ^= (z >> 31) ^ (z >> 62)
+    z = z * pow(0x94D049BB133111EB, -1, 1 << 64) & _M64
+    z ^= (z >> 27) ^ (z >> 54)
+    z = z * pow(0xBF58476D1CE4E5B9, -1, 1 << 64) & _M64
+    z ^= (z >> 30) ^ (z >> 60)
+    z = (z - 0x9E3779B97F4A7C15) & _M64
+    return z - (1 << 64) if z >= 1 << 63 else z
+
+
+def _buckets(n_l, n_r):
+    return device_join._bucket(n_l), device_join._build_bucket(n_r)
+
+
+def _oracle(lcols, rcols):
+    n_l, n_r = len(lcols[0]), len(rcols[0])
+    lb, rb = _buckets(n_l, n_r)
+    hl = _np_hash_rows(device_join._pad_matrix(lcols, lb))
+    hr = _np_hash_rows(device_join._pad_matrix(rcols, rb))
+    hr[n_r:] = _SENTINEL
+    order = np.argsort(hr, kind="stable")
+    hrs = hr[order]
+    lo = np.searchsorted(hrs, hl, side="left")
+    hi = np.searchsorted(hrs, hl, side="right")
+    offs = np.cumsum(np.where(np.arange(lb) < n_l, hi - lo, 0))
+    total = int(offs[-1])
+    out = {"order": order, "lo": lo, "offs": offs, "total": total}
+    if total == 0:
+        e = np.empty(0, dtype=np.int64)
+        return out | {"pairs": (e, e)}
+    pos = np.arange(device_join._bucket(total))
+    li = np.clip(np.searchsorted(offs, pos, side="right"), 0, lb - 1)
+    start = np.where(li > 0, offs[li - 1], 0)
+    ri = order[np.clip(lo[li] + (pos - start), 0, rb - 1)]
+    valid = pos < total
+    mask = valid & (li < n_l) & (ri < n_r)
+    pl, pr = li[mask], ri[mask]
+    keep = np.ones(len(pl), dtype=bool)
+    for lc, rc in zip(lcols, rcols):
+        keep &= lc[pl] == rc[pr]
+    return out | {"li": li, "ri": ri, "valid": valid,
+                  "pairs": (pl[keep], pr[keep])}
+
+
+def _ints(rng, dom, n, words):
+    return [rng.randint(0, dom, n).astype(np.int64) for _ in range(words)]
+
+
+def _case_one_row_build(n_l, words=3):
+    """The cells' shape: a window's rows against its one max row."""
+    rng = np.random.RandomState(n_l)
+    lcols = _ints(rng, 1000, n_l, words)
+    hit = rng.randint(0, n_l, 4)
+    rcols = [c[hit[:1]].copy() for c in lcols]
+    for c, r in zip(lcols, rcols):
+        c[hit] = r[0]
+    return lcols, rcols
+
+
+def _case_duplicates(n_l, n_r, words, dom=12):
+    rng = np.random.RandomState(n_l + n_r + words)
+    return _ints(rng, dom, n_l, words), _ints(rng, dom, n_r, words)
+
+
+def _case_sentinel():
+    k = _key_hashing_to(_M64)
+    lcols = [np.array([5, k, 7, k, 9] * 20, dtype=np.int64)]
+    return lcols, [np.array([7, k, 11], dtype=np.int64)]
+
+
+def _case_every_row_matches(n_l, copies):
+    return ([np.full(n_l, 42, dtype=np.int64)],
+            [np.full(copies, 42, dtype=np.int64)])
+
+
+_CASES = {
+    "one_row_build_5000x3": lambda: _case_one_row_build(5000),
+    "one_row_build_70000x3": lambda: _case_one_row_build(70000),
+    "one_row_build_5000x1": lambda: _case_one_row_build(5000, words=1),
+    "duplicates_1_word": lambda: _case_duplicates(5000, 300, 1, dom=40),
+    "duplicates_2_words": lambda: _case_duplicates(5000, 300, 2),
+    "duplicates_3_words": lambda: _case_duplicates(3000, 700, 3, dom=5),
+    "two_sided_40000x20000": lambda: _case_duplicates(40000, 20000, 2,
+                                                      dom=300),
+    "probe_rows_eq_bucket": lambda: _case_duplicates(1024, 90, 1, dom=64),
+    "probe_rows_bucket_plus_1": lambda: _case_duplicates(1025, 90, 1,
+                                                         dom=64),
+    "build_rows_eq_small": lambda: _case_duplicates(2048, 8, 1, dom=16),
+    "build_rows_small_plus_1": lambda: _case_duplicates(2048, 9, 1, dom=16),
+    "build_rows_eq_bucket": lambda: _case_duplicates(2048, 1024, 1,
+                                                     dom=900),
+    "build_rows_bucket_plus_1": lambda: _case_duplicates(2049, 1025, 2,
+                                                         dom=40),
+    "probe_hash_eq_sentinel": _case_sentinel,
+    "zero_matches": lambda: ([np.arange(3000, dtype=np.int64)],
+                             [np.arange(5000, 5600, dtype=np.int64)]),
+    "every_probe_row_matches": lambda: _case_every_row_matches(3000, 1),
+    # 1,024 x 2 pairs: the output bucket outgrows the probe side's
+    "every_probe_row_matches_twice": lambda: _case_every_row_matches(
+        1024, 2),
+}
+
+
+@pytest.fixture
+def fill(request, monkeypatch):
+    """Hold phase 2 to one way of filling whatever the bucket, with the
+    jitted programs rebuilt for the test: a traced program has the rule's
+    answer baked in. "rule" leaves both as they are."""
+    if request.param != "rule":
+        monkeypatch.setattr(device_join, "_fill", lambda size: request.param)
+        monkeypatch.setattr(device_join, "_fns", None)
+    return request.param
+
+
+@pytest.mark.parametrize("fill", ["rule", "search", "hist"], indirect=True)
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_programs_hand_back_the_parents_arrays(case, fill):
+    lcols, rcols = _CASES[case]()
+    want = _oracle(lcols, rcols)
+    n_l, n_r = len(lcols[0]), len(rcols[0])
+    lb, rb = _buckets(n_l, n_r)
+    phase1, phase2_at = device_join._build_fns()
+    order, lo, offs = phase1(
+        device_join._pad_matrix(lcols, lb),
+        device_join._pad_matrix(rcols, rb),
+        np.int64(n_l), np.int64(n_r), rung=rb, rows=n_r)
+    for name, got in (("order", order), ("lo", lo), ("offs", offs)):
+        assert np.array_equal(np.asarray(got), want[name]), name
+    total = want["total"]
+    if total:
+        li, ri, valid = phase2_at(device_join._bucket(total), order, lo,
+                                  offs, total)
+        for name, got in (("li", li), ("ri", ri), ("valid", valid)):
+            assert np.array_equal(np.asarray(got), want[name]), name
+    # probe(): the parent's pairs in the parent's order (probe-side order)
+    pl, pr = device_join.probe(lcols, rcols)
+    assert np.array_equal(pl, want["pairs"][0])
+    assert np.array_equal(pr, want["pairs"][1])
+    assert np.all(np.diff(pl) >= 0)
+    if case == "zero_matches":
+        assert total == 0 and len(pl) == 0
+    if case.startswith("every_probe_row_matches"):
+        assert len(pl) == total == n_l * n_r
+    if case == "probe_hash_eq_sentinel":
+        # 40 probe rows collide with the 5 padded build rows: spurious
+        # candidates that the bounds and the key check drop, none missing
+        assert total == 20 + 40 * 6 and len(pl) == 60
+
+
+def _ledger_notes(name):
+    from arroyo_tpu.obs import timeline
+
+    return [e for e in timeline.snapshot() if e["phase"] == name]
+
+
+@pytest.mark.parametrize("make, n_r, build_bucket, fill, out_bucket", [
+    # q7's shape: a few pairs in the floor bucket
+    (lambda: _case_one_row_build(5000), 1, 8, "search", 1024),
+    # q5's: every row of the window a candidate of its max row
+    (lambda: _case_every_row_matches(3000, 1), 1, 8, "hist", 4096),
+    (lambda: _case_duplicates(5000, 9, 1, dom=9), 9, 1024, "hist", 8192),
+])
+def test_the_ledger_says_what_the_probe_chose(make, n_r, build_bucket, fill,
+                                              out_bucket):
+    from arroyo_tpu.obs import timeline
+
+    timeline.clear()
+    lcols, rcols = make()
+    assert len(rcols[0]) == n_r
+    pl, _ = device_join.probe(lcols, rcols)
+    total = _oracle(lcols, rcols)["total"]
+    rank, = _ledger_notes("join.probe.rank")
+    got, = _ledger_notes("join.probe.fill")
+    assert (rank["n"], rank["dur"]) == (len(lcols[0]), 0)
+    assert (got["key"], got["n"], got["dur"]) == (fill, total, 0)
+    t = timeline.totals()
+    assert t["join.probe.rank"]["padded"] == build_bucket
+    assert t["join.probe.fill"]["padded"] == out_bucket
+    # no match, no expansion: the rank is booked, the fill is not
+    timeline.clear()
+    device_join.probe([np.arange(5000, dtype=np.int64)],
+                      [np.array([-1], dtype=np.int64)])
+    assert len(_ledger_notes("join.probe.rank")) == 1
+    assert not _ledger_notes("join.probe.fill")
+
+
+def test_a_growing_build_side_compiles_phase1_twice(monkeypatch):
+    """The small build bucket is one bucket, not a ladder of them: each is a
+    compile on the engine's loop (`updating_join`'s other side grows through
+    every size)."""
+    assert sorted({device_join._build_bucket(n) for n in range(1, 1025)}) \
+        == [8, 1024]
+    assert device_join._build_bucket(1025) == 2048
+    monkeypatch.setattr(device_join, "_fns", None)
+    phase1, _ = device_join._build_fns()
+    probe_side = [np.arange(3000, dtype=np.int64)]
+    for n_r in (1, 2, 8, 9, 100, 1000, 1024):
+        device_join.probe(probe_side, [np.arange(n_r, dtype=np.int64)])
+    assert len(phase1.seen) == 2
+
+
+def test_the_fill_rule_reads_the_output_bucket_alone():
+    # the cells (module docstring): q7 fills the floor bucket with a pair or
+    # two, q5 fills 65,536 positions from its window's ~60,000 rows
+    assert device_join._fill(1024) == "search"
+    assert device_join._fill(2048) == device_join._fill(65536) == "hist"

@@ -64,23 +64,31 @@ def main(argv=None):
 
     args = ap.parse_args(argv)
     if args.cmd == "run":
-        return asyncio.run(_run(args))
+        return _serve(_run(args))
     if args.cmd == "worker":
-        return asyncio.run(_worker(args))
+        return _serve(_worker(args))
     if args.cmd == "node":
-        return asyncio.run(_node(args))
+        return _serve(_node(args))
     if args.cmd == "controller":
-        return asyncio.run(_controller(args))
+        return _serve(_controller(args))
     if args.cmd == "api":
-        return asyncio.run(_api(args))
+        return _serve(_api(args))
     if args.cmd == "cluster":
-        return asyncio.run(_cluster(args))
+        return _serve(_cluster(args))
     if args.cmd == "visualize":
         return _visualize(args)
     if args.cmd == "bench":
         import subprocess
 
         return subprocess.call([sys.executable, "bench.py"])
+
+
+def _serve(role):
+    """Every role's loop is made in one place: its selector books the
+    loop's idle time into the phase ledger (`obs/timeline.py`)."""
+    from .obs.timeline import event_loop
+
+    return asyncio.run(role, loop_factory=event_loop)
 
 
 def _load_sql(q: str) -> str:

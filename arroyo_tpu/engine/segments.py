@@ -817,6 +817,7 @@ class FusedSegmentOperator(Operator):
         from .. import obs
 
         t0 = time.perf_counter()
+        c0 = obs.timeline.thread_cpu(t0)
         prog = self._program()
         staged = None
         if prog is not None and self._use_jax:
@@ -839,7 +840,9 @@ class FusedSegmentOperator(Operator):
                 out = self._run_host(batch)
             staged = _StagedBatch(out) if out is not None else None
             self._host_h.observe(time.perf_counter() - t0)
-        obs.timeline.note("segment", time.perf_counter() - t0)
+        t1 = time.perf_counter()
+        obs.timeline.note("segment", t1 - t0,
+                          cpu_s=obs.timeline.thread_cpu(t1) - c0)
         return staged
 
     # -- staging / pipelining ----------------------------------------------

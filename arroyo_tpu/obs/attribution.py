@@ -38,7 +38,9 @@ with no `__shared/*` escape bucket.
 The pump also samples event-loop lag (sleep-overshoot of a fixed
 timer) into `arroyo_worker_loop_lag_seconds` — the signal that
 separates "my job is starved" from "a co-resident tenant is hogging
-the loop" in the bottleneck doctor.
+the loop" in the bottleneck doctor — and on the same cadence books the
+loop's running time and its thread's CPU into the phase ledger
+(`timeline.loop_tick`: `loop.run`, beside the selector's `loop.idle`).
 
 Everything is gated on `obs.attribution` (independent of `obs.enabled`:
 attribution is plain metrics, no spans, so the fleet harness can run it
@@ -397,6 +399,9 @@ async def _pump_loop():
                     # Perfetto dumps and the offline doctor see loop
                     # pressure
                     timeline.note("loop.lag", lag, job="")
+                # and the stretch since the last tick, idle taken out,
+                # with the loop thread's CPU: `loop.run`
+                timeline.loop_tick()
             ACCOUNTING.flush()
         history.HISTORY.sample_registry()
 

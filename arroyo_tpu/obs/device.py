@@ -25,7 +25,10 @@ transfer and launch) and never the program's run on the device. JAX
 returns before the device finishes; `dispatch_s`, the "device seconds" of
 the attribution accounting and the ledger's `dispatch` phase all mean
 this. Device time per program comes from a profiler trace only
-(`benchmark/trace_reduce.py`): no call here waits for the device.
+(`benchmark/trace_reduce.py`): no call here waits for the device. A fresh
+signature's call is booked as the phase `compile` (`key` = the program)
+instead of `dispatch`, and is annotated, so that a device gap under a
+compile on the loop reads `engine:compile` in a trace.
 
 A call site that pads passes `rung=` (the padded rows) and `rows=` (the
 real ones); both are summed per program (`summary()["programs"][p]`:
@@ -264,8 +267,18 @@ class InstrumentedJit:
         fresh = key not in self.seen
         start_us = time.time() * 1e6
         t0 = time.perf_counter()
-        out = self.fn(*args)
-        dt = time.perf_counter() - t0
+        c0 = timeline.thread_cpu(t0)
+        if fresh:
+            # trace and compile, seconds long and on the caller's thread:
+            # a phase of its own, on the profiler's clock too, INSTEAD of
+            # `dispatch` (a note tallies no children: both would count
+            # the seconds twice)
+            with timeline.phase("compile", key=self.program):
+                out = self.fn(*args)
+        else:
+            out = self.fn(*args)
+        t1 = time.perf_counter()
+        dt = t1 - t0
         # per-job device attribution (ISSUE 11): jitted programs are
         # cached process-wide ACROSS jobs, so the per-program families
         # cannot carry a job label — the ambient job context gives
@@ -273,7 +286,6 @@ class InstrumentedJit:
         # timeline ledger its `dispatch` swimlane. `dt` is the host time
         # of the enqueue (module docstring), not time on the device
         attribution.note(device=dt, dispatches=1)
-        timeline.note("dispatch", dt)
         if fresh:
             self.seen.add(key)
             self._compiles.inc()
@@ -282,6 +294,8 @@ class InstrumentedJit:
             _record_compile(self.program, signature_of(args), rung,
                             len(self.seen), dt, start_us)
         else:
+            timeline.note("dispatch", dt,
+                          cpu_s=timeline.thread_cpu(t1) - c0)
             self._hit.inc()
             self._dispatch_h.observe(dt)
             if self._exchange_h is not None:

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from . import timeline
+
 # signal thresholds: a busy ratio above BUSY_HIGH reads as "the job is
 # the bottleneck of itself"; loop lag above LAG_FLOOR_MS reads as loop
 # contention; a co-resident tenant above NEIGHBOR_SHARE of attributed
@@ -109,7 +111,7 @@ def collect(job_id: str, registry=None) -> dict:
     watchtower history tier has coverage, WINDOWED rates instead of
     lifetime cumulatives (see _windowed_overlay)."""
     from ..metrics import REGISTRY, hist_quantiles
-    from . import attribution, timeline
+    from . import attribution
 
     registry = registry or REGISTRY
     attribution.ACCOUNTING.flush()
@@ -204,8 +206,11 @@ def diagnose(sig: dict) -> dict:
     (trace-dump) and online paths cannot drift."""
     busy = float(sig.get("busy_ratio") or 0.0)
     phases = sig.get("phases") or {}
+    # named work alone: the recorder's own lists say what is a wait and
+    # what encloses (`loop.idle` and `loop.run` would swamp every share)
+    skip = timeline.WAITS + timeline.ENCLOSING
     phase_total = sum(
-        v for p, v in phases.items() if p not in ("loop.lag", "queue.wait")
+        v for p, v in phases.items() if p not in skip
     ) or 1e-9
     device_s = float(sig.get("device_s") or phases.get("dispatch", 0.0))
     busy_s = float(sig.get("busy_s") or 0.0) or phase_total
@@ -305,8 +310,9 @@ def signals_from_trace(events: List[dict], job_id: str) -> dict:
         if phase == "loop.lag":
             lags.append((ev.get("dur") or 0.0) / 1e6)
             continue
-        if phase == "queue.wait":
-            continue            # waiting on a full out queue is not work
+        if phase in timeline.WAITS or phase == "loop.run":
+            continue            # a wait is not work; the loop's own
+                                # stretch encloses every job's
         # self time where the dump carries it: nested phases add up
         dur_s = (args.get("self", ev.get("dur")) or 0.0) / 1e6
         by_job[job] = by_job.get(job, 0.0) + dur_s

@@ -341,7 +341,8 @@ def perfetto_trace(spans: List[Dict[str, Any]],
             "pid": e.get("pid", pid), "tid": tid,
             "args": {"job": e.get("job", ""), "task": e.get("task", ""),
                      "n": e.get("n", 0), "key": e.get("key"),
-                     "self": e.get("self", e.get("dur") or 0.0)},
+                     "self": e.get("self", e.get("dur") or 0.0),
+                     "cpu": e.get("cpu", 0.0)},
         })
     events.sort(key=lambda ev: (ev.get("ts", 0), ev.get("pid", 0)))
     doc["phaseCount"] = len(timeline)

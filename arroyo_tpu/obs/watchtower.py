@@ -47,6 +47,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from ..config import config
 from ..utils.logging import get_logger
+from . import timeline
 from .history import HISTORY, MetricHistory
 
 logger = get_logger("watchtower")
@@ -597,7 +598,12 @@ class Watchtower:
             job_id, spec.name, value, spec.threshold, spec.unit,
         )
         try:
-            self._capture_bundle(job_id, tenant, spec, ev)
+            # the flight recorder and the phase ring serialised on the loop
+            # the controller shares with an embedded worker: seconds with
+            # full rings, so a leaf of the ledger (`n` = spans written)
+            with timeline.phase("watch.bundle", job=job_id,
+                                key=spec.name) as ph:
+                ph.n = self._capture_bundle(job_id, tenant, spec, ev)["spans"]
         except Exception:  # noqa: BLE001 - a failed bundle must not
             logger.exception("bundle capture for %s/%s failed",
                              job_id, spec.name)

@@ -137,10 +137,13 @@ class SourceOperator(Operator):
         import time as _time
 
         t0 = _time.perf_counter()
+        c0 = obs.timeline.thread_cpu(t0)
         batch = ctx.take_buffer()
         if batch is not None:
-            obs.timeline.note("decode", _time.perf_counter() - t0,
-                              task=ctx.task_info.task_id)
+            t1 = _time.perf_counter()
+            obs.timeline.note("decode", t1 - t0,
+                              task=ctx.task_info.task_id,
+                              cpu_s=obs.timeline.thread_cpu(t1) - c0)
             await collector.collect(batch)
         # latency markers stamp at flush cadence (throttled by
         # obs.latency_marker_interval): they leave through the subtask's

@@ -17,6 +17,7 @@ import pyarrow as pa
 
 from ..graph.logical import OperatorName
 from ..engine.construct import register_operator
+from ..obs import timeline
 from .base import Operator
 
 
@@ -63,7 +64,10 @@ class BatchMapOperator(Operator):
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
         if self.segment_member:
             self._count_unfused(ctx)
-        out = self.fn(batch)
+        # an unfused value operator's whole work (a fused run books
+        # `segment`): in a source's chain the projection over the raw row
+        with timeline.phase("project", n=batch.num_rows):
+            out = self.fn(batch)
         if out is not None and out.num_rows:
             await collector.collect(out)
 

@@ -93,6 +93,20 @@ class ChainCollector:
             await r.tail.collect(batch)
 
 
+class SourceCollector(ChainCollector):
+    """What a source operator collects into: a source task has no input
+    item, so a batch it hands on is the item its task handles, inside the
+    same `process` enclosure as an operator task's batch (`n` = rows): the
+    chained operators' un-named work, the tail's and the runner's belong
+    to that task in the ledger."""
+
+    async def collect(self, batch: pa.RecordBatch):
+        with obs.timeline.phase(
+                "process", task=self.runner.task_info.task_id,
+                n=batch.num_rows, annotate=False):
+            await super().collect(batch)
+
+
 # runner state is shared between the main select loop, the pipelined
 # flush tasks it spawns (which set _flush_failed), and stop/commit
 # control arrivals; the pipelined-flush bookkeeping is the hottest
@@ -123,6 +137,8 @@ class SubtaskRunner:
         self.control_rx = control_rx
         self.control_tx = control_tx
         self.collectors = [ChainCollector(self, i) for i in range(len(ops))]
+        if self.is_source:
+            self.collectors[0] = SourceCollector(self, 0)
         for ctx in ctxs:
             ctx._runner = self  # back-ref for in-chain watermark injection
         self.task_info = ctxs[0].task_info
@@ -540,32 +556,32 @@ class SubtaskRunner:
         iq = self.inputs[i]
         if isinstance(item, SignalMessage):
             if item.kind == SignalKind.WATERMARK:
-                changed = self.watermarks.set(i, item.watermark)
-                if changed is not None:
-                    self._track_watermark_lag(changed)
-                    # window emission happens here: count it as busy time
-                    # or watermark-driven operators look idle to the
-                    # autoscaler no matter how hard they work
-                    t0 = time.perf_counter()
-                    anchor = obs.device.anchor(
-                        self._compile_trace, "watermark.advance",
-                        task=self.task_info.task_id,
-                    )
-                    # the enclosing phase of a close: its leaves are
-                    # booked where the work happens, its self time is
-                    # what they leave unnamed
-                    with obs.timeline.phase(
-                            "watermark", task=self.task_info.task_id,
-                            annotate=False):
+                # the enclosing phase of a watermark signal, first line to
+                # last: a close's leaves are booked where the work
+                # happens, its self time is what they leave unnamed, the
+                # holder's arithmetic for a signal that moves nothing too
+                with obs.timeline.phase(
+                        "watermark", task=self.task_info.task_id,
+                        annotate=False) as ph:
+                    changed = self.watermarks.set(i, item.watermark)
+                    if changed is not None:
+                        self._track_watermark_lag(changed)
+                        anchor = obs.device.anchor(
+                            self._compile_trace, "watermark.advance",
+                            task=self.task_info.task_id,
+                        )
                         try:
                             await self._chain_watermark(0, changed)
                         finally:
                             anchor.close()
-                    dt = time.perf_counter() - t0
-                    self._busy_secs.inc(dt)
-                    # per-job attributed busy (the ambient job context is
-                    # set by run())
-                    obs.attribution.note(busy=dt)
+                        # window emission happens here: count it as busy
+                        # time or watermark-driven operators look idle to
+                        # the autoscaler no matter how hard they work
+                        dt = ph.elapsed()
+                        self._busy_secs.inc(dt)
+                        # per-job attributed busy (the ambient job context
+                        # is set by run())
+                        obs.attribution.note(busy=dt)
                 return True
             if item.kind == SignalKind.LATENCY_MARKER:
                 await self._handle_marker(item)
@@ -580,37 +596,35 @@ class SubtaskRunner:
                     await self._maybe_complete_alignment()
                 return False
             return True
-        # data batch
-        self._batches_recv.inc()
-        self._msgs_recv.inc(item.num_rows)
-        nbytes = batch_bytes(item)
-        self._bytes_recv.inc(nbytes)
-        obs.attribution.note(nbytes=nbytes)
-        if self._audit_on:
-            tap = self._rx_taps[i]
-            if tap is not None:
-                with obs.timeline.phase(
-                        "audit.attest", task=self.task_info.task_id,
-                        n=item.num_rows):
-                    tap.observe(item)
-            self._op_counts[0][0] += item.num_rows
-        t0 = time.perf_counter()
-        anchor = obs.device.anchor(
-            self._compile_trace, "batch.process",
-            task=self.task_info.task_id,
-        )
+        # data batch: the enclosing phase of its whole handling, the
+        # counters and the audit tap ahead of the operators included
         with obs.timeline.phase("process", task=self.task_info.task_id,
-                                n=item.num_rows, annotate=False):
+                                n=item.num_rows, annotate=False) as ph:
+            self._batches_recv.inc()
+            self._msgs_recv.inc(item.num_rows)
+            nbytes = batch_bytes(item)
+            self._bytes_recv.inc(nbytes)
+            obs.attribution.note(nbytes=nbytes)
+            if self._audit_on:
+                tap = self._rx_taps[i]
+                if tap is not None:
+                    with obs.timeline.phase("audit.attest", n=item.num_rows):
+                        tap.observe(item)
+                self._op_counts[0][0] += item.num_rows
+            anchor = obs.device.anchor(
+                self._compile_trace, "batch.process",
+                task=self.task_info.task_id,
+            )
             try:
                 await self.ops[0].process_batch(
                     item, self.ctxs[0], self.collectors[0], iq.logical_input
                 )
             finally:
                 anchor.close()
-        dt = time.perf_counter() - t0
-        self._batch_seconds.observe(dt)
-        self._busy_secs.inc(dt)
-        obs.attribution.note(busy=dt)
+            dt = ph.elapsed()
+            self._batch_seconds.observe(dt)
+            self._busy_secs.inc(dt)
+            obs.attribution.note(busy=dt)
         return True
 
     async def _handle_marker(self, item: SignalMessage):

@@ -191,6 +191,27 @@ def build_admin_app(role: str, details_fn=None,
                 job, window=window, series=request.query.get("series"))
         return web.json_response(doc)
 
+    async def debug_timeline(request: web.Request):
+        """The phase ledger's totals for THIS process (`obs/timeline.py`):
+        per phase count, wall and self seconds and, beside them, the CPU
+        seconds of the thread that ran it (`cpu_s`, `self_cpu_s`: wall
+        less CPU is time off a core), with the recorder's lists of what
+        encloses, what waits and what waits for the device. ?job=<id>
+        narrows to one job (`loop.idle` and `loop.run` are the process's:
+        job ""); ?last=<s> to the newest seconds."""
+        from ..obs import timeline
+
+        try:
+            last = float(request.query.get("last", 0))
+        except ValueError:
+            return web.Response(status=400, text="bad last\n")
+        t0_us = (time.time() - last) * 1e6 if last > 0 else None
+        return web.json_response({
+            "totals": timeline.totals(t0_us, job=request.query.get("job")),
+            "enclosing": timeline.ENCLOSING, "waits": timeline.WAITS,
+            "device_waits": timeline.DEVICE_WAITS,
+        })
+
     async def debug_doctor(request: web.Request):
         """Bottleneck doctor for one job hosted in this process:
         ?job=<id> (required) returns the ranked limiting-factor verdict
@@ -252,6 +273,7 @@ def build_admin_app(role: str, details_fn=None,
     app.router.add_get("/debug/latency", debug_latency)
     app.router.add_get("/debug/history", debug_history)
     app.router.add_get("/debug/attribution", debug_attribution)
+    app.router.add_get("/debug/timeline", debug_timeline)
     app.router.add_get("/debug/doctor", debug_doctor)
     for path, handler in (extra_routes or {}).items():
         app.router.add_get(path, handler)

@@ -388,3 +388,497 @@ def test_the_jitted_function_names_the_trace_reduction_maps():
     assert names == trace_reduce.MODULE_NAMES
     assert {acc._update_fn.program, acc._reset_fn.program, phase1.program,
             impl.program} == set(trace_reduce.MODULE_NAMES.values())
+
+
+# -- ISSUE 38: thread CPU beside wall time, the loop's own time, every runner
+# item inside an enclosure of its task, a compile with a name ----------------
+
+
+def _spin(seconds):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        sum(range(200))
+
+
+@pytest.fixture
+def every_edge(monkeypatch):
+    """The thread CPU clock read at every edge of a phase, whatever a
+    reading costs on this machine (`timeline.cpu_every_s`)."""
+    monkeypatch.setattr(timeline, "_CPU_EVERY_S", 0.0)
+
+
+def test_a_dear_cpu_clock_is_read_on_a_grid_and_the_seconds_still_add_up(
+        monkeypatch):
+    """Where a reading of the thread's CPU clock is dear (the chip's host:
+    6 us, steps of 10 ms) a thread reads it on a grid of its own: far fewer
+    readings than edges, a frame's children and its self CPU still
+    partition its own exactly, a phase longer than the grid reads true and
+    a kind of short phases gets the CPU of the stretch it runs in."""
+    import threading
+
+    reads = []
+    real = time.thread_time
+
+    def counted():
+        reads.append(1)
+        return real()
+
+    monkeypatch.setattr(time, "thread_time", counted)
+    monkeypatch.setattr(timeline, "_CPU_EVERY_S", 0.002)
+    clock = time.pthread_getcpuclockid(threading.get_ident())
+    c0 = time.clock_gettime(clock)
+    with timeline.phase("process", job="dear", annotate=False):
+        for _ in range(300):
+            with timeline.phase("win.keys"):
+                _spin(0.0004)
+            with timeline.phase("agg.pack"):
+                _spin(0.0008)
+        for _ in range(5):
+            with timeline.phase("agg.read"):
+                time.sleep(0.03)            # a wait, ten grid steps long
+            with timeline.phase("serve.seal"):
+                _spin(0.03)                 # and work as long, no edge in it
+    c1 = time.clock_gettime(clock)
+    t = timeline.phase_totals("dear")
+    edges = 2 * sum(v["count"] for v in t.values())
+    assert len(reads) <= t["process"]["total_s"] / 0.001 + 2
+    assert len(reads) < edges / 2
+    # exactly: the enclosure's CPU is its children's and its own
+    assert t["process"]["cpu_s"] == pytest.approx(
+        t["win.keys"]["cpu_s"] + t["agg.pack"]["cpu_s"]
+        + t["agg.read"]["cpu_s"] + t["serve.seal"]["cpu_s"]
+        + t["process"]["self_cpu_s"], abs=2e-5)
+    assert t["process"]["cpu_s"] == pytest.approx(c1 - c0, abs=0.004)
+    # a long phase reads true to a grid step an edge, waiting or working:
+    # its CPU is its own, not the enclosure's that reads the clock next
+    assert t["agg.read"]["total_s"] > 0.15 > 0.03 > t["agg.read"]["cpu_s"]
+    assert t["serve.seal"]["cpu_s"] == pytest.approx(
+        t["serve.seal"]["total_s"], abs=5 * 2 * 0.003)
+    assert t["process"]["self_cpu_s"] < 0.04
+    # the short leaves share the stretch's CPU by the time each holds: the
+    # grid favours neither the long one nor the one that comes first
+    leaves = t["win.keys"]["cpu_s"] + t["agg.pack"]["cpu_s"]
+    assert leaves == pytest.approx(
+        t["win.keys"]["total_s"] + t["agg.pack"]["total_s"], rel=0.1)
+    assert t["agg.pack"]["cpu_s"] / leaves == pytest.approx(2 / 3, abs=0.12)
+
+
+def test_a_sleeping_phase_books_wall_and_a_spinning_one_books_both(every_edge):
+    with timeline.phase("asleep", job="cpu"):
+        time.sleep(0.05)
+    with timeline.phase("spinning", job="cpu"):
+        _spin(0.05)
+    t = timeline.phase_totals("cpu")
+    assert t["asleep"]["total_s"] >= 0.05 and t["asleep"]["cpu_s"] < 0.01
+    assert t["spinning"]["total_s"] >= 0.05
+    # on a core all the while, but for what the OS took away
+    assert 0.03 < t["spinning"]["cpu_s"] <= t["spinning"]["total_s"] * 1.01
+    ring = {e["phase"]: e for e in timeline.snapshot("cpu")}
+    assert ring["spinning"]["cpu"] == pytest.approx(
+        t["spinning"]["cpu_s"] * 1e6, abs=1.0)
+
+
+def test_self_cpu_is_cpu_less_the_childrens_and_a_note_carries_its_own(every_edge):
+    with timeline.phase("process", job="nest", task="3-0", annotate=False):
+        _spin(0.04)
+        with timeline.phase("dir.assign"):
+            _spin(0.03)
+        time.sleep(0.02)                 # wall the enclosure does not burn
+        timeline.note("dispatch", 0.004, cpu_s=0.003)
+        timeline.note("dir.new", 0.0, n=5)           # a count: no CPU
+        timeline.note("queue.wait", 0.001)           # a wait books none
+        with timeline.phase("emit", annotate=False):
+            # a wait that awaits: the thread runs other tasks' work in it,
+            # which is neither the wait's CPU nor its parent's own
+            with timeline.phase("queue.wait", annotate=False):
+                _spin(0.03)
+    t = timeline.phase_totals("nest")
+    assert t["queue.wait"]["total_s"] > 0.03 > 0.01 > t["emit"]["self_cpu_s"]
+    assert t["dispatch"]["cpu_s"] == t["dispatch"]["self_cpu_s"] == 0.003
+    assert t["dir.new"]["cpu_s"] == t["queue.wait"]["cpu_s"] == 0.0
+    assert t["dir.assign"]["self_cpu_s"] == t["dir.assign"]["cpu_s"] > 0.02
+    assert t["process"]["self_cpu_s"] == pytest.approx(
+        t["process"]["cpu_s"] - t["dir.assign"]["cpu_s"] - 0.003
+        - t["emit"]["cpu_s"], abs=1e-5)
+    assert 0.02 < t["process"]["self_cpu_s"] < t["process"]["self_s"] - 0.015
+    doc = obs.perfetto_trace([])
+    args = {e["name"]: e["args"] for e in doc["traceEvents"]
+            if e.get("cat") == "phase"}
+    assert args["phase.dispatch"]["cpu"] == pytest.approx(3000.0)
+    assert args["phase.dir.assign"]["cpu"] > 20_000
+
+
+def _run_loop(main):
+    """`main` on a loop the program's one factory made, with the accounting
+    pump ticking: what every role of `__main__.py` runs under."""
+    import asyncio
+
+    from arroyo_tpu.obs import attribution
+
+    async def pumped():
+        attribution.ensure_pump()
+        try:
+            return await main()
+        finally:
+            attribution.release_pump()
+
+    return asyncio.run(pumped(), loop_factory=timeline.event_loop)
+
+
+def test_every_role_of_the_cli_runs_on_the_one_timed_loop():
+    import inspect
+
+    from arroyo_tpu import __main__ as cli
+
+    src = inspect.getsource(cli)
+    assert src.count("asyncio.run(") == 1       # `_serve`, for all six
+    assert src.count("return _serve(_") == 6
+    assert "loop_factory=event_loop" in inspect.getsource(cli._serve)
+    loop = timeline.event_loop()
+    try:
+        assert isinstance(loop._selector, timeline.TimedSelector)
+    finally:
+        loop.close()
+
+
+def test_a_select_that_may_block_books_loop_idle_and_a_poll_books_nothing():
+    sel = timeline.TimedSelector()
+    try:
+        assert sel.select(0) == [] and sel.select(-1) == []
+        assert "loop.idle" not in timeline.totals()
+        t0 = time.perf_counter()
+        assert sel.select(0.05) == []
+        waited = time.perf_counter() - t0
+    finally:
+        sel.close()
+    idle = timeline.totals()["loop.idle"]
+    assert idle["count"] == 1 and idle["cpu_s"] == 0.0
+    assert 0.05 <= idle["total_s"] <= waited
+    assert sel.idle_s == pytest.approx(idle["total_s"], abs=1e-5)
+    assert "loop.idle" in timeline.WAITS and "loop.run" in timeline.ENCLOSING
+
+
+def test_loop_run_is_wall_less_idle_with_the_loop_threads_own_cpu(every_edge):
+    """Two seconds of a loop that spins 30 ms and sleeps 20 ms in turn:
+    idle + run = the wall, and `loop.run`'s CPU is the loop thread's own
+    clock read from outside (`pthread_getcpuclockid`) within 2 %."""
+    import asyncio
+    import threading
+
+    from arroyo_tpu.config import update
+
+    clock = time.pthread_getcpuclockid(threading.get_ident())
+
+    async def busy():
+        await asyncio.sleep(0.3)            # the pump's first tick: the mark
+        edges = [(time.perf_counter(), time.clock_gettime(clock))]
+        while time.perf_counter() - edges[0][0] < 2.0:
+            _spin(0.03)
+            await asyncio.sleep(0.02)
+        # up to the next tick, so that the last stretch is booked
+        n = timeline.totals()["loop.run"]["count"]
+        while timeline.totals()["loop.run"]["count"] == n:
+            await asyncio.sleep(0.005)
+        edges.append((time.perf_counter(), time.clock_gettime(clock)))
+        return edges
+
+    t0_us = time.time() * 1e6
+    with update(obs={"loop_lag_interval": 0.1}):
+        (w0, c0), (w1, c1) = _run_loop(busy)
+    t = timeline.totals()
+    run, idle = t["loop.run"], t["loop.idle"]
+    # from the first tick on: the 0.3 s before it, nearly all idle, less
+    lead = 0.3
+    assert run["total_s"] + idle["total_s"] == pytest.approx(
+        w1 - w0 + lead, abs=0.25)
+    assert 0.6 < idle["total_s"] - lead + 0.1 and idle["count"] > 30
+    assert run["cpu_s"] <= run["total_s"] * 1.01
+    assert run["self_cpu_s"] == run["cpu_s"] and run["self_s"] == run["total_s"]
+    # `n` = the loop's turns: each of the 40 rounds takes two at least (the
+    # sleep's timer, then the task), and no select goes uncounted
+    assert 80 <= run["n"] and idle["count"] <= run["n"] + 2
+    # the thread's clock from outside covers a little more than the ticks
+    # inside [w0, w1]: the first tick's stretch began before w0
+    assert run["cpu_s"] == pytest.approx(c1 - c0, rel=0.02, abs=0.02)
+    assert 1.0 < run["cpu_s"] < 1.6
+    assert t0_us < time.time() * 1e6
+
+
+def test_a_phase_on_a_worker_thread_stays_out_of_the_loops_sums(every_edge):
+    """`asyncio.to_thread` copies the context: a phase booked there keeps
+    its own CPU in `cpu_s`, none in `self_cpu_s` (identity 3 subtracts only
+    the loop thread's), and gives its enclosing frame wall and no CPU."""
+    import asyncio
+
+    from arroyo_tpu.config import update
+
+    def stored():
+        with timeline.phase("storage.put"):
+            _spin(0.1)
+
+    async def flush():
+        await asyncio.sleep(0.25)           # a tick names the loop's thread
+        with timeline.phase("ckpt.capture", job="thr", task="3-0",
+                            annotate=False):
+            await asyncio.to_thread(stored)
+        with timeline.phase("agg.pack", job="thr", task="3-0"):
+            _spin(0.05)
+
+    with update(obs={"loop_lag_interval": 0.1}):
+        _run_loop(flush)
+    t = timeline.phase_totals("thr")
+    put, capture, pack = t["storage.put"], t["ckpt.capture"], t["agg.pack"]
+    assert put["cpu_s"] > 0.06 and put["self_cpu_s"] == 0.0
+    assert pack["self_cpu_s"] == pack["cpu_s"] > 0.03
+    # the worker's CPU is no child of the loop thread's frame
+    assert capture["self_s"] == pytest.approx(
+        capture["total_s"] - put["total_s"], abs=1e-5)
+    assert capture["self_cpu_s"] == capture["cpu_s"] < 0.03
+
+
+def test_a_fresh_signature_books_compile_and_a_seen_one_dispatch(every_edge):
+    import jax
+    import jax.numpy as jnp
+
+    from arroyo_tpu.obs import device as obs_device
+
+    fn = obs_device.InstrumentedJit(
+        "loopclock.double", jax.jit(lambda x: x * 2))
+    with timeline.phase("agg.enqueue", job="jit", task="3-0"):
+        fn(jnp.arange(8))
+    t = timeline.phase_totals("jit")
+    assert t["compile"]["count"] == 1 and "dispatch" not in t
+    assert t["agg.enqueue"]["self_s"] == pytest.approx(
+        t["agg.enqueue"]["total_s"] - t["compile"]["total_s"], abs=1e-5)
+    entry = next(e for e in timeline.snapshot("jit")
+                 if e["phase"] == "compile")
+    assert entry["key"] == "loopclock.double" and entry["task"] == "3-0"
+    with timeline.phase("agg.enqueue", job="jit", task="3-0"):
+        fn(jnp.arange(8))
+    t = timeline.phase_totals("jit")
+    assert t["compile"]["count"] == 1 and t["dispatch"]["count"] == 1
+    assert 0 < t["dispatch"]["cpu_s"] <= t["dispatch"]["total_s"] * 1.01 + 1e-4
+    with timeline.phase("agg.enqueue", job="jit", task="3-0"):
+        fn(jnp.arange(16))                  # a new shape: a compile again
+    t = timeline.phase_totals("jit")
+    assert t["compile"]["count"] == 2 and t["dispatch"]["count"] == 1
+    assert "compile" in timeline.DEVICE_WAITS
+
+
+def test_a_disabled_ledger_turns_every_part_off_the_timed_select_too():
+    import asyncio
+
+    import jax
+    import jax.numpy as jnp
+
+    from arroyo_tpu.config import update
+    from arroyo_tpu.obs import device as obs_device
+
+    fn = obs_device.InstrumentedJit("loopclock.off", jax.jit(lambda x: x + 1))
+
+    async def main():
+        with timeline.phase("process", job="off", annotate=False) as ph:
+            await asyncio.sleep(0.3)        # blocking selects, two ticks
+            fn(jnp.arange(4))
+            fn(jnp.arange(4))
+            assert ph.elapsed() >= 0.3      # the runner's counters still read
+        timeline.loop_tick()
+
+    with update(obs={"timeline_events": 0, "loop_lag_interval": 0.1}):
+        _run_loop(main)
+        sel = timeline.TimedSelector()
+        try:
+            sel.select(0.01)
+        finally:
+            sel.close()
+        assert sel.idle_s == 0.0
+    assert timeline.totals() == {} and timeline.snapshot() == []
+
+
+RUNNER_SQL = """
+CREATE TABLE src (
+  timestamp TIMESTAMP, a BIGINT NOT NULL
+) WITH (connector = 'single_file', path = '{src}', format = 'json',
+        type = 'source', event_time_field = 'timestamp');
+CREATE TABLE out (a BIGINT, cnt BIGINT) WITH (
+  connector = 'single_file', path = '{out}', format = 'json', type = 'sink');
+INSERT INTO out
+SELECT a, cnt FROM (
+  SELECT a, count(*) as cnt, tumble(interval '1 second') as w
+  FROM src GROUP BY 1, w);
+"""
+
+
+def test_every_item_a_runner_handles_is_inside_an_enclosure_of_its_task(
+        tmp_path):
+    """A source task's batches are under `process` with its task id and
+    their rows (it has no input item: its collector opens the enclosure);
+    an operator task's batch is under `process` from its counters on, the
+    receiver's `audit.attest` inside; every watermark signal is under
+    `watermark`, the ones that move nothing too."""
+    import asyncio
+    import json
+
+    from arroyo_tpu.engine import Engine
+    from arroyo_tpu.sql import plan_query
+
+    n = 4000
+    src = tmp_path / "in.json"
+    with open(src, "w") as f:
+        for i in range(n):
+            stamp = np.datetime64(1_677_628_800_000 + 2 * i, "ms")
+            f.write(json.dumps({"a": i % 7,
+                                "timestamp": str(stamp) + "Z"}) + "\n")
+    signals = {}
+
+    async def run():
+        plan = plan_query(RUNNER_SQL.format(src=src, out=tmp_path / "o.json"),
+                          parallelism=1)
+        eng = Engine(plan.graph, job_id="encl",
+                     storage_url=str(tmp_path / "ckpt")).start()
+        for s in eng.program.subtasks:
+            holder = s.runner.watermarks
+            sound = holder.set
+
+            def counted(i, wm, tid=s.runner.task_info.task_id, sound=sound):
+                signals[tid] = signals.get(tid, 0) + 1
+                return sound(i, wm)
+
+            holder.set = counted
+            signals.setdefault(s.runner.task_info.task_id, 0)
+            if s.node.is_source:
+                signals["source"] = s.runner.task_info.task_id
+        await eng.join(120)
+
+    asyncio.run(run())
+    source = signals.pop("source")
+    by_task = {tid: timeline.totals(job="encl", task=tid) for tid in signals}
+    assert by_task[source]["process"]["n"] == n
+    assert "watermark" not in by_task[source]
+    for tid, seen in signals.items():
+        if tid == source:
+            continue
+        t = by_task[tid]
+        # every signal, moved or not: the enclosure is around `set` itself
+        assert t["watermark"]["count"] == seen > 0, tid
+        assert t["process"]["count"] > 0
+        if "audit.attest" in t:
+            # the receiver's tap is inside the batch's enclosure now
+            assert t["process"]["self_s"] <= (
+                t["process"]["total_s"] - t["audit.attest"]["total_s"]
+                + 1e-4), tid
+    assert sum(t["process"]["n"] for tid, t in by_task.items()
+               if tid != source) >= n
+
+
+def test_the_doctor_skips_the_recorders_waits_and_enclosures():
+    """A ledger that holds `loop.idle` and `loop.run`: neither may swamp
+    the host-bound score's shares (`obs/doctor.py` reads `timeline.WAITS`
+    and `timeline.ENCLOSING`, no list of its own)."""
+    from arroyo_tpu.obs import doctor
+
+    base = {
+        "job": "j", "window_s": 10.0, "busy_s": 8.0, "busy_ratio": 0.8,
+        "device_s": 0.0, "operators": [{"task": "2-0", "busy_s": 8.0}],
+        "backpressure": 0.0, "queue_depth": 0.0, "watermark_lag_s": 0.0,
+        "dispatch_p50_ms": 0.0, "dispatches": 0, "padding_waste": 0.0,
+        "loop_lag_ms_p99": 1.0, "neighbors": [], "neighbor_top_share": 0.0,
+        "phases": {"exchange": 3.0, "emit": 1.0},
+    }
+    plain = doctor.diagnose(base)["ranked"]
+    with_loop = doctor.diagnose(dict(base, phases={
+        **base["phases"], "loop.idle": 40.0, "loop.run": 60.0,
+        "loop.lag": 5.0, "queue.wait": 9.0, "process": 2.0,
+        "watermark": 1.0}))["ranked"]
+    assert with_loop == plain
+    # and offline, from a dump that carries both
+    timeline.note("loop.idle", 0.5, job="")
+    timeline.note("loop.run", 0.5, job="", cpu_s=0.4)
+    timeline.note("agg.pack", 0.2, job="j", task="3-0", cpu_s=0.1)
+    sig = doctor.signals_from_trace(
+        obs.perfetto_trace([])["traceEvents"], "j")
+    assert sig["phases"] == {"agg.pack": pytest.approx(0.2)}
+    assert sig["neighbors"] == []
+
+
+def test_debug_timeline_shows_cpu_beside_wall(every_edge):
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from arroyo_tpu.utils.admin import build_admin_app
+
+    with timeline.phase("agg.pack", job="dbg", task="3-0"):
+        _spin(0.02)
+    timeline.note("loop.run", 0.5, job="", cpu_s=0.3)
+
+    async def go():
+        async with TestClient(TestServer(build_admin_app("test"))) as client:
+            whole = await (await client.get("/debug/timeline")).json()
+            mine = await (await client.get(
+                "/debug/timeline", params={"job": "dbg", "last": "60"})).json()
+            assert (await client.get(
+                "/debug/timeline", params={"last": "x"})).status == 400
+        return whole, mine
+
+    whole, mine = asyncio.run(go())
+    assert whole["totals"]["loop.run"]["cpu_s"] == pytest.approx(0.3)
+    assert set(mine["totals"]) == {"agg.pack"}
+    pack = mine["totals"]["agg.pack"]
+    assert 0.01 < pack["self_cpu_s"] <= pack["total_s"] * 1.01
+    assert whole["waits"] == list(timeline.WAITS)
+    assert whole["enclosing"] == list(timeline.ENCLOSING)
+    assert whole["device_waits"] == list(timeline.DEVICE_WAITS)
+
+
+def test_an_unfused_value_operator_books_project_with_the_rows_it_took_in():
+    """`project` (ISSUE 38): the source chain's projection over the raw row
+    was the largest un-named part of a source task's enclosure."""
+    import asyncio
+
+    import pyarrow as pa
+
+    from arroyo_tpu.operators.projection import BatchMapOperator
+
+    got = []
+
+    class Collector:
+        async def collect(self, batch):
+            got.append(batch)
+
+    op = BatchMapOperator(lambda b: b.slice(0, 3), "agg_input")
+    batch = pa.record_batch({"a": list(range(10))})
+
+    async def run():
+        with timeline.phase("process", job="proj", task="1-0", n=10,
+                            annotate=False):
+            await op.process_batch(batch, None, Collector())
+
+    asyncio.run(run())
+    t = timeline.phase_totals("proj")
+    assert got[0].num_rows == 3
+    assert t["project"]["count"] == 1 and t["project"]["n"] == 10
+    assert t["process"]["self_s"] == pytest.approx(
+        t["process"]["total_s"] - t["project"]["total_s"], abs=1e-5)
+    assert [e["task"] for e in timeline.snapshot("proj")] == ["1-0", "1-0"]
+
+
+def test_a_breach_bundle_written_on_the_loop_is_a_leaf_of_the_ledger(tmp_path):
+    """An SLO alert that fires serialises the flight recorder and the
+    phase ring where it stands, on the controller's loop: `watch.bundle`,
+    the job's, `key` = the rule, `n` = the spans it wrote."""
+    from arroyo_tpu.config import update
+    from arroyo_tpu.obs.watchtower import AlertState, RuleSpec, Watchtower
+
+    spec = RuleSpec("loop_lag", "", lambda ctx: 1.0, "above", 0.25, 0.1,
+                    0.0, 0.0, "loop")
+    with update(watch={"spool_dir": str(tmp_path)}):
+        tower = Watchtower()
+        tower._fire("jobw", "t", None, spec, AlertState(), 1.0, 0.0)
+    assert len(tower.bundles_for("jobw")) == 1
+    entry, = (e for e in timeline.snapshot("jobw")
+              if e["phase"] == "watch.bundle")
+    assert entry["key"] == "loop_lag"
+    assert entry["n"] == tower.bundles_for("jobw")[0]["spans"]
+    assert "watch.bundle" not in (timeline.ENCLOSING + timeline.WAITS
+                                  + timeline.DEVICE_WAITS)

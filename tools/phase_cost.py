@@ -3,13 +3,21 @@
 enter and exit: before jax is imported, with jax and no profiler session
 (the state of every `--trace 0` run), and inside a profiler session with
 the options the benchmark's tracer uses (host tracer on, python tracer
-off). Multiply by the entries a window books (the sum of `count` over
+off). Since ISSUE 38 a phase reads the thread's CPU clock beside the wall
+clock, at every edge where a reading is cheap and on a grid where it is not
+(`timeline.cpu_every_s`, printed): the two clocks alone are printed first,
+then a `note` with and without `cpu_s`, and a `select` of the loop's timed
+selector that may block (one ready handle, so it never does) against the
+plain selector's.
+Multiply by the entries a window books (the sum of `count` over
 `timeline.totals`) for the ledger's share of the window's host time.
 
     JAX_PLATFORMS=cpu python tools/phase_cost.py
 """
 
 import os
+import selectors
+import socket
 import sys
 import tempfile
 import time
@@ -48,9 +56,57 @@ def notes() -> None:
         timeline.note("x.y", 1e-6)
 
 
+def notes_with_cpu() -> None:
+    for _ in range(N):
+        t0 = time.perf_counter()
+        c0 = timeline.thread_cpu(t0)
+        t1 = time.perf_counter()
+        timeline.note("x.y", t1 - t0, cpu_s=timeline.thread_cpu(t1) - c0)
+
+
+def wall_clock() -> None:
+    for _ in range(N):
+        time.perf_counter()
+
+
+def cpu_clock() -> None:
+    for _ in range(N):
+        time.thread_time()
+
+
+def selects(selector):
+    """`N` selects with a timeout and one handle always ready: what each
+    turn of a loop with nothing else to run costs."""
+    a, b = socket.socketpair()
+    b.send(b"x")
+    selector.register(a, selectors.EVENT_READ)
+
+    def body() -> None:
+        for _ in range(N):
+            selector.select(0.01)
+
+    try:
+        yield body
+    finally:
+        selector.close()
+        a.close()
+        b.close()
+
+
 def main() -> None:
+    bench("perf_counter", wall_clock)
+    bench("thread_time", cpu_clock)
     bench("phase, jax not imported", leaves)
     bench("note, jax not imported", notes)
+    bench("note with cpu_s (its clock readings included)", notes_with_cpu)
+    print(f"the thread CPU clock is read every {timeline.cpu_every_s() * 1e3:.2f}"
+          " ms of wall at most (0 = at every edge)")
+    for label, selector in (
+            ("select, plain selector", selectors.DefaultSelector()),
+            ("select, timed selector (books loop.idle)",
+             timeline.TimedSelector())):
+        for body in selects(selector):
+            bench(label, body)
     assert "jax" not in sys.modules
     import jax
 

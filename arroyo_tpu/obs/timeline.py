@@ -15,9 +15,10 @@ names yet; and beside both the CPU seconds of the thread that ran it
 (ISSUE 38). Wall less CPU is the time that thread was not on a core inside
 the phase: for a leaf that never blocks that is the wait for the GIL or the
 OS, for a leaf of `DEVICE_WAITS` mostly the wait for the device. A phase of
-`WAITS` books no CPU (the loop's thread runs other tasks inside a
+`WAITS` books no CPU on the loop's thread (it runs other tasks inside a
 `queue.wait`); a phase that awaits one keeps its self CPU its own, its
-`cpu_s` holds the others' work too.
+`cpu_s` holds the others' work too. `flush.resolve`, the one wait that is
+a worker thread's work, keeps that thread's CPU.
 
 The CPU clock is a system call (`time.thread_time`). Where it is cheap
 (0.35 us a reading in the sandbox) every edge of a phase reads it and every
@@ -115,8 +116,11 @@ _N_CELLS = 0
 ENCLOSING = ("process", "watermark", "loop.run")
 # phases that measure waiting, not work on the engine's thread (a full out
 # queue, a storage thread, the loop's lag, a `select` with nothing ready):
-# left out of a sum of named work
-WAITS = ("queue.wait", "flush", "loop.lag", "loop.idle")
+# left out of a sum of named work. `flush.resolve` is the storage thread's
+# own work inside `flush` (ISSUE 39: what a capture staged unresolved, the
+# serve view's merge and the blob's packing): seconds beside the loop, so
+# a wait to its sums, booked with that thread's CPU
+WAITS = ("queue.wait", "flush", "flush.resolve", "loop.lag", "loop.idle")
 # leaves that block on the device, on a transfer back or (`compile`) on
 # the compiler's own threads: their wall less their CPU is mostly that
 # wait, not a wait for a core. Settled by the code and by traced runs of
@@ -238,10 +242,12 @@ def _book(phase_name: str, dur_s: float, child_s: float, job: Optional[str],
             job = parent.job
     if job is None:
         job = _current_job()
-    if phase_name in WAITS:
+    if phase_name in WAITS and (_LOOP_THREAD is None or tid == _LOOP_THREAD):
         # a wait that awaits (`queue.wait`) hands the CPU of its stretch to
         # its parent's tally above, so that the parent's self CPU stays
-        # its own, and books none: the thread ran other tasks' work in it
+        # its own, and books none: the thread ran other tasks' work in it.
+        # (A wait booked on a worker thread, `flush.resolve`, is that
+        # thread's work and keeps its CPU.)
         cpu_s = child_cpu_s = 0.0
     ts_us = time.time() * 1e6
     dur_us = dur_s * 1e6

@@ -332,7 +332,9 @@ class WindowOperatorBase(Operator):
         the current device state, and the returned zero-arg callable
         materializes the RecordBatch (__ts = bin_ts(bin), __bin, __k*,
         __v*) on the flush path — so the device->host copy overlaps the
-        next epoch's processing."""
+        next epoch's processing. The gather hands back its bucket's
+        padded arrays; the slice to the slots' count is taken here,
+        behind the copy, on the host."""
         if not self._dirty_chunks:
             return None
         slots, bins, key_cols = self._coalesce_dirty()
@@ -349,7 +351,7 @@ class WindowOperatorBase(Operator):
                 arrays.append(arr)
                 names.append(f"__k{i}")
             for j, v in enumerate(values):
-                arrays.append(pa.array(np.asarray(v)))
+                arrays.append(pa.array(np.asarray(v)[: len(slots)]))
                 names.append(f"__v{j}")
             return pa.RecordBatch.from_arrays(arrays, names=names)
 

@@ -626,7 +626,11 @@ class Accumulator:
         finalize() can resolve UDAF value buffers for the same emission.
         With materialize=False the jax device->host copy is only
         *dispatched*: the returned arrays are device arrays whose
-        np.asarray completes later (async snapshot overlap)."""
+        np.asarray completes later (async snapshot overlap), PADDED to
+        the gather's bucket: the caller takes `np.asarray(v)[:len(slots)]`
+        behind the copy. A slice here would be an eager device program
+        over a length that is new at every barrier: a compile each time,
+        on the engine's thread."""
         self._gather_slots = np.asarray(slots)
         self._segment_udaf = None
         self._segment_multiset = None
@@ -647,7 +651,7 @@ class Accumulator:
                 rows=len(slots)
             )
         if not materialize:
-            return [o[: len(slots)] for o in outs]
+            return list(outs)
         # the device-to-host read: waits for every program queued before it
         with timeline.phase("agg.read", n=len(slots), annotate=False):
             return [np.asarray(o)[: len(slots)] for o in outs]

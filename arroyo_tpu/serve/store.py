@@ -18,10 +18,19 @@ with three layers:
 Representation (ISSUE 25): the view is columnar on its write side. A
 window close hands `stage_batch` the Arrow batch it has just built, and
 the stage keeps that batch as one *segment* (a list append). `seal`
-merges the interval's segments into one per epoch with a vectorised
-keep-last-per-key, `seal_op` mirrors that segment into the `__serve__`
-table as ONE entry of Arrow IPC bytes, and folding appends segments to
-`served`, which compacts by the same merge. A Python key tuple and
+files the interval's segments under the epoch as ONE layer, `seal_op`
+mirrors that layer into the `__serve__` table as ONE entry of Arrow IPC
+bytes, and folding appends layers to `served`, which compacts by a
+vectorised keep-last-per-key. What the barrier must fix is WHICH rows
+belong to the epoch, and staged segments are immutable batches: so a
+stage that holds segments alone is detached and filed un-merged (O(1)
+on the engine's thread), its merge happens once, under a lock, for
+whoever asks first for the layer's table, and the mirror entry's bytes
+are a `Deferred` (state/tables.py) that the checkpoint's flush thread
+resolves before it writes the epoch's blob: the same merge, the same
+bytes, beside the loop. A stage with a dict layer (rows staged one at a
+time) is merged at the barrier, as ever: a dict layer's values are the
+operator's own objects. A Python key tuple and
 value dict exist only for the rows a read returns: a lookup goes newest
 layer first through a per-segment index built on the first read that
 touches the segment. Row-wise callers (`stage(key, value)`,
@@ -52,6 +61,7 @@ agree with `parallel/sharded_state.py owners_for` by construction.
 from __future__ import annotations
 
 import datetime
+import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 import msgpack
@@ -62,6 +72,7 @@ import pyarrow.compute as pc
 from ..analysis.model.effects import protocol_effect
 from ..config import config
 from ..obs import timeline
+from ..state.tables import Deferred, _batch_nbytes, resolved
 from ..types import hash_arrays, hash_column, server_for_hash_array
 from ..utils.logging import get_logger
 
@@ -179,11 +190,14 @@ def owner_subtask(key: Tuple, kinds: Tuple[str, ...], parallelism: int) -> int:
 # -- columnar segments --------------------------------------------------------
 
 
-def _ipc_bytes(table: pa.Table) -> bytes:
+def _ipc_bytes(table: pa.Table) -> memoryview:
+    """The table as an Arrow IPC stream: a bytes-like view of Arrow's own
+    buffer (`to_pybytes` would copy it once more, holding the GIL: 0.2 s
+    for a 180 MB segment on the chip's host, on whichever thread)."""
     sink = pa.BufferOutputStream()
     with pa.ipc.new_stream(sink, table.schema) as w:
         w.write_table(table)
-    return sink.getvalue().to_pybytes()
+    return memoryview(sink.getvalue()).cast("B")
 
 
 def _keep_last(table: pa.Table, n_keys: int) -> pa.Table:
@@ -287,22 +301,52 @@ class _Segment:
     """An immutable columnar run of view rows: the key's leaf columns
     in canonical form (`__k0`, `__k1`, ...: timestamps as int64 nanos,
     integers widened, strings as `string`) first, then value columns as
-    emitted and/or `__row` / `__tomb`. Holds the emitted batch as it
-    came until it is first merged or looked up; the index is built on
+    emitted and/or `__row` / `__tomb`. Three origins: an emitted batch
+    as it came (`raw`), held so until it is first merged or looked up; a
+    table; the layers of one sealed interval, un-merged (`parts`, oldest
+    first), which the first call of `table` merges (`ServeView._merge`),
+    once, under a lock: the checkpoint's flush thread as a rule, a read
+    or a compaction on the loop if it comes first. The index is built on
     the first lookup."""
 
-    __slots__ = ("_raw", "_table", "_index", "num_rows")
+    __slots__ = ("_raw", "_table", "_parts", "_lock", "_index",
+                 "staged_rows")
 
-    def __init__(self, raw=None, table: Optional[pa.Table] = None):
+    def __init__(self, raw=None, table: Optional[pa.Table] = None,
+                 parts: Optional[list] = None):
         self._raw = raw
         self._table = table
+        self._parts = parts
+        self._lock = threading.Lock() if parts is not None else None
         self._index = None
-        self.num_rows = (raw if table is None else table).num_rows
+        # the rows handed in: of un-merged parts, all of them (keys that
+        # recur still count each time)
+        self.staged_rows = (
+            sum(p.staged_rows for p in parts) if parts is not None
+            else (raw if table is None else table).num_rows)
 
-    def __len__(self) -> int:
-        return self.num_rows
+    @property
+    def deferred(self) -> bool:
+        """Un-merged parts, still: nobody has asked for the table."""
+        return self._parts is not None
+
+    def staged_nbytes(self) -> int:
+        """What the barrier can know of an un-merged layer's size."""
+        if self._parts is not None:
+            return sum(p.staged_nbytes() for p in self._parts)
+        return _batch_nbytes(self._raw if self._table is None
+                             else self._table)
+
+    def rows(self, view: "ServeView") -> int:
+        return (self._raw if self._raw is not None
+                else self.table(view)).num_rows
 
     def table(self, view: "ServeView") -> pa.Table:
+        if self._lock is not None and self._table is None:
+            with self._lock:
+                if self._table is None:
+                    self._table = view._merge(self._parts)._table
+                    self._parts = None
         if self._table is None:
             raw, names = self._raw, self._raw.schema.names
             cols = []
@@ -324,7 +368,7 @@ class _Segment:
         """Row of the last entry for `key`, or -1."""
         types = view._key_types
         if not types:
-            return self.num_rows - 1
+            return self.rows(view) - 1
         table = self.table(view)
         if self._index is None:
             if len(types) == 1 and types[0] != pa.string():
@@ -333,11 +377,11 @@ class _Segment:
                 self._index = (keys[order], order)
             else:
                 # tuples and strings: a dict of Python keys, built once
-                with view._materialize(self.num_rows):
+                with view._materialize(table.num_rows):
                     cols = [table.column(i).to_pylist()
                             for i in range(len(types))]
                     self._index = dict(zip(zip(*cols),
-                                           range(self.num_rows)))
+                                           range(table.num_rows)))
         key = _flat(key)
         if isinstance(self._index, dict):
             return self._index.get(key, -1)
@@ -382,10 +426,10 @@ class ServeView:
     """One subtask's epoch-consistent keyed view of an operator's
     emitted aggregates (see module docstring for the layer semantics).
 
-    A layer is a `_Segment` (an emitted batch, or a merge of layers) or
-    a dict key -> value | `_TOMB` (rows staged one at a time). `_stage`
-    and `served` are lists of layers, oldest first; `pending[epoch]` is
-    one merged layer."""
+    A layer is a `_Segment` (an emitted batch, a merge of layers, or a
+    sealed interval's layers still to be merged) or a dict key -> value |
+    `_TOMB` (rows staged one at a time). `_stage` and `served` are lists
+    of layers, oldest first; `pending[epoch]` is one layer."""
 
     def __init__(self, *, job_id: str, table: str, node_id: int,
                  task_index: int, parallelism: int,
@@ -420,6 +464,9 @@ class ServeView:
         # the view by a batch, rows turned into Python objects
         self.staged_rows = 0
         self.materialized_rows = 0
+        # rows sealed un-merged, for the flush thread (ISSUE 39): all of
+        # a view's sealed rows where every stage held segments alone
+        self.deferred_rows = 0
         # this view's segment entries in the `__serve__` table, oldest
         # first, the sequence number of the next one, and whether the
         # table (still) holds entries per key
@@ -429,6 +476,9 @@ class ServeView:
 
     def _key_columns(self) -> List[str]:
         return [f"__k{i}" for i in range(len(self._key_types))]
+
+    def _rows(self, layer) -> int:
+        return len(layer) if isinstance(layer, dict) else layer.rows(self)
 
     def _materialize(self, n: int):
         self.materialized_rows += n
@@ -488,19 +538,29 @@ class ServeView:
             for layer in self._stage)
 
     def seal(self, epoch: int):
-        """Move the staged layers under `epoch`, merged to one (called
-        at checkpoint capture, synchronously at the barrier). Bounded:
-        past serve.max_pending_epochs the oldest pending epoch folds
-        forward (publication stalled far beyond the inflight window).
-        Returns the sealed delta as one layer (None when nothing was
-        staged) — seal_op mirrors it into the `__serve__` state table
-        for followers."""
+        """Move the staged layers under `epoch` as one layer (called at
+        checkpoint capture, synchronously at the barrier). The rule is
+        read from the stage: segments alone are immutable batches, so
+        detaching the list fixes the epoch's rows and the layer is filed
+        un-merged (`_Segment(parts=)`: O(1) here, merged once by whoever
+        first asks for its table); a stage with a dict layer is merged
+        now. Bounded: past serve.max_pending_epochs the oldest pending
+        epoch folds forward (publication stalled far beyond the inflight
+        window). Returns the sealed delta as one layer (None when
+        nothing was staged) — seal_op mirrors it into the `__serve__`
+        state table for followers."""
         if not self._stage:
             return None
         staged, self._stage = self._stage, []
-        with timeline.phase("serve.seal", annotate=False,
-                            n=sum(map(len, staged))):
-            sealed = self._merge(staged)
+        rows = sum(len(x) if isinstance(x, dict) else x.staged_rows
+                   for x in staged)
+        with timeline.phase("serve.seal", annotate=False, n=rows):
+            if self._key_types is not None and not any(
+                    isinstance(x, dict) for x in staged):
+                sealed = _Segment(parts=staged)
+                self.deferred_rows += rows
+            else:
+                sealed = self._merge(staged)
             if epoch in self.pending:
                 self.pending[epoch] = self._merge(
                     [self.pending[epoch], sealed])
@@ -559,8 +619,8 @@ class ServeView:
         if not self.served:
             self._served_exact = True
             return
-        with timeline.phase("serve.compact", annotate=False,
-                            n=sum(map(len, self.served))):
+        with timeline.phase("serve.compact", annotate=False) as compact:
+            compact.n = sum(map(self._rows, self.served))
             self.served = [self._merge(self.served, bottom=True)]
         self._served_exact = True
 
@@ -608,12 +668,13 @@ class ServeView:
         return {
             "table": self.table,
             "task_index": self.task_index,
-            "keys": sum(map(len, self.served)),
+            "keys": sum(map(self._rows, self.served)),
             "pending_epochs": len(self.pending),
-            "staged": sum(map(len, self._stage)),
+            "staged": sum(map(self._rows, self._stage)),
             "served_epoch": self.served_epoch,
             "staged_rows": self.staged_rows,
             "materialized_rows": self.materialized_rows,
+            "deferred_rows": self.deferred_rows,
         }
 
     def describe(self) -> dict:
@@ -848,8 +909,8 @@ def seed_from_mirror(view: ServeView, mirror, adopt: bool = False) -> None:
     # the table's tombstones (each subtask drops all the segments and
     # re-persists what it owns: the union of the new chains is the view
     # again)
-    base = _owned(view, view.served[0].table(view))
-    view.served = [_Segment(table=base)]
+    base = _Segment(table=_owned(view, view.served[0].table(view)))
+    view.served = [base]
     for k in [k for *_, k, _t in segs] + list(rows):
         mirror.delete(k)
     view._mirror_rows = False
@@ -914,13 +975,16 @@ def stage_batch(view: ServeView, batch, partial: bool = False) -> list:
     return staged
 
 
-def _mirror_segment(view: ServeView, mirror, epoch: int, table: pa.Table,
+def _mirror_segment(view: ServeView, mirror, epoch: int, seg: _Segment,
                     seq: int) -> None:
     """Write one segment into the `__serve__` table as one entry, and
     keep the view's entries bounded: past `_MIRROR_SEGMENTS` the oldest
     merge into one, stored under the newest merged entry's key (so the
     replay order holds), and the merged-away entries are deleted
-    through the table's own tombstones."""
+    through the table's own tombstones. All of that bookkeeping (keys,
+    the log, which entries go) is done here, at the barrier; the bytes
+    of a layer still un-merged, and of a merge of entries, are a
+    `Deferred` that the flush resolves (or a `get`, if it comes first)."""
     log = view._mirror_log
     if view._mirror_rows:
         # the table holds entries per key (rows sealed before the view
@@ -934,20 +998,31 @@ def _mirror_segment(view: ServeView, mirror, epoch: int, table: pa.Table,
             log.insert(0, f"{SEG_PREFIX}/{view.task_index}/0/0")
             mirror.put(log[0], _ipc_bytes(view._encode_rows(rows)))
     key = f"{SEG_PREFIX}/{view.task_index}/{epoch}/{seq}"
-    mirror.put(key, _ipc_bytes(table))
+    if seg.deferred:
+        mirror.put(key, Deferred(lambda: _ipc_bytes(seg.table(view)),
+                                 rows=seg.staged_rows,
+                                 nbytes=seg.staged_nbytes()))
+    else:
+        mirror.put(key, _ipc_bytes(seg.table(view)))
     log.append(key)
     if len(log) <= _MIRROR_SEGMENTS:
         return
     old = log[:len(log) - _MIRROR_SEGMENTS // 2]
-    tables = [pa.ipc.open_stream(mirror.get(k)).read_all() for k in old]
-    with timeline.phase("serve.compact", annotate=False,
-                        n=sum(t.num_rows for t in tables)):
-        merged = _drop_tombs(_keep_last(
-            pa.concat_tables(tables, promote_options="default"),
-            len(view._key_types)))
-        for k in old[:-1]:
-            mirror.delete(k)
-        mirror.put(old[-1], _ipc_bytes(merged))
+    # the entries as they are now (bytes, or the `Deferred` of an epoch
+    # whose flush is in flight: epoch-ordered, so it resolves first)
+    entries = [mirror.raw(k) for k in old]
+    n_keys = len(view._key_types)
+
+    def merged() -> memoryview:
+        tables = [pa.ipc.open_stream(resolved(v)).read_all()
+                  for v in entries]
+        return _ipc_bytes(_drop_tombs(_keep_last(
+            pa.concat_tables(tables, promote_options="default"), n_keys)))
+
+    for k in old[:-1]:
+        mirror.delete(k)
+    mirror.put(old[-1], Deferred(merged, nbytes=sum(
+        v.nbytes if isinstance(v, Deferred) else len(v) for v in entries)))
     del log[:len(old) - 1]
 
 
@@ -959,8 +1034,9 @@ def seal_op(op, epoch: int, table_manager=None) -> None:
     snapshot rides this epoch. With a table manager, the sealed delta
     mirrors into the `__serve__` GlobalTable before capture serializes
     it, keeping the follower-visible chain in lockstep with the view:
-    one segment entry, or one entry per key for a view that was never
-    handed a batch."""
+    one segment entry (its bytes deferred to the flush where the seal
+    was), or one entry per key for a view that was never handed a
+    batch."""
     view = getattr(op, "_serve_view", None)
     if view is None:
         return
@@ -991,9 +1067,9 @@ def seal_op(op, epoch: int, table_manager=None) -> None:
                 else:
                     mirror.put(k, v)
         return
-    with timeline.phase("serve.mirror", n=sealed.num_rows, annotate=False):
-        _mirror_segment(view, mirror, epoch, sealed.table(view),
-                        view._mirror_seq)
+    with timeline.phase("serve.mirror", n=sealed.staged_rows,
+                        annotate=False):
+        _mirror_segment(view, mirror, epoch, sealed, view._mirror_seq)
         view._mirror_seq += 1
 
 

@@ -14,7 +14,12 @@ after compaction. Restore semantics per table kind:
     reference parquet.rs + expiring_time_key_map.rs)
 
 Checkpointing is split into capture (synchronous at the barrier, O(dirty))
-and flush (storage I/O, safe to overlap later epochs): the runner keeps up
+and flush (storage I/O, safe to overlap later epochs). A capture cuts WHAT
+belongs to the epoch; what is a pure function of that cut may be staged
+unresolved and is resolved by the flush, before anything is written: a
+time-key delta's thunk (a device->host copy), a global table's blob whose
+entries are `Deferred` (the serve view's merged segment as Arrow IPC bytes,
+and the msgpack packing around it). The runner keeps up
 to `state.max_inflight_flushes` epochs' flushes in flight, strictly
 epoch-ordered per subtask, so flush N always lands before flush N+1 runs —
 which is what lets flush-time bookkeeping (the cumulative time-key file
@@ -40,6 +45,7 @@ from ..metrics import (
     STATE_ROWS,
     STATE_SPILLED_BYTES,
 )
+from ..obs import timeline
 from ..types import TaskInfo
 from ..utils.logging import get_logger
 from .backend import StateBackend
@@ -61,6 +67,12 @@ class TableManager:
         # "epoch", "base"}]. Extended at CAPTURE time (paths are
         # deterministic) so pipelined flushes can't race the bookkeeping.
         self._chains: Dict[str, list] = {}
+        # blobs whose packing a capture left to the flush: name -> path
+        # -> [epoch, the nbytes of the staged inputs (what
+        # `_should_rebase` reckons with while the flush is in flight),
+        # len(blob) once it is written]. What lies before a flushed
+        # chain's base goes
+        self._late_blobs: Dict[str, Dict[str, list]] = {}
         # hot-standby tailing (ISSUE 17): highest manifest epoch whose
         # chain suffix has been replayed onto the open tables
         self._tailed_epoch = -1
@@ -303,17 +315,26 @@ class TableManager:
         One-shot form of capture() + flush_captured()."""
         return self.flush_captured(epoch, self.capture(epoch, watermark))
 
-    def _should_rebase(self, chain: list) -> bool:
+    def _should_rebase(self, chain: list, name: str) -> bool:
         st = get_config().state
         if not chain:
             return True
         deltas = [f for f in chain if not f.get("base")]
         if len(deltas) >= st.rebase_epochs:
             return True
-        base_bytes = sum(
-            f.get("bytes", 0) for f in chain if f.get("base")
-        ) or 1
-        delta_bytes = sum(f.get("bytes", 0) for f in deltas)
+        late = self._late_blobs.get(name, {})
+
+        def size(f: dict) -> int:
+            # its own, or while its flush is in flight what the capture
+            # knew of its inputs
+            n = f.get("bytes", 0)
+            if n is None:
+                _epoch, staged, written = late.get(f["path"], (0, 0, 0))
+                n = staged if written is None else written
+            return n
+
+        base_bytes = sum(size(f) for f in chain if f.get("base")) or 1
+        delta_bytes = sum(size(f) for f in deltas)
         return delta_bytes > st.rebase_bytes_factor * base_bytes
 
     @protocol_effect("state.capture_tables")
@@ -322,7 +343,9 @@ class TableManager:
         tables serialize only their dirty entries + tombstones (a base
         when the chain is empty or the rebase policy fires), time-key
         deltas are detached from the tables (possibly as unresolved
-        thunks whose device->host copy completes later). After capture
+        thunks whose device->host copy completes later; a global table's
+        blob as a `Deferred` where its entries are, its chain entry's
+        `bytes` None until the flush knows them). After capture
         the operator may resume processing; flush_captured does the I/O."""
         staged: Dict[str, dict] = {}
         ti = self.task_info
@@ -331,13 +354,18 @@ class TableManager:
             if cfg.kind == "global":
                 chain = self._chains.setdefault(name, [])
                 blob, is_base = table.serialize_delta(
-                    epoch, force_base=self._should_rebase(chain)
+                    epoch, force_base=self._should_rebase(chain, name)
                 )
                 if blob is not None:
                     path = self.backend.global_blob_path(
                         epoch, ti.node_id, self.op_idx, name, ti.task_index
                     )
-                    meta = {"path": path, "bytes": len(blob),
+                    late = callable(blob)
+                    if late:
+                        self._late_blobs.setdefault(name, {})[path] = [
+                            epoch, blob.nbytes, None]
+                    meta = {"path": path,
+                            "bytes": None if late else len(blob),
                             "epoch": epoch, "base": is_base}
                     if is_base:
                         chain[:] = [meta]
@@ -372,13 +400,24 @@ class TableManager:
             cfg = self.configs[name]
             if st["kind"] == "global":
                 chain = st["chain"]
-                if st["blob"] is not None:
-                    self.backend.write_blob(chain[-1]["path"], st["blob"])
+                blob = st["blob"]
+                if callable(blob):
+                    # beside the loop, on this storage thread: a wait to
+                    # the loop's sums (`timeline.WAITS`), with this
+                    # thread's own CPU
+                    with timeline.phase("flush.resolve", task=ti.task_id,
+                                        key=epoch, n=blob.rows,
+                                        annotate=False):
+                        blob = blob()
+                    self._late_blobs[name][chain[-1]["path"]][2] = len(blob)
+                self._fill_late_bytes(name, chain)
+                if blob is not None:
+                    self.backend.write_blob(chain[-1]["path"], blob)
                     # task-local recovery (ISSUE 17): a same-worker restart
                     # or tailing standby re-reads this exact blob; keep it
                     # in process memory so that read skips storage
                     CACHE.put(self.backend.storage.url, chain[-1]["path"],
-                              st["blob"])
+                              blob)
                 meta[name] = {
                     "kind": "global",
                     "chain": chain,
@@ -397,6 +436,24 @@ class TableManager:
                 table.files = files
                 meta[name] = {"kind": "time_key", "files": files}
         return meta
+
+    def _fill_late_bytes(self, name: str, chain: list) -> None:
+        """Give the chain entries of blobs packed on the flush their
+        `bytes`: in the staged copy the completion report carries (this
+        epoch's, and earlier epochs' that were in flight at its capture:
+        flushes are epoch-ordered, so theirs are written) and in
+        `_chains`, then forget what lies before this chain's base."""
+        late = self._late_blobs.get(name)
+        if not late:
+            return
+        for f in chain + list(self._chains.get(name, ())):
+            if f.get("bytes", 0) is None and f["path"] in late:
+                f["bytes"] = late[f["path"]][2]
+        floor = chain[0]["epoch"] if chain else 0
+        # (the loop's capture may add a path meanwhile: a list, at once)
+        for path, (epoch, _staged, written) in list(late.items()):
+            if epoch < floor and written is not None:
+                del late[path]
 
     async def load_compacted(self, table: str, paths):
         """Swap pre-compaction file references for the compacted file

@@ -199,3 +199,52 @@ def test_32bit_device_accumulators_exact():
         assert np.allclose(out[3], [(5 - 3 - 3) / 3, 1000007 / 2])
     finally:
         config().tpu.use_32bit_accumulators = False
+
+
+def test_an_undrained_gather_compiles_nothing_per_length_and_build_slices_on_the_host(
+        caplog):
+    """ISSUE 39: `gather(materialize=False)` hands back its bucket's padded
+    device arrays, so three barriers with three different dirty counts in
+    one bucket compile nothing after the first (an eager `o[:n]` on the
+    device, the parent's, compiles a `dynamic_slice` per new length: the
+    control); the delta's `build()`, on the flush path, slices behind the
+    copy and returns the parent's rows."""
+    import logging
+
+    import jax
+    import pyarrow as pa
+
+    from arroyo_tpu.operators.windows import TumblingWindowOperator
+
+    specs = [AggSpec("count", None, "cnt"), AggSpec("sum", 0, "total")]
+    acc = make_accumulator(specs, capacity=4096, backend="jax")
+    ints = np.arange(2000, dtype=np.int64) * 3
+    acc.update(np.arange(2000), {0: ints})
+    acc.gather(np.arange(700), materialize=False)  # the bucket's first
+
+    def compiles():
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("Compiling")]
+
+    op = object.__new__(TumblingWindowOperator)
+    op.acc = acc
+    op.codec = type("Codec", (), {"delta_arrays": staticmethod(
+        lambda key_cols: [pa.array(c) for c in key_cols])})()
+    with caplog.at_level(logging.WARNING), jax.log_compiles():
+        for n in (701, 800, 1000):
+            slots = np.arange(n) + 5
+            outs = acc.gather(slots, materialize=False)
+            assert [o.shape for o in outs] == [(1024,), (1024,)]
+            op._dirty_chunks = [(slots, np.full(n, 7, dtype=np.int64),
+                                 [slots * 11])]
+            op._dirty_rows = op._dirty_base = 0
+            batch = op._build_delta_batch(lambda bins: bins * 1_000)()
+            assert batch.schema.names == ["__ts", "__bin", "__k0", "__v0",
+                                          "__v1"]
+            assert batch.num_rows == n
+            assert batch.column(2).to_pylist() == (slots * 11).tolist()
+            assert batch.column(3).to_pylist() == [1] * n
+            assert batch.column(4).to_pylist() == ints[5:n + 5].tolist()
+        assert compiles() == []
+        outs[0][:999]   # the control: the parent's eager slice
+        assert any("dynamic_slice" in m for m in compiles())

@@ -415,3 +415,83 @@ def test_fast_smoke_drill(tmp_path):
     ]
     assert any("/ck-" in s["trace_id"] for s in cas_events), cas_events
     obs.reset()
+
+
+@pytest.mark.parametrize("flushes_lost", [1, 2])
+def test_a_worker_killed_between_a_barrier_and_its_flush_restores_the_published_view(
+        tmp_path, flushes_lost):
+    """ISSUE 39 moved a capture's merge, bytes and blob behind the
+    barrier, onto the flush: a worker that dies after the barrier of
+    epoch N (N + 1 too: `state.max_inflight_flushes` 2) and before its
+    flush has written nothing of it, so the next incarnation restores
+    from the last PUBLISHED epoch, and its serve view (seeded from the
+    `__serve__` chain) equals the reference at that epoch: none of the
+    unflushed epochs' rows, all of the published ones'."""
+    from types import SimpleNamespace
+
+    import numpy as np
+    import pyarrow as pa
+
+    from arroyo_tpu.operators.control import CheckpointCompletedResp
+    from arroyo_tpu.operators.windows import WindowOperatorBase
+    from arroyo_tpu.serve.store import (
+        SERVE_TABLE,
+        register_op,
+        seal_op,
+        serve_mirror_tables,
+        stage_batch,
+    )
+    from arroyo_tpu.state.backend import StateBackend
+    from arroyo_tpu.state.table_manager import TableManager
+    from arroyo_tpu.types import TaskInfo
+
+    url = f"file://{tmp_path}/kill"
+    ti = TaskInfo("kill", 3, "hop", 0, 1)
+    rng = np.random.default_rng(39 + flushes_lost)
+
+    def incarnation():
+        backend = StateBackend(url, "kill").initialize()
+        tm = TableManager(backend, ti, 0)
+        op = WindowOperatorBase.__new__(WindowOperatorBase)
+        op.name, op._key_names, op.key_cols = "hop", ["auction"], [0]
+        op.out_schema = SimpleNamespace(schema=pa.schema([
+            ("auction", pa.int64()), ("count", pa.int64())]))
+        asyncio.run(tm.open(serve_mirror_tables(op, ti)))
+        view = register_op(op, SimpleNamespace(task_info=ti,
+                                               table_manager=tm))
+        return backend, tm, op, view
+
+    backend, tm, op, view = incarnation()
+    reference, captured = {}, {}
+    last_epoch, published = 6, 6 - flushes_lost
+    for epoch in range(1, last_epoch + 1):
+        for _close in range(3):
+            keys = np.concatenate([rng.choice(100, 30, replace=False),
+                                   np.arange(20) + 1_000 * epoch])
+            counts = rng.integers(1, 900, len(keys))
+            stage_batch(view, pa.RecordBatch.from_pydict({
+                "auction": pa.array(keys.astype(np.int64)),
+                "count": pa.array(counts)}))
+            if epoch <= published:
+                reference.update(zip(keys.tolist(), counts.tolist()))
+        seal_op(op, epoch, tm)
+        captured[epoch] = tm.capture(epoch, None)   # the barrier passes
+        if epoch <= published:
+            meta = tm.flush_captured(epoch, captured[epoch])
+            backend.publish_checkpoint(epoch, {"3-0": CheckpointCompletedResp(
+                "3-0", 3, 0, epoch, subtask_metadata={"op0": meta},
+                watermark=None)})
+    # killed here: the unflushed epochs are captured, un-merged, unwritten
+    for epoch in range(published + 1, last_epoch + 1):
+        staged = captured[epoch][SERVE_TABLE]
+        assert callable(staged["blob"]) and view.pending[epoch].deferred
+        assert backend.read_blob(staged["chain"][-1]["path"]) is None
+    assert view.read((1_000 * last_epoch,), last_epoch)[0]
+
+    backend2, _tm2, _op2, restored = incarnation()
+    assert backend2.restore_epoch == published
+    for k in list(range(100)) + [1_000 * e + 7 for e in range(1, 8)]:
+        want = ((True, {"count": reference[k]}) if k in reference
+                else (False, None))
+        assert restored.read((k,), published) == want, k
+    assert restored.stats()["keys"] == len(reference)

@@ -351,10 +351,12 @@ class _Worker:
 
     def capture(self, epoch):
         from arroyo_tpu.serve.store import seal_op
+        from arroyo_tpu.state.tables import resolved
 
         seal_op(self.op, epoch, self.tm)
         blob, is_base = self.table.serialize_delta(epoch)
         if blob is not None:
+            blob = resolved(blob)   # as `flush_captured` does (ISSUE 39)
             self.chain = [blob] if is_base else self.chain + [blob]
 
 
@@ -541,7 +543,7 @@ def test_mirror_segment_merging_deletes_what_it_merged():
         stage_batch,
     )
     from arroyo_tpu.state.table_config import global_table
-    from arroyo_tpu.state.tables import GlobalTable
+    from arroyo_tpu.state.tables import GlobalTable, resolved
 
     rng = np.random.default_rng(7)
     w = _Worker(0, 1)
@@ -555,7 +557,7 @@ def test_mirror_segment_merging_deletes_what_it_merged():
             last[k] = c
         seal_op(w.op, epoch, w.tm)
         # every delta of the chain, merged-away entries' tombstones too
-        blobs.append(w.table.serialize_delta(epoch)[0])
+        blobs.append(resolved(w.table.serialize_delta(epoch)[0]))
         assert len(w.table.data) <= _MIRROR_SEGMENTS + 1, epoch
     replay = GlobalTable(global_table(SERVE_TABLE))
     replay.load_chain(blobs)

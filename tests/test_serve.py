@@ -310,15 +310,17 @@ def test_write_path_builds_no_python_rows():
         }))
     seal_op(op, 1, tm)
     blob, is_base = tm.tables[SERVE_TABLE].serialize_delta(1)
-    assert is_base and len(blob) > n * 8
+    # segments alone: the blob is the flush's to pack (ISSUE 39)
+    assert is_base and callable(blob) and len(blob()) > n * 8
     # one entry for the epoch beside the meta record, not one per key
     assert len(tm.tables[SERVE_TABLE].data) == 2
     totals = timeline.totals()
     assert view.staged_rows == n * closes
     assert view.materialized_rows == 0
     assert "serve.materialize" not in totals
+    # both count the rows staged: the merge is not the barrier's any more
     assert totals["serve.seal"]["n"] == n * closes
-    assert totals["serve.mirror"]["n"] == n + 1_000 * (closes - 1)
+    assert totals["serve.mirror"]["n"] == n * closes
     k = 7
     for key in range(2_000, 2_000 + k):
         found, value = view.read((key,), 1)
@@ -329,6 +331,295 @@ def test_write_path_builds_no_python_rows():
     assert st["materialized_rows"] == k and st["staged_rows"] == n * closes
     assert st["keys"] == n + 1_000 * (closes - 1)
     assert timeline.totals()["serve.materialize"]["n"] == k
+
+
+# -- ISSUE 39: what a barrier cuts, and what the flush resolves ---------------
+
+
+def _hop_view(**kw):
+    return _view(key_names=["auction"], value_names=["window", "count"],
+                 **kw)
+
+
+def _scripted_run(url, lag, epochs=20):
+    """Twenty barriers of a viewed hop operator through a real
+    `TableManager`: three closes an epoch of recurring and new keys,
+    `seal_op`, `capture`, and `flush_captured` `lag` epochs later (0 =
+    at the barrier's heels; 2 = two epochs late, while later epochs
+    stage and capture). Returns (the reports, {path: blob}, the view, its
+    last values, the batches staged by epoch)."""
+    from types import SimpleNamespace
+
+    from arroyo_tpu.serve.store import SERVE_TABLE, seal_op, stage_batch
+    from arroyo_tpu.state.backend import StateBackend
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.table_manager import TableManager
+    from arroyo_tpu.types import TaskInfo
+    from test_replica import _hop_batch  # (it imports this module's)
+
+    backend = StateBackend(url, "bi").initialize()
+    tm = TableManager(backend, TaskInfo("bi", 3, "hop", 0, 1), 0)
+    asyncio.run(tm.open({SERVE_TABLE: global_table(SERVE_TABLE)}))
+    view = _hop_view()
+    op = SimpleNamespace(_serve_view=view)
+    rng = np.random.default_rng(39)
+    inflight, reports, last, batches = [], {}, {}, {}
+
+    def flush():
+        epoch, staged = inflight.pop(0)
+        reports[epoch] = tm.flush_captured(epoch, staged)
+
+    for epoch in range(1, epochs + 1):
+        for close in range(3):
+            keys = np.concatenate([
+                rng.choice(np.arange(200), 60, replace=False),
+                np.arange(50) + 10_000 * epoch + 100 * close])
+            b = _hop_batch(rng, keys, 3 * epoch + close)
+            batches.setdefault(epoch, []).append(b)
+            stage_batch(view, b)
+            last.update(zip(keys.tolist(), b.column(2).to_pylist()))
+        seal_op(op, epoch, tm)
+        inflight.append((epoch, tm.capture(epoch, None)))
+        while len(inflight) > lag:
+            flush()
+    while inflight:
+        flush()
+    paths = {f["path"] for r in reports.values()
+             for f in r[SERVE_TABLE]["chain"]}
+    blobs = {path: backend.read_blob(path) for path in sorted(paths)}
+    return reports, blobs, view, last, batches
+
+
+def test_a_deferred_capture_writes_the_same_bytes_two_epochs_late(
+        tmp_path):
+    """The byte identity of ISSUE 39: resolved at the barrier's heels or
+    two epochs late, a capture writes the same blobs under the same paths
+    and reports the same chain (every `bytes` filled in, one compaction
+    of the mirror past `_MIRROR_SEGMENTS`, one rebase); the first two
+    blobs are the parent's arithmetic spelled out; a follower seeded from
+    the last chain serves what the worker serves."""
+    import msgpack
+    import pyarrow as pa
+
+    from arroyo_tpu.obs import timeline
+    from arroyo_tpu.serve.store import (
+        _MIRROR_SEGMENTS,
+        META_KEY,
+        SEG_PREFIX,
+        SERVE_TABLE,
+        _ipc_bytes,
+        _keep_last,
+        _Segment,
+        seed_from_mirror,
+    )
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.tables import GlobalTable
+
+    # the bytes rule would read an estimate while a flush is in flight
+    # (its own test below): here the length rule alone decides
+    with update(state={"rebase_bytes_factor": 1e9}):
+        timeline.clear()
+        at_once = _scripted_run(f"file://{tmp_path}/a", 0)
+        totals = timeline.totals()
+        late = _scripted_run(f"file://{tmp_path}/b", 2)
+    reports, blobs, view, last, batches = at_once
+    assert json.dumps(reports, sort_keys=True) == json.dumps(
+        late[0], sort_keys=True)
+    assert blobs == late[1] and len(blobs) == 20 and all(blobs.values())
+    chains = [reports[e][SERVE_TABLE]["chain"] for e in range(1, 21)]
+    for e, chain in enumerate(chains, 1):
+        assert chain[-1]["epoch"] == e
+        assert all(f["bytes"] == len(blobs[f["path"]]) for f in chain)
+    # base at 1, sixteen deltas, a rebase at 18
+    assert [len(c) for c in chains] == list(range(1, 18)) + [1, 2, 3]
+    assert [c[0]["base"] for c in chains] == [True] * 20
+    # every staged row was the flush's: the engagement share is 1.0
+    assert totals["flush.resolve"]["count"] == 20
+    assert totals["flush.resolve"]["n"] == totals["serve.seal"]["n"] == (
+        view.deferred_rows) == view.staged_rows == 20 * 3 * 110
+    # the parent's arithmetic for the first base and the first delta
+    for e in (1, 2):
+        seg = _Segment(table=_keep_last(pa.concat_tables(
+            [_Segment(raw=b).table(view) for b in batches[e]]), 1))
+        entries = [[f"{SEG_PREFIX}/0/{e}/{e - 1}",
+                    _ipc_bytes(seg.table(view)), e]]
+        if e == 1:
+            entries.insert(0, [META_KEY, view.describe(), 1])
+        assert blobs[chains[e - 1][-1]["path"]] == msgpack.packb(
+            {"v": 2, "b": e == 1, "e": entries, "t": []}, use_bin_type=True)
+    # the mirror compacted once (17 entries -> 9), through tombstones
+    assert len(view._mirror_log) == _MIRROR_SEGMENTS // 2 + 1 + 3
+    packed = msgpack.unpackb(blobs[chains[16][-1]["path"]], raw=False)
+    assert len(packed["t"]) == _MIRROR_SEGMENTS // 2
+    # and a follower over the last chain serves the worker's view
+    table = GlobalTable(global_table(SERVE_TABLE))
+    table.load_chain([blobs[f["path"]] for f in chains[-1]])
+    fview = _hop_view()
+    seed_from_mirror(fview, table)
+    for k in list(range(200)) + [10_000, 200_149, 7]:
+        want = (True, last[k]) if k in last else (False, None)
+        got = view.read((k,), 20)
+        assert (got[0], got[1] and got[1]["count"]) == want
+        assert fview.read((k,), 20) == got == late[2].read((k,), 20)
+    assert fview.stats()["keys"] == view.stats()["keys"] == len(last)
+
+
+def test_a_flush_in_flight_counts_by_its_staged_inputs_and_a_failure_writes_nothing(
+        tmp_path):
+    """`_should_rebase` at a barrier that finds an epoch's `bytes` still
+    unknown reckons with the staged inputs' size (else a chain nobody
+    flushed would never rebase by bytes); a resolution that raises fails
+    `flush_captured` before anything is written."""
+    from types import SimpleNamespace
+
+    from arroyo_tpu.serve.store import SERVE_TABLE, seal_op, stage_batch
+    from arroyo_tpu.state.backend import StateBackend
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.table_manager import TableManager
+    from arroyo_tpu.state.tables import Deferred
+    from arroyo_tpu.types import TaskInfo
+    from test_replica import _hop_batch
+
+    backend = StateBackend(f"file://{tmp_path}/r", "rb").initialize()
+    tm = TableManager(backend, TaskInfo("rb", 3, "hop", 0, 1), 0)
+    asyncio.run(tm.open({SERVE_TABLE: global_table(SERVE_TABLE)}))
+    view = _hop_view()
+    op = SimpleNamespace(_serve_view=view)
+    rng = np.random.default_rng(3)
+    staged = {}
+    for epoch in range(1, 6):
+        stage_batch(view, _hop_batch(rng, np.arange(500) + 1_000 * epoch,
+                                     epoch))
+        seal_op(op, epoch, tm)
+        staged[epoch] = tm.capture(epoch, None)
+    chains = [staged[e][SERVE_TABLE]["chain"] for e in range(1, 6)]
+    # equal epochs, factor 2.0: three deltas outweigh the base twice over
+    assert [len(c) for c in chains] == [1, 2, 3, 4, 1]
+    assert all(f["bytes"] is None for c in chains for f in c)
+    for epoch in range(1, 6):
+        meta = tm.flush_captured(epoch, staged[epoch])
+        assert all(f["bytes"] == len(backend.read_blob(f["path"]))
+                   for f in meta[SERVE_TABLE]["chain"])
+    assert tm._chains[SERVE_TABLE][0]["bytes"] > 0
+    assert list(tm._late_blobs[SERVE_TABLE]) == [
+        tm._chains[SERVE_TABLE][0]["path"]]
+
+    def boom():
+        raise RuntimeError("resolution failed")
+
+    tm.tables[SERVE_TABLE].put("__serve_seg__/0/6/5", Deferred(boom))
+    bad = tm.capture(6, None)
+    with pytest.raises(RuntimeError, match="resolution failed"):
+        tm.flush_captured(6, bad)
+    assert backend.read_blob(bad[SERVE_TABLE]["chain"][-1]["path"]) is None
+
+
+def test_a_read_racing_the_flush_gets_one_table_merged_once(monkeypatch):
+    """Once and only once: a `read` on the loop and the flush thread ask
+    for the same unresolved layer at the same moment, fifty times: both
+    get the one table, `_keep_last` ran once a round, and the read sees
+    exactly the epoch's view."""
+    import threading
+
+    from arroyo_tpu.serve import store
+    from arroyo_tpu.serve.store import stage_batch
+    from test_replica import _hop_batch
+
+    calls = []
+    keep_last = store._keep_last
+
+    def counted(table, n_keys):
+        calls.append(table.num_rows)
+        return keep_last(table, n_keys)
+
+    monkeypatch.setattr(store, "_keep_last", counted)
+    rng = np.random.default_rng(50)
+    view = _hop_view()
+    for epoch in range(1, 51):
+        for close in range(4):
+            stage_batch(view, _hop_batch(
+                rng, rng.choice(np.arange(5_000), 2_000, replace=False),
+                4 * epoch + close))
+        marker = _hop_batch(rng, [7], 4 * epoch + 4)
+        stage_batch(view, marker)
+        sealed = view.seal(epoch)
+        assert sealed.deferred and not calls
+        gate, got = threading.Barrier(2), []
+
+        def flush():
+            gate.wait()
+            got.append(sealed.table(view))
+
+        t = threading.Thread(target=flush)
+        t.start()
+        gate.wait()
+        found, value = view.read((7,), epoch)
+        t.join()
+        assert found and value["count"] == marker.column(2)[0].as_py()
+        assert got[0] is sealed.table(view) and not sealed.deferred
+        # (a fold past `_SERVED_SEGMENTS` layers compacts `served`: a
+        # merge of its own, of more rows)
+        assert [c for c in calls if c <= 8_001] == [4 * 2_000 + 1]
+        calls.clear()
+
+
+@pytest.mark.parametrize("case", ["segments", "partials", "updating",
+                                  "rows_after_a_batch"])
+def test_a_stage_with_a_dict_layer_seals_at_the_barrier(case, monkeypatch):
+    """The rule, read from the stage's layers: segments alone are filed
+    un-merged (`serve.seal` books their rows and `_keep_last` does not
+    run before the flush asks); session partials and an updating
+    aggregate's rows (a dict layer) seal at the barrier as ever."""
+    from arroyo_tpu.obs import timeline
+    from arroyo_tpu.serve import store
+    from arroyo_tpu.serve.store import SERVE_TABLE, seal_op, stage_batch
+    from arroyo_tpu.state.table_config import global_table
+    from arroyo_tpu.state.tables import Deferred, GlobalTable
+    from test_replica import _hop_batch
+
+    calls = []
+    keep_last = store._keep_last
+    monkeypatch.setattr(store, "_keep_last", lambda t, n: (
+        calls.append(t.num_rows), keep_last(t, n))[1])
+    timeline.clear()
+    rng = np.random.default_rng(4)
+    view = _hop_view()
+    op = type("Op", (), {"_serve_view": view})()
+    mirror = GlobalTable(global_table(SERVE_TABLE))
+    tm = type("TM", (), {"tables": {SERVE_TABLE: mirror}})()
+    if case != "updating":
+        stage_batch(view, _hop_batch(rng, np.arange(300), 1))
+        stage_batch(view, _hop_batch(rng, np.arange(200, 500), 2))
+    if case == "partials":
+        stage_batch(view, _hop_batch(rng, [900, 901], 3), partial=True)
+    elif case in ("updating", "rows_after_a_batch"):
+        view.stage((900,), {"count": 5})
+        view.stage_tomb((3,))
+    seal_op(op, 1, tm)
+    rows = {"segments": 600, "partials": 602, "updating": 2,
+            "rows_after_a_batch": 602}[case]
+    assert timeline.totals()["serve.seal"]["n"] == rows
+    seg_key = "__serve_seg__/0/1/0"
+    if case == "segments":
+        assert not calls and view.pending[1].deferred
+        assert view.deferred_rows == view.stats()["deferred_rows"] == 600
+        assert isinstance(mirror.raw(seg_key), Deferred)
+        blob, _ = mirror.serialize_delta(1)
+        assert callable(blob) and not calls
+        blob()
+        assert calls == [600] and not view.pending[1].deferred
+    else:
+        # merged (and mirrored) at the barrier, nothing left for a flush
+        assert view.deferred_rows == 0
+        if case == "updating":
+            assert isinstance(view.pending[1], dict) and not calls
+            assert mirror.get((900,)) == {"count": 5}
+        else:
+            assert calls == [rows] and not view.pending[1].deferred
+            assert isinstance(mirror.raw(seg_key), memoryview)
+        assert isinstance(mirror.serialize_delta(1)[0], bytes)
+    assert view.read((900,), 1)[0] == (case != "segments")
+    assert view.read((3,), 1)[0] == (case in ("segments", "partials"))
 
 
 def test_model_faithful_reader_clean_and_mutant_caught():

@@ -130,6 +130,40 @@ def test_global_capture_is_o_dirty():
     assert blob is None
 
 
+@pytest.mark.parametrize("base", [True, False])
+def test_the_flush_threads_packing_is_packb_byte_for_byte(base):
+    """ISSUE 39: a blob whose entries were deferred is framed by msgpack
+    and its large binary values copied in outside the GIL
+    (`_packb_beside_the_loop`): the bytes are `msgpack.packb`'s, whatever
+    the keys (strings, tuples), the values (bytes and bytes-like views on
+    either side of `_BIG_VALUE`, dicts, None) and the tombstones."""
+    import os
+
+    import msgpack
+
+    from arroyo_tpu.state.tables import _BIG_VALUE, _packb_beside_the_loop
+
+    sizes = [0, 1, 255, 256, _BIG_VALUE - 1, _BIG_VALUE, 3 * _BIG_VALUE + 7]
+    for n in (0, 1, 15, 16, 70):   # fixarray, array16
+        entries = []
+        for i in range(n):
+            value = os.urandom(sizes[i % len(sizes)])
+            if i % 5 == 1:
+                value = memoryview(value)
+            elif i % 5 == 2:
+                value = {"table": "t", "keys": [1, 2, {"x": None}], "f": 0.5}
+            entries.append([("seg", i) if i % 2 else f"seg/{i}", value, 7 * i])
+        tombs = [[f"gone/{i}", i] for i in range(n // 3)]
+        want = msgpack.packb(
+            {"v": 2, "b": base,
+             "e": [[k, bytes(v) if isinstance(v, memoryview) else v, st]
+                   for k, v, st in entries], "t": tombs},
+            use_bin_type=True)
+        got = _packb_beside_the_loop(base, entries, tombs)
+        assert bytes(got) == want and len(got) == len(want)
+        assert msgpack.unpackb(got, raw=False)["t"] == tombs
+
+
 def test_rebase_policy_truncates_chain(tmp_storage):
     """TableManager rebases once the chain carries state.rebase_epochs
     deltas (or delta bytes exceed the factor), and the manifest's chain

@@ -636,6 +636,77 @@ def test_a_phase_on_a_worker_thread_stays_out_of_the_loops_sums(every_edge):
     assert capture["self_cpu_s"] == capture["cpu_s"] < 0.03
 
 
+def test_flush_resolve_is_a_wait_that_keeps_its_worker_threads_cpu(
+        every_edge, monkeypatch):
+    """ISSUE 39: what a capture left to the flush is resolved on the storage
+    thread inside `flush.resolve`: one of `timeline.WAITS` (seconds beside
+    the loop, not the loop's named work) that, unlike a wait on the loop,
+    carries its thread's `cpu_s` (and, a worker's, no `self_cpu_s`). The
+    three readers that sum the loop's named work (`engine_unnamed_pct` of
+    BENCHMARK.json, and the two owed entries that read CPU) read the same
+    with such entries in the window and without."""
+    import asyncio
+    import importlib
+    import json
+
+    from arroyo_tpu.config import update
+
+    assert "flush.resolve" in timeline.WAITS
+
+    def resolve():
+        with timeline.phase("flush.resolve", task="3-0", key=7, n=1_000,
+                            annotate=False):
+            _spin(0.15)
+
+    async def barrier():
+        await asyncio.sleep(0.25)           # a tick names the loop's thread
+        with timeline.phase("process", job="fr", task="3-0",
+                            annotate=False):
+            with timeline.phase("ckpt.capture", key=7, annotate=False):
+                with timeline.phase("serve.seal", n=1_000, annotate=False):
+                    _spin(0.01)
+            flush = asyncio.ensure_future(asyncio.to_thread(resolve))
+            with timeline.phase("agg.pack"):
+                _spin(0.1)
+            with timeline.phase("queue.wait", annotate=False):
+                await flush
+        await asyncio.sleep(0.25)           # the last tick books `loop.run`
+
+    with update(obs={"loop_lag_interval": 0.1}):
+        _run_loop(barrier)
+    t = timeline.phase_totals()
+    res = t["flush.resolve"]
+    assert res["n"] == t["serve.seal"]["n"] == 1_000   # the engagement share
+    assert res["cpu_s"] > 0.04 and res["self_cpu_s"] == 0.0
+    assert t["queue.wait"]["cpu_s"] == 0.0             # a wait on the loop
+    # the worker's seconds are no child of the loop thread's enclosure
+    assert t["process"]["self_cpu_s"] < 0.05
+
+    monkeypatch.syspath_prepend(os.path.join(REPO, "benchmark"))
+    ledger_window = importlib.import_module("ledger_window")
+    with open(os.path.join(REPO, "tests", "benchmark", "data",
+                           "owed_entries.json")) as f:
+        owed = {m["name"] for m in json.load(f)["per_layer"]}
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        held = {m["name"] for m in json.load(f)["per_layer"]}
+    names = ["engine_unnamed_pct", "engine_unnamed_cpu_pct",
+             "host_leaf_offcore_pct"]
+    assert names[0] in held and set(names[1:]) <= owed
+    run = types.SimpleNamespace(start={"t_ns": 0}, end={"t_ns": 2 ** 62},
+                                window_s=1.0)
+
+    def read():
+        return [importlib.import_module(f"layer_metrics.{n}").read(run)
+                for n in names]
+
+    with_entries = read()
+    assert "flush.resolve" in ledger_window.totals(run)
+    real = ledger_window.totals
+    monkeypatch.setattr(ledger_window, "totals", lambda r: {
+        p: v for p, v in real(r).items() if p != "flush.resolve"})
+    assert read() == with_entries and None not in with_entries
+
+
 def test_a_fresh_signature_books_compile_and_a_seen_one_dispatch(every_edge):
     import jax
     import jax.numpy as jnp

@@ -7,6 +7,22 @@ end + watermark delay lies at or before the last delivered event time, rows
 keyed by the window end, exact equality as multisets (a result delivered
 twice is wrong), no tolerance. The windows that the end-of-stream flush
 emits early are partial on the program's side and are left out on both.
+
+Which events a reference reads and which results are due is the
+reference's to say (`benchmark/reference/<name>.py`):
+
+- `COLUMNS`, `compute(*stream, ends)` -> {key: sorted rows} and
+  `flows(*stream, ends)` -> [(what, rows in, rows out)]: every reference.
+- `READS`, a tuple of "person", "auction", "bid": the reference is handed
+  ONE argument, {kind: {"ts": event times, field: values}} of those kinds
+  (`gen.events`). One that declares nothing is handed the four bid columns
+  (event time, auction, bidder, price), as the first three were.
+- `SLIDE_NS`: its results end on that grid (`schedule.Grid`); or
+  `ends(*stream)` -> every key its results have over the events given: its
+  results end where the stream says (`schedule.Listed`: sessions). Off a
+  grid one rule is stricter: a produced key at or before the last due one
+  that the reference does not hold (a session split or merged wrongly) is
+  wrong. On a grid the program can produce no such key but by the flush.
 Beside the answers stands a conservation comparison that does not depend
 on them: the rows that each stateful step of the query took in and gave
 out, by the program's own per-task row counters, against the reference's
@@ -33,9 +49,11 @@ from typing import Callable, Dict, List
 
 import numpy as np
 
+import schedule
 from gen import nexmark as gen
 
 BLOCK = 1 << 20
+BID_READS = ("bid",)     # what a reference that declares no READS reads
 
 
 @dataclasses.dataclass
@@ -47,29 +65,48 @@ class Verdict:
 
 
 def bid_stream(feed, n_lo: int, n_hi: int):
-    """The bids among events [n_lo, n_hi), regenerated from the seed in
-    blocks: (event time, auction, bidder, price)."""
-    parts = [[], [], [], []]
-    for lo in range(n_lo, n_hi, BLOCK):
+    """The bids among events [n_lo, n_hi): (event time, auction, bidder,
+    price)."""
+    bid = event_stream(feed, BID_READS, n_lo, n_hi)["bid"]
+    return bid["ts"], bid["auction"], bid["bidder"], bid["price"]
+
+
+def event_stream(feed, reads, n_lo: int, n_hi: int) -> Dict[str, dict]:
+    """{kind: {"ts": event times, field: values}} of the events of the
+    kinds `reads` among events [n_lo, n_hi), regenerated from the seed in
+    blocks (`gen.events`)."""
+    parts: Dict[str, Dict[str, list]] = {kind: {} for kind in reads}
+    # an empty range still names every field, each with no values
+    for lo in range(n_lo, n_hi, BLOCK) or (n_lo,):
         ns = np.arange(lo, min(lo + BLOCK, n_hi), dtype=np.int64)
-        is_bid, auction, bidder, price = gen.bids(ns, feed.seed)
-        parts[0].append(feed.event_time_ns(ns[is_bid]))
-        for part, col in zip(parts[1:], (auction, bidder, price)):
-            part.append(col)
-    if not parts[0]:
-        return tuple(np.empty(0, dtype=np.int64) for _ in parts)
-    return tuple(np.concatenate(p) for p in parts)
+        block = gen.events(ns, feed.event_time_ns(ns), feed.seed, reads)
+        for kind, fields in block.items():
+            for name, col in fields.items():
+                parts[kind].setdefault(name, []).append(col)
+    return {kind: {name: np.concatenate(cols) for name, cols in p.items()}
+            for kind, p in parts.items()}
 
 
-def window_ends(feed, reference, n_hi: int) -> List[int]:
-    """Every window end that a real watermark closed: non-empty, and end +
-    delay <= the event time of the last delivered event."""
-    t_first = int(feed.event_time_ns(feed.n_first))
-    t_last = int(feed.event_time_ns(n_hi - 1))
-    slide = reference.SLIDE_NS
-    first = t_first // slide * slide + slide
-    last = (t_last - feed.watermark_delay_ns) // slide * slide
-    return list(range(first, last + 1, slide))
+def reads_of(reference) -> tuple:
+    return tuple(getattr(reference, "READS", BID_READS))
+
+
+def reference_stream(feed, reference, n_lo: int, n_hi: int) -> tuple:
+    """Events [n_lo, n_hi) as the reference's functions take them, to be
+    splatted in front of `ends`."""
+    if not hasattr(reference, "READS"):
+        return bid_stream(feed, n_lo, n_hi)
+    return (event_stream(feed, reads_of(reference), n_lo, n_hi),)
+
+
+def schedule_of(reference, feed):
+    """The object that says which of the reference's results are due, and
+    when (`schedule.py`)."""
+    if hasattr(reference, "SLIDE_NS"):
+        return schedule.Grid(feed, reference.SLIDE_NS)
+    return schedule.Listed(
+        feed, reference.ends,
+        lambda n_hi: reference_stream(feed, reference, feed.n_first, n_hi))
 
 
 def produced(feed, reference) -> Dict[int, dict]:
@@ -100,12 +137,16 @@ def judge(run, reference, config: dict, say: Callable[[str], None]) -> Verdict:
     feed = run.feed
     late_limit_ms = config.get("late_limit_ms")
     paced = feed.traffic.mode == "steady"
-    ends = window_ends(feed, reference, feed.n_delivered)
-    stream = bid_stream(feed, feed.n_first, feed.n_delivered)
+    due = feed.schedule
+    stream = reference_stream(feed, reference, feed.n_first, feed.n_delivered)
+    ends = due.due_by(feed.n_delivered, stream)
     want = reference.compute(*stream, ends)
     got = produced(feed, reference)
 
     wrong = [e for e in ends if e in got and got[e]["rows"] != want[e]]
+    if due.strict and ends:
+        # a key the reference does not hold, where every key is due
+        wrong += sorted(e for e in got if e <= ends[-1] and e not in want)
     missing = [e for e in ends if e not in got]
     rows = sum(len(want[e]) for e in ends)
     say(f"compared: windows={len(ends)} rows={rows} "
@@ -113,7 +154,7 @@ def judge(run, reference, config: dict, say: Callable[[str], None]) -> Verdict:
     for e in (wrong + missing)[:3]:
         say(f"  window end {e}: got "
             f"{got.get(e, {}).get('rows', 'nothing')!s:.200} "
-            f"want {want[e]!s:.200}")
+            f"want {want.get(e, 'no such key')!s:.200}")
     cadence_ok = cadence(run, say)
     unbooked = conservation(run.flow, reference.flows(*stream, ends), say)
 
@@ -123,8 +164,7 @@ def judge(run, reference, config: dict, say: Callable[[str], None]) -> Verdict:
             else feed.n_window_end)
     closes = []
     bad = set(wrong) | set(missing)
-    for end in feed.closes_between(n_lo, n_hi):
-        n_due = feed.due_event(end)
+    for end, n_due in due.due_between(n_lo, n_hi):
         # a close due by the schedule whose events were never delivered
         # (the run fell behind) is not in `want`, and failed
         c = {"end": end, "due_event": n_due,
@@ -218,14 +258,16 @@ def conservation(flow: Dict[str, tuple], wanted, say) -> int:
     return unbooked
 
 
-def pick_fault(kind: str, traffic, seed: int):
-    """The bid a control run loses or repeats at the source: drawn from
-    the seed among the bids of the warm-up (every run delivers those),
-    whatever the answers rest on."""
+def pick_fault(kind: str, traffic, seed: int, reads=BID_READS):
+    """The event a control run loses or repeats at the source: drawn from
+    the seed among the warm-up's events (every run delivers those) of the
+    kinds the reference reads, bids unless it declares more, whatever the
+    answers rest on."""
     from feed import Fault, Feed
 
     feed = Feed(traffic, seed, 0.0)
     ns = np.arange(feed.n_first, feed.n_warm, dtype=np.int64)
-    is_bid = gen.bids(ns, seed)[0]
-    n = np.random.default_rng(seed).choice(ns[is_bid])
+    masks = gen.kinds(ns)
+    read = np.logical_or.reduce([masks[k] for k in reads])
+    n = np.random.default_rng(seed).choice(ns[read])
     return Fault(kind, int(n))

@@ -131,23 +131,27 @@ class Feed:
         self.published_epoch: Callable[[], int] = lambda: 0
         self.long_stall_end_ns: Optional[int] = None     # wall clock
         self.longest_stall_s = 0.0      # of the whole run, for the log
-        # set by run.py from the configuration and its reference
-        self.slide_ns = 0
+        # set by run.py from the configuration and its reference: which
+        # results are due, and when (`schedule.py`)
         self.watermark_delay_ns = NS
+        self.schedule = None
 
     # -- the schedule -------------------------------------------------------
 
     def event_time_ns(self, n) -> np.ndarray:
         return gen.event_times(n, 0, self.rate)
 
-    def first_event_at(self, t_ns: int) -> int:
-        """The first sequence number whose event time is >= t_ns."""
-        n = int(np.ceil(t_ns * self.rate / NS))
-        while n > 0 and int(self.event_time_ns(n - 1)) >= t_ns:
-            n -= 1
-        while int(self.event_time_ns(n)) < t_ns:
-            n += 1
-        return n
+    def first_event_at(self, t_ns):
+        """The first sequence number whose event time is >= t_ns; an array
+        of times gives an array."""
+        t = np.asarray(t_ns, dtype=np.int64)
+        n = np.ceil(t * self.rate / NS).astype(np.int64)
+        while True:                     # the float's last place, both ways
+            early = (n > 0) & (self.event_time_ns(np.maximum(n - 1, 0)) >= t)
+            late = self.event_time_ns(n) < t
+            if not (early.any() or late.any()):
+                return n if n.ndim else int(n)
+            n = n - early + late
 
     def due_wall(self, n: int) -> float:
         """Wall time (monotonic) at which event n is due in a paced run."""
@@ -253,7 +257,7 @@ class Feed:
         warm-up made due has reached the sink, so that the warm-up's own
         work (and, in a checkout's first run, its compiles) stays in
         set-up. The edge queues refill within the window's first second."""
-        last_due = self.last_due_close(self.n_warm)
+        last_due = self.schedule.last_due(self.n_warm)
         t_end = time.monotonic() + timeout
         while time.monotonic() < t_end:
             if (last_due is None or self.close_arrived(last_due)) and (
@@ -338,37 +342,6 @@ class Feed:
     def close_arrived(self, window_end_ns: int) -> bool:
         """A result row carries its window's last nanosecond."""
         return self.last_arrived_ts >= window_end_ns - 1
-
-    # -- closes -------------------------------------------------------------
-
-    def due_event(self, window_end_ns: int) -> int:
-        """The event whose delivery makes the close of this window due:
-        the first with event time >= window end + watermark delay."""
-        return self.first_event_at(window_end_ns + self.watermark_delay_ns)
-
-    def last_due_close(self, n_end: int) -> Optional[int]:
-        """The last window end made due by events [.., n_end)."""
-        if n_end <= self.n_first:
-            return None
-        t_last = int(self.event_time_ns(n_end - 1))
-        end = (t_last - self.watermark_delay_ns) // self.slide_ns * self.slide_ns
-        t_first = int(self.event_time_ns(self.n_first))
-        return end if end > t_first else None
-
-    def closes_between(self, n_lo: int, n_hi: int) -> List[int]:
-        """Window ends whose due event lies in [n_lo, n_hi)."""
-        if n_hi <= n_lo:
-            return []
-        out = []
-        t_lo = int(self.event_time_ns(n_lo)) - self.watermark_delay_ns
-        end = t_lo // self.slide_ns * self.slide_ns
-        while True:
-            n_due = self.due_event(end)
-            if n_due >= n_hi:
-                return out
-            if n_due >= n_lo:
-                out.append(end)
-            end += self.slide_ns
 
 
 def _timestamps(batch) -> np.ndarray:

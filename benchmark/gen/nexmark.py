@@ -149,6 +149,12 @@ def _person_fields(ns, key):
     return first, last, city, state, cc
 
 
+def _person_names(first, last) -> list:
+    """"<first> <last>" per person, from `_person_fields`' two draws."""
+    return [f"{_FIRST[f]} {_LAST[l]}"
+            for f, l in zip(first.tolist(), last.tolist())]
+
+
 def _active_person(last_person0, u):
     """`PersonGenerator.nextBase0PersonId`: one of the last
     NUM_ACTIVE_PEOPLE people, or of the PERSON_ID_LEAD not yet created."""
@@ -257,10 +263,7 @@ def gen_batch(ns: np.ndarray, ts: np.ndarray, seed: int = 0) -> "pa.RecordBatch"
         pns = ns[pi]
         first, last, city, state, cc = _person_fields(pns, key)
         ids = FIRST_PERSON_ID + pns // PROPORTION_DENOMINATOR
-        names = [
-            f"{_FIRST[f]} {_LAST[l]}"
-            for f, l in zip(first.tolist(), last.tolist())
-        ]
+        names = _person_names(first, last)
         emails = [
             f"{nm.replace(' ', '.').lower()}@example.com" for nm in names
         ]
@@ -376,7 +379,56 @@ def bids(ns: np.ndarray, seed: int):
     """(mask of bid events, auction, bidder, price of those) for the
     sequence numbers `ns`: what a plain reference needs of the stream."""
     ns = np.asarray(ns, dtype=np.int64)
-    is_bid = ns % PROPORTION_DENOMINATOR >= (
-        PERSON_PROPORTION + AUCTION_PROPORTION)
+    is_bid = kinds(ns)["bid"]
     auction, bidder, price, _channel = _bid_fields(ns[is_bid], seed_key(seed))
     return is_bid, auction, bidder, price
+
+
+def kinds(ns: np.ndarray) -> dict:
+    """{"person" | "auction" | "bid": mask of that kind's events} among the
+    sequence numbers `ns`."""
+    offs = np.asarray(ns, dtype=np.int64) % PROPORTION_DENOMINATOR
+    is_person = offs < PERSON_PROPORTION
+    is_bid = offs >= PERSON_PROPORTION + AUCTION_PROPORTION
+    return {"person": is_person, "auction": ~is_bid & ~is_person,
+            "bid": is_bid}
+
+
+def events(ns: np.ndarray, ts: np.ndarray, seed: int,
+           reads=("person", "auction", "bid")) -> dict:
+    """{kind: {"ts": event times, field: values}} of the events of each
+    kind in `reads` among the sequence numbers `ns` with event times `ts`:
+    what a plain reference over more than the bids needs of the stream, as
+    `gen_batch` sends it. A person's id, name, city and state (q3, q8); an
+    auction's id, seller, category, initial bid, reserve and expiry (q4,
+    q6, q8, q9); a bid's auction, bidder and price. Every draw is
+    `_person_fields`', `_auction_fields`' or `_bid_fields`', the ones
+    `gen_batch` makes; strings come as object arrays."""
+    ns = np.asarray(ns, dtype=np.int64)
+    ts = np.asarray(ts, dtype=np.int64)
+    key = seed_key(seed)
+    masks = kinds(ns)
+    out = {}
+    for kind in reads:
+        m = masks[kind]
+        kns, kts = ns[m], ts[m]
+        if kind == "person":
+            first, last, city, state, _cc = _person_fields(kns, key)
+            fields = {
+                "id": FIRST_PERSON_ID + kns // PROPORTION_DENOMINATOR,
+                "name": np.asarray(_person_names(first, last), dtype=object),
+                "city": np.asarray(_CITIES, dtype=object)[city],
+                "state": np.asarray(_STATES, dtype=object)[state]}
+        elif kind == "auction":
+            seller, initial, reserve, expires_s, category = _auction_fields(
+                kns, key)
+            fields = {
+                "id": _last_auction_ids(kns), "seller": seller,
+                "category": category, "initial_bid": initial,
+                "reserve": reserve,
+                "expires": kts + expires_s * 1_000_000_000}
+        else:
+            auction, bidder, price, _channel = _bid_fields(kns, key)
+            fields = {"auction": auction, "bidder": bidder, "price": price}
+        out[kind] = {"ts": kts, **fields}
+    return out

@@ -7,7 +7,7 @@ the GIL (the benchmark's producer thread shares the process), nor for the
 OS, nor a blocking wait for the device inside a leaf of
 `timeline.DEVICE_WAITS`: all three are in here, and
 `host_leaf_offcore_pct` tells the first two from the third. None where the
-program books no `loop.run`. No entry yet: see `loop_idle_pct.py`."""
+program books no `loop.run`. Entered with `loop_idle_pct.py`."""
 
 import ledger_window
 

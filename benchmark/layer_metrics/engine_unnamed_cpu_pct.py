@@ -7,7 +7,7 @@ machinery, the controller and the benchmark's source. `self_cpu_s` holds
 the loop thread's CPU alone, so a phase on a worker thread does not enter
 the subtraction. What `engine_unnamed_pct` reads is this, plus
 `loop_idle_pct`, plus the part of `engine_offcore_pct` outside named
-phases. None where the program books no `loop.run`. No entry yet: see
+phases. None where the program books no `loop.run`. Entered with
 `loop_idle_pct.py`."""
 
 import ledger_window

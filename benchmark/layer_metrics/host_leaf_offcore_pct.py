@@ -5,7 +5,7 @@ every ledger phase outside `timeline.ENCLOSING`, `WAITS` and
 `audit.attest`, ...) is plain host code, so its wall less its CPU is a wait
 for the GIL or for the OS and nothing else: the number PERF.md's "waits
 behind the producer's GIL" was an inference for. None where the program
-books no `loop.run`. No entry yet: see `loop_idle_pct.py`."""
+books no `loop.run`. Entered with `loop_idle_pct.py`."""
 
 import ledger_window
 

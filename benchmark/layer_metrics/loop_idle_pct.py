@@ -6,10 +6,8 @@ waiting on a queue or a timer at once. With `engine_offcore_pct` and
 `engine_unnamed_cpu_pct` it splits what `engine_unnamed_pct` adds up.
 None where the program books no `loop.run` (a parent of ISSUE 38).
 
-No entry in BENCHMARK.json yet (`tests/benchmark/data/owed_entries.json`
-holds it word for word): `tests/benchmark/test_bench_ledger_metrics.py`
-holds the entries of source `program_span` to its eight; a builder reads it
-through `--benchmark-file`."""
+Its entry stands in BENCHMARK.json since ISSUE 40 (all four cells), word
+for word as `tests/benchmark/data/owed_entries.json` held it."""
 
 import ledger_window
 

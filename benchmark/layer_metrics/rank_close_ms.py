@@ -6,12 +6,8 @@ queue's wait run inside it (`operators/window_fn.py`
 `WindowFunctionOperator.handle_watermark`). `rank.buffer` is per batch and
 is not a close's. A program that books none of them gives None.
 
-No entry in BENCHMARK.json yet: its source is `program_span`, and
-`tests/benchmark/test_bench_ledger_metrics.py` holds the entries of that
-source to its eight, each on `q5.catchup` alone. The `benchmark` issue that
-relaxes the pin adds the entry (unit ms, layer "window functions", moves
-`events_per_s`, cell `top5-hop60.catchup`); until then a builder reads it
-from a traced run (PERF.md section 5)."""
+Its entry stands in BENCHMARK.json since ISSUE 40 (unit ms, layer "window
+functions", moves `events_per_s`, cell `top5-hop60.catchup`)."""
 
 import ledger_window
 

@@ -356,10 +356,11 @@ def main(argv=None) -> int:
     ap.add_argument("--control", choices=("drop", "dup", "cadence"),
                     default=None,
                     help="a control run, which breaks one stated guarantee: "
-                    "one bid drawn from the seed is lost (drop) or delivered "
-                    "twice (dup) at the source, or barriers go out at a "
-                    "quarter of the stated cadence; `correct` must come out "
-                    "false")
+                    "one event drawn from the seed among those the reference "
+                    "reads (a bid, unless it declares more) is lost (drop) "
+                    "or delivered twice (dup) at the source, or barriers go "
+                    "out at a quarter of the stated cadence; `correct` must "
+                    "come out false")
     ap.add_argument("--benchmark-file", default=None,
                     help="entries read from this file instead of "
                     "BENCHMARK.json (tests rehearse files that no cell "
@@ -408,12 +409,13 @@ def main(argv=None) -> int:
     reference = load_module("reference", cell.config["reference"])
     fault = None
     if args.control in ("drop", "dup"):
-        fault = check.pick_fault(args.control, traffic, args.seed)
+        fault = check.pick_fault(args.control, traffic, args.seed,
+                                 check.reads_of(reference))
         say(f"control: {fault.kind} event {fault.event}")
     feed = feed_mod.Feed(traffic, args.seed, args.seconds, fault)
-    feed.slide_ns = reference.SLIDE_NS
     feed.watermark_delay_ns = int(
         cell.config.get("watermark_delay_s", 1.0) * feed_mod.NS)
+    feed.schedule = check.schedule_of(reference, feed)
     feed.long_stall_s = float(
         cell.config.get("setup_long_stall_s", feed.long_stall_s))
     run = Run(cell, args, device)

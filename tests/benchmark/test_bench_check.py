@@ -10,6 +10,7 @@ import pyarrow as pa
 import pytest
 
 import check
+import schedule
 from feed import NS, Feed, Traffic
 from reference import q5, q7
 
@@ -23,7 +24,7 @@ def make_feed(reference=q5, n_events=30_000):
     t = Traffic(mode="catchup", nominal_rate=RATE, first_event=FIRST,
                 warm_event_seconds=12, batch_rows=100)
     f = Feed(t, SEED, seconds=10.0)
-    f.slide_ns = reference.SLIDE_NS
+    f.schedule = check.schedule_of(reference, f)
     f.n_window_start = f.n_warm
     f.n_delivered = f.n_window_end = FIRST + n_events
     f.t_window_start, f.t_window_end = 0.0, 10.0
@@ -77,7 +78,7 @@ def judge(feed, reference, checkpoints=5, window_s=10.0, barriers=(),
 
 
 def engine_results(feed, reference, **fault):
-    ends = check.window_ends(feed, reference, feed.n_delivered)
+    ends = feed.schedule.due_by(feed.n_delivered)
     # the end-of-stream flush also emits the windows still open
     flush = [ends[-1] + reference.SLIDE_NS * k for k in (1, 2)]
     return reference.compute(*stream(feed, **fault), ends + flush)
@@ -86,7 +87,7 @@ def engine_results(feed, reference, **fault):
 def engine_flow(feed, reference, **fault):
     """{task: (rows received, rows sent)} of an engine that is exact on the
     stream it was given, with a stateless task before and after."""
-    ends = check.window_ends(feed, reference, feed.n_delivered)
+    ends = feed.schedule.due_by(feed.n_delivered)
     steps = reference.flows(*stream(feed, **fault), ends)
     flow = {f"{k}-0": (rows_in, rows_out)
             for k, (_what, rows_in, rows_out) in enumerate(steps, 2)}
@@ -98,7 +99,7 @@ def engine_flow(feed, reference, **fault):
 def a_bid_no_answer_rests_on(feed, reference):
     """A bid drawn from the seed whose loss or repeat changes no answer."""
     ts, auction, bidder, price = stream(feed)
-    ends = check.window_ends(feed, reference, feed.n_delivered)
+    ends = feed.schedule.due_by(feed.n_delivered)
     sound = reference.compute(ts, auction, bidder, price, ends)
     rng = np.random.default_rng(SEED)
     while True:
@@ -110,7 +111,7 @@ def a_bid_no_answer_rests_on(feed, reference):
 
 def a_bid_an_answer_rests_on(feed, reference):
     ts, auction, bidder, price = stream(feed)
-    ends = check.window_ends(feed, reference, feed.n_delivered)
+    ends = feed.schedule.due_by(feed.n_delivered)
     end = ends[len(ends) // 2]
     row = reference.compute(ts, auction, bidder, price, [end])[end][0]
     m = (ts >= end - reference.SIZE_NS) & (ts < end)
@@ -202,7 +203,7 @@ def test_a_result_delivered_twice_is_not_correct():
 def test_a_missing_window_is_not_correct_and_counts_as_failed():
     feed = make_feed()
     results = engine_results(feed, q5)
-    ends = check.window_ends(feed, q5, feed.n_delivered)
+    ends = feed.schedule.due_by(feed.n_delivered)
     del results[ends[-1]]            # a close that became due in the window
     deliver(feed, q5, results)
     v, said = judge(feed, q5)
@@ -223,12 +224,12 @@ def test_a_paced_close_later_than_the_limit_counts_as_failed():
     t = Traffic(mode="steady", nominal_rate=RATE, first_event=FIRST,
                 warm_event_seconds=12)
     feed = Feed(t, SEED, seconds=10.0)
-    feed.slide_ns = 2 * NS
+    feed.schedule = schedule.Grid(feed, 2 * NS)
     feed.n_window_start = feed.n_warm
     feed.n_delivered = feed.n_window_end = feed.n_warm + 10_000
     feed.t_window_start, feed.t_window_end = 1000.0, 1010.0
     results = engine_results(feed, q5)
-    due = {e: feed.due_wall(feed.due_event(e)) for e in results}
+    due = {e: feed.due_wall(feed.schedule.due_event(e)) for e in results}
     import time as time_mod
 
     real = time_mod.monotonic_ns

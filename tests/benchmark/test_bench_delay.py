@@ -5,6 +5,7 @@ import math
 import pytest
 
 import delay
+import schedule
 from feed import NS, Feed, Traffic
 
 
@@ -12,8 +13,8 @@ def feed(mode="steady", rate=1000.0, first=20_000, seconds=20.0):
     t = Traffic(mode=mode, nominal_rate=rate, first_event=first,
                 warm_event_seconds=12, batch_rows=100, chunk_seconds=0.02)
     f = Feed(t, seed=1, seconds=seconds)
-    f.slide_ns = 2 * NS
     f.watermark_delay_ns = NS
+    f.schedule = schedule.Grid(f, 2 * NS)
     return f
 
 
@@ -38,13 +39,15 @@ def test_the_warm_up_is_a_whole_number_of_window_sized_batches():
 def test_a_close_is_due_when_end_plus_watermark_delay_has_happened():
     f = feed()
     # window end 34 s + 1 s delay: the event at 35 s is number 35,000
-    assert f.due_event(34 * NS) == 35_000
+    due = f.schedule
+    assert due.due_event(34 * NS) == 35_000
     # events 32,000..36,999 make the closes at 32 (due 33,000) and 34 due
-    assert f.closes_between(32_000, 37_000) == [32 * NS, 34 * NS]
+    assert due.due_between(32_000, 37_000) == [
+        (32 * NS, 33_000), (34 * NS, 35_000)]
     # a range's upper end is exclusive
-    assert f.closes_between(32_000, 35_000) == [32 * NS]
-    assert f.closes_between(35_000, 35_001) == [34 * NS]
-    assert f.last_due_close(f.n_warm) == 30 * NS
+    assert due.due_between(32_000, 35_000) == [(32 * NS, 33_000)]
+    assert due.due_between(35_000, 35_001) == [(34 * NS, 35_000)]
+    assert due.last_due(f.n_warm) == 30 * NS
 
 
 def test_result_delay_is_arrival_minus_the_due_wall_time():

@@ -14,14 +14,20 @@ import pytest
 
 import run as bench_run
 import trace_reduce
-from bench_helpers import REPO, rehearse
+from bench_helpers import REPO, listed, rehearse
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
-LEDGER = {m["name"]: m for m in BENCH["per_layer"]
-          if m["source"] == "program_span"}
+EIGHT = ["dir_assign_us_per_kevent", "agg_pack_us_per_kevent",
+         "agg_update_rows_per_call", "agg_update_pad_pct", "close_host_ms",
+         "close_combine_ms", "join_close_ms", "engine_unnamed_pct"]
+LEDGER = {m["name"]: m for m in BENCH["per_layer"] if m["name"] in EIGHT}
 COUNTED = {"host_cpu_cores", "dispatches_per_mevent",
            "compiles_in_window.catchup"}     # what a CPU run gave before
+# the loop's clock, entered by ISSUE 40 in every cell
+# (test_bench_loop_clock.py rehearses it)
+LOOP = {"loop_idle_pct", "engine_offcore_pct", "host_leaf_offcore_pct",
+        "engine_unnamed_cpu_pct"}
 
 
 @pytest.fixture(scope="module")
@@ -38,13 +44,17 @@ def value(line, name):
 
 
 def test_the_entries_are_the_eight_of_the_issue():
-    assert list(LEDGER) == [
-        "dir_assign_us_per_kevent", "agg_pack_us_per_kevent",
-        "agg_update_rows_per_call", "agg_update_pad_pct", "close_host_ms",
-        "close_combine_ms", "join_close_ms", "engine_unnamed_pct"]
+    """These eight are there, unchanged and in their order; a later
+    `program_span` entry (the loop's clock, `rank_close_ms`: ISSUE 40)
+    comes after them and is its own test's to hold."""
+    assert list(LEDGER) == EIGHT
     for m in LEDGER.values():
+        assert m["source"] == "program_span"
         assert m["moves"] == "events_per_s"
         assert m["workloads"] == ["q5.catchup"]
+    spans = [m["name"] for m in BENCH["per_layer"]
+             if m["source"] == "program_span"]
+    assert spans[:8] == EIGHT
 
 
 @pytest.mark.parametrize("name", sorted(LEDGER))
@@ -56,7 +66,8 @@ def test_each_metric_is_in_the_traced_line_with_its_unit(traced, name):
 
 
 def test_the_line_holds_the_old_metrics_and_the_new_and_no_other(traced):
-    assert set(traced["metrics"]) == COUNTED | set(LEDGER)
+    assert COUNTED | set(LEDGER) | LOOP <= set(
+        traced["metrics"]) <= listed(BENCH, "q5.catchup", "per_layer")
     assert "breakdown" not in traced           # a CPU trace has no device
 
 

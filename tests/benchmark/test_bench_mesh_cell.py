@@ -12,7 +12,7 @@ import types
 import pytest
 
 import run as bench_run
-from bench_helpers import HERE, REPO, rehearse
+from bench_helpers import HERE, REPO, listed, rehearse
 
 CELL = "q5-mesh4.catchup"
 CONFIG = "nexmark-q5-mesh4"
@@ -25,6 +25,12 @@ COUNTED_NEW = set(NEW) - {"mesh_route_call_us"}
 # what the cell shares with the one-chip cells and a CPU run can give
 COUNTED = {"host_cpu_cores", "dispatches_per_mevent",
            "compiles_in_window.catchup"}
+# the loop's clock (ISSUE 38; entered by ISSUE 40 in all four cells): host
+# times the program's ledger books, so a CPU run gives them too
+LOOP = {"loop_idle_pct", "engine_offcore_pct", "host_leaf_offcore_pct",
+        "engine_unnamed_cpu_pct"}
+# the cells the benchmark had when this one was appended, in their order
+OLDER = ["q5.catchup", "q7.catchup-25k"]
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
@@ -49,8 +55,8 @@ def test_the_cell_is_the_kept_configuration_on_four_chips():
     cell = entry("workloads", CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "catchup-100k", 4)
-    # the one cell that may ask for four, and the last of the list
-    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [
+    # the first cell to ask for four
+    assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4][:1] == [
         CELL]
     with open(os.path.join(HERE, "data", "future_cells.json")) as f:
         kept = next(c for c in json.load(f)["configs"]
@@ -93,20 +99,24 @@ def test_the_traffic_is_the_backlog_replay_the_issue_names():
 def test_the_cell_reports_what_the_issue_lists_and_no_ledger_span():
     mine = {m["name"]: m for m in BENCH["end_to_end"] + BENCH["per_layer"]
             if "workloads" not in m or CELL in m["workloads"]}
-    assert set(mine) == {
-        "setup_s", "events_per_s", "device_idle_pct.catchup",
-        "state_hbm_peak_mb"} | COUNTED | set(NEW)
-    assert not [m for m in mine.values() if m["source"] == "program_span"]
+    known = {"setup_s", "events_per_s", "device_idle_pct.catchup",
+             "state_hbm_peak_mb"} | COUNTED | set(NEW) | LOOP
+    assert known <= set(mine)           # a later PR may list the cell too
+    # no ledger span of its own: the loop's clock is every cell's
+    assert {n for n in known
+            if mine[n]["source"] == "program_span"} == LOOP
     for name, (unit, better, source) in NEW.items():
         m = mine[name]
         assert (m["unit"], m["better"], m["source"], m["layer"],
                 m["moves"], m["workloads"]) == (
             unit, better, source, "mesh exchange", "events_per_s", [CELL])
-    # appended, nothing before them moved
-    assert [m["name"] for m in BENCH["per_layer"]][-4:] == list(NEW)
-    for name in mine:
+    # appended when the list held sixteen, and the older entries stand
+    # where they stood, in their order: a later cell's come after them (an
+    # entry put before the end reads as a change to what was there)
+    assert [m["name"] for m in BENCH["per_layer"]][16:20] == list(NEW)
+    for name in known:
         if name not in NEW and "workloads" in mine[name]:
-            assert mine[name]["workloads"][-1] == CELL
+            assert mine[name]["workloads"][:3] == OLDER + [CELL], name
 
 
 def test_the_rehearsal_is_correct_over_more_than_ten_closes(traced):
@@ -121,7 +131,8 @@ def test_the_rehearsal_is_correct_over_more_than_ten_closes(traced):
 
 
 def test_the_traced_line_holds_the_counted_metrics_and_the_three_new(traced):
-    assert set(traced["metrics"]) == COUNTED | COUNTED_NEW
+    assert COUNTED | COUNTED_NEW | LOOP <= set(
+        traced["metrics"]) <= listed(BENCH, CELL, "per_layer")
     assert "breakdown" not in traced           # a CPU trace has no device
     for name in COUNTED_NEW:
         assert traced["metrics"][name]["unit"] == NEW[name][0]
@@ -144,7 +155,8 @@ def test_the_exchange_ships_filler_and_one_shard_owns_the_most(traced):
 def test_the_untraced_line_holds_the_end_to_end_metrics():
     line, _said = rehearse(CELL, seed=2**31 + 31, seconds=4)
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == {"setup_s", "events_per_s"}
+    assert {"setup_s", "events_per_s"} <= set(
+        line["metrics"]) == listed(BENCH, CELL, "end_to_end")
     assert line["metrics"]["events_per_s"]["value"] > 0
 
 

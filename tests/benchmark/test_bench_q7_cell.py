@@ -12,13 +12,17 @@ import types
 import pytest
 
 import run as bench_run
-from bench_helpers import HERE, REPO, rehearse
+from bench_helpers import HERE, REPO, listed, rehearse
 
 CELL = "q7.catchup-25k"
 NEW = {"dir_new_slot_pct": "%", "ckpt_delta_krows_per_capture": "krows"}
 # what the cell shares with q5.catchup and a CPU run can give
 COUNTED = {"host_cpu_cores", "dispatches_per_mevent",
            "compiles_in_window.catchup"}
+# the loop's clock (ISSUE 38; entered by ISSUE 40 in all four cells): host
+# times the program's ledger books, so a CPU run gives them too
+LOOP = {"loop_idle_pct", "engine_offcore_pct", "host_leaf_offcore_pct",
+        "engine_unnamed_cpu_pct"}
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
@@ -66,12 +70,14 @@ def test_the_traffic_is_catchup_100k_at_a_quarter_of_the_rate():
 def test_the_cell_reports_what_the_issue_lists_and_no_ledger_span():
     mine = {m["name"]: m for m in BENCH["end_to_end"] + BENCH["per_layer"]
             if "workloads" not in m or CELL in m["workloads"]}
-    assert set(mine) == {
-        "setup_s", "events_per_s", "agg_update_call_us",
-        "device_idle_pct.catchup", "state_hbm_peak_mb"} | COUNTED | set(NEW)
-    # the eight `program_span` metrics stay q5.catchup's until a
-    # `benchmark` issue relaxes the test that pins them
-    assert not [m for m in mine.values() if m["source"] == "program_span"]
+    known = {"setup_s", "events_per_s", "agg_update_call_us",
+             "device_idle_pct.catchup", "state_hbm_peak_mb"} | COUNTED | set(
+                 NEW) | LOOP
+    assert known <= set(mine)           # a later PR may list the cell too
+    # the eight older `program_span` metrics stay q5.catchup's (ISSUE 40
+    # relaxed the pin and entered the loop's clock, nothing else)
+    assert {n for n in known
+            if mine[n]["source"] == "program_span"} == LOOP
     for name, unit in NEW.items():
         m = mine[name]
         assert (m["unit"], m["source"], m["moves"], m["workloads"]) == (
@@ -89,7 +95,8 @@ def test_the_rehearsal_is_correct_over_more_than_ten_closes(traced):
 
 
 def test_the_traced_line_holds_the_counted_metrics_and_the_two_new(traced):
-    assert set(traced["metrics"]) == COUNTED | set(NEW)
+    assert COUNTED | set(NEW) | LOOP <= set(
+        traced["metrics"]) <= listed(BENCH, CELL, "per_layer")
     assert "breakdown" not in traced           # a CPU trace has no device
     for name, unit in NEW.items():
         assert traced["metrics"][name]["unit"] == unit
@@ -112,7 +119,8 @@ def test_a_capture_carries_thousands_of_rows(traced):
 def test_the_untraced_line_holds_the_end_to_end_metrics():
     line, _said = rehearse(CELL, seed=2**31 + 27, seconds=5)
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == {"setup_s", "events_per_s"}
+    assert {"setup_s", "events_per_s"} <= set(
+        line["metrics"]) == listed(BENCH, CELL, "end_to_end")
     assert line["metrics"]["events_per_s"]["value"] > 0
 
 

@@ -7,6 +7,7 @@ import time
 
 import pytest
 
+import schedule
 from feed import Feed, Traffic
 
 ROWS = 100
@@ -37,7 +38,7 @@ def drive(seconds, **engine):
     traffic = Traffic(mode="catchup", nominal_rate=1000.0,
                       warm_event_seconds=0.5, batch_rows=ROWS)
     feed = Feed(traffic, seed=3, seconds=seconds)
-    feed.slide_ns = 2_000_000_000
+    feed.schedule = schedule.Grid(feed, 2_000_000_000)
     ends = []
     feed.on_window_end = lambda: ends.append(time.monotonic())
     eng = Engine(feed, **engine)
@@ -92,7 +93,7 @@ def drive_through_a_compile(long_stall_s, publish_after):
     traffic = Traffic(mode="catchup", nominal_rate=1000.0,
                       warm_event_seconds=0.5, batch_rows=ROWS)
     feed = Feed(traffic, seed=3, seconds=0.2)
-    feed.slide_ns = 2_000_000_000
+    feed.schedule = schedule.Grid(feed, 2_000_000_000)
     feed.long_stall_s = long_stall_s
     published = []
 

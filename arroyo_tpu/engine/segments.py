@@ -719,28 +719,31 @@ class FusedSegmentOperator(Operator):
             )
 
     def _run_host(self, batch: pa.RecordBatch) -> Optional[pa.RecordBatch]:
-        from ..sql.expressions import _LazyFilteredBatch
+        views = []  # the stages' filtered views, booked once they are read
+
+        def materialize(cur) -> pa.RecordBatch:
+            out = _materialize(cur)
+            for v in views:
+                v.book()
+            views.clear()
+            return out
 
         cur = batch
         for st in self._stages:
             if st.kind == "identity":
                 continue
             if st.kind == "opaque":
-                cur = _materialize(cur)
-                cur = st.fn(cur)
+                cur = st.fn(materialize(cur))
                 if cur is None or cur.num_rows == 0:
                     return None
                 continue
-            proj = st.proj
-            if proj.predicate is not None:
-                mask = pc.fill_null(proj.predicate.eval(cur), False)
-                kept = pc.sum(mask).as_py() or 0
-                if kept == 0:
-                    return None
-                if kept < cur.num_rows:
-                    cur = _LazyFilteredBatch(cur, mask, kept)
-            cur = _ProjectedView(proj, cur)
-        out = _materialize(cur)
+            rows = st.proj.filtered(cur)
+            if rows is None:
+                return None
+            if rows is not cur:
+                views.append(rows)
+            cur = _ProjectedView(st.proj, rows)
+        out = materialize(cur)
         return out if out.num_rows else None
 
     def _pack_leaves(self, batch: pa.RecordBatch, prog: _SegmentProgram):

@@ -13,6 +13,22 @@ class Expr:
     pass
 
 
+def expr_children(e: Expr):
+    """Immediate child expressions of an AST node, discovered generically
+    through its dataclass fields (lists/tuples flattened) so walkers never
+    miss a position — CASE branches, IN lists, BETWEEN bounds included."""
+
+    def flatten(v):
+        if isinstance(v, Expr):
+            yield v
+        elif isinstance(v, (list, tuple)):
+            for item in v:
+                yield from flatten(item)
+
+    for f in dataclasses.fields(e):
+        yield from flatten(getattr(e, f.name))
+
+
 @dataclasses.dataclass
 class Column(Expr):
     name: str

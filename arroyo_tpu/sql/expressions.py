@@ -12,7 +12,15 @@ from __future__ import annotations
 import dataclasses
 import json
 import operator as _op
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import (
+    Callable,
+    Dict,
+    FrozenSet,
+    List,
+    NamedTuple,
+    Optional,
+    Tuple,
+)
 
 import numpy as np
 import pyarrow as pa
@@ -33,7 +41,9 @@ from .ast import (
     Literal,
     Star,
     UnaryOp,
+    expr_children,
 )
+from ..obs import timeline
 from .lexer import SqlError
 from .types import common_type, sql_type_to_arrow
 
@@ -113,6 +123,17 @@ class BoundExpr:
     # keeps the expression host-only (it can still feed a segment as a
     # host-evaluated input leaf when its dtype is numeric)
     jax: Optional["JaxExpr"] = None
+    # the parsed expression and the scope `bind` bound it against; None
+    # for a closure built by hand
+    source: Optional[Tuple[Expr, "Scope"]] = None
+
+    @property
+    def reads(self) -> Optional[FrozenSet[Tuple[int, Tuple[str, ...]]]]:
+        """What the expression reads of its relation: (column index, path
+        of child names below it) pairs, () = the column as a value. A
+        walk of the parsed expression, because the bound one is a
+        closure; None for one built by hand, which does not say."""
+        return None if self.source is None else column_reads(*self.source)
 
     def eval(self, batch: pa.RecordBatch) -> pa.Array:
         out = self.fn(batch)
@@ -279,7 +300,55 @@ def bind(expr: Expr, scope: Scope) -> BoundExpr:
         be.jax = JaxExpr(
             lambda env, _k=id(be): env.host(_k), hosts=(be,), leaf=True
         )
+    be.source = (expr, scope)
     return be
+
+
+def _column_path(expr: Expr, scope: Scope):
+    """(column index, path of child names below it, the type there)
+    where `expr` names a column of the relation or a struct child below
+    one, resolved as `_bind` resolves it (`a.b`: the qualified column
+    first, then child `b` of a struct column `a`). None for anything
+    computed, and for a name the binder will refuse."""
+    if isinstance(expr, Column):
+        col = scope.try_resolve(expr.name, expr.table)
+        if col is not None:
+            return col.index, (), col.dtype
+        if expr.table is None:
+            return None
+        expr = FieldAccess(Column(expr.table), expr.name)
+    if isinstance(expr, FieldAccess):
+        at = _column_path(expr.base, scope)
+        if at is None or not pa.types.is_struct(at[2]):
+            return None
+        fidx = at[2].get_field_index(expr.field)
+        if fidx < 0:
+            return None
+        return at[0], at[1] + (expr.field,), at[2].field(fidx).type
+    return None
+
+
+def column_reads(expr: Expr, scope: Scope) -> FrozenSet[Tuple[int, tuple]]:
+    """`BoundExpr.reads` of `expr` bound against `scope`."""
+    out = set()
+
+    def walk(e: Expr) -> None:
+        at = _column_path(e, scope)
+        if at is not None:
+            out.add(at[:2])
+            return
+        for c in expr_children(e):
+            walk(c)
+
+    walk(expr)
+    return frozenset(out)
+
+
+def _struct_child(b, idx: int, path: Tuple[str, ...]) -> pa.Array:
+    """Child `path` of struct column `idx`, null where an ancestor is."""
+    if isinstance(b, _LazyFilteredBatch):
+        return b.child(idx, path)
+    return pc.struct_field(b.column(idx), path)
 
 
 def _bind(expr: Expr, scope: Scope) -> BoundExpr:
@@ -302,6 +371,14 @@ def _bind(expr: Expr, scope: Scope) -> BoundExpr:
         return BoundExpr(lambda b: b.column(idx), col.dtype, expr.name,
                          jax=_jx_col(idx, col.dtype))
     if isinstance(expr, FieldAccess):
+        at = _column_path(expr, scope)
+        if at is not None:
+            # a child below a plain column: a predicate's view may filter
+            # the child alone and leave the struct's other children be
+            idx, path, ftype = at
+            return BoundExpr(
+                lambda b: _struct_child(b, idx, path), ftype, expr.field
+            )
         base = bind(expr.base, scope)
         if not pa.types.is_struct(base.dtype):
             raise SqlError(f"{base.name} is not a struct; cannot access "
@@ -309,10 +386,9 @@ def _bind(expr: Expr, scope: Scope) -> BoundExpr:
         fidx = base.dtype.get_field_index(expr.field)
         if fidx < 0:
             raise SqlError(f"struct {base.name} has no field {expr.field}")
-        ftype = base.dtype.field(fidx).type
         return BoundExpr(
             lambda b: pc.struct_field(base.eval(b), expr.field),
-            ftype,
+            base.dtype.field(fidx).type,
             expr.field,
         )
     if isinstance(expr, Literal):
@@ -929,6 +1005,80 @@ def bind_scalar_function(expr: FuncCall, scope: Scope) -> BoundExpr:
 # ---------------------------------------------------------------------------
 
 
+def leaf_fields(t: pa.DataType) -> int:
+    """Leaf arrays under a type: a struct counts its children, deeply."""
+    if t.num_fields and pa.types.is_struct(t):
+        return sum(leaf_fields(f.type) for f in t)
+    return 1
+
+
+class _ViewRule(NamedTuple):
+    """How a projection's filtered view treats its input's columns."""
+
+    # (column, *child path) of every struct child that is filtered alone
+    alone: FrozenSet[tuple]
+    # (column, *child path) the predicate says is not null in a kept row
+    not_null: FrozenSet[tuple]
+    column_leaves: Tuple[int, ...]  # leaf arrays under each input column
+
+
+def _kept_not_null(predicate: Optional[BoundExpr]) -> FrozenSet[tuple]:
+    """The (column, *child path) that a row kept by `predicate` holds a
+    value in: what it asks `IS NOT NULL` of, alone or under ANDs (a null
+    or false conjunct drops the row)."""
+    out = set()
+
+    def walk(e: Expr, scope: Scope) -> None:
+        if isinstance(e, BinaryOp) and e.op.upper() == "AND":
+            walk(e.left, scope)
+            walk(e.right, scope)
+        elif isinstance(e, IsNull) and e.negated:
+            at = _column_path(e.operand, scope)
+            if at is not None:  # and so is every struct above it
+                key = (at[0], *at[1])
+                out.update(key[:k] for k in range(1, len(key) + 1))
+
+    if predicate is not None and predicate.source is not None:
+        walk(*predicate.source)
+    return frozenset(out)
+
+
+def _view_rule(exprs: List[BoundExpr], schema: pa.Schema,
+               predicate: Optional[BoundExpr] = None) -> _ViewRule:
+    """Read from what the bound expressions say they read
+    (`BoundExpr.reads`) and the schema they were bound against: the
+    children read of a struct column are filtered alone where they hold
+    fewer leaves than the struct and nothing reads the struct as a
+    value; where they are all of it the struct is filtered whole, once
+    (a validity merge a child costs more than one struct filter). A
+    child below one that is read itself goes with that one. An
+    expression that does not say reads whole columns through `column()`,
+    which is always right. And from the predicate, which structs a kept
+    row is sure to hold: a child of such a one needs no validity from
+    above."""
+    paths: Dict[int, set] = {}
+    for e in exprs:
+        for idx, path in e.reads or ():
+            paths.setdefault(idx, set()).add(path)
+    column_leaves = tuple(leaf_fields(f.type) for f in schema)
+    alone = set()
+    for idx, ps in paths.items():
+        if () in ps:
+            continue
+        top = [p for p in ps
+               if not any(p[:k] in ps for k in range(1, len(p)))]
+        read = 0
+        for p in top:
+            t = schema.field(idx).type
+            for f in p:
+                t = t.field(f).type
+            read += leaf_fields(t)
+        if read < column_leaves[idx]:
+            alone.update((idx, *p) for p in top)
+    return _ViewRule(frozenset(alone), _kept_not_null(predicate),
+                     column_leaves)
+
+
 class _LazyFilteredBatch:
     """Duck-typed RecordBatch view whose columns are filtered ON DEMAND.
 
@@ -937,23 +1087,77 @@ class _LazyFilteredBatch:
     wide struct columns the projection never reads (nexmark batches
     carry person+auction+bid structs; q5/q1 read only `bid`). This view
     exposes just the surface bound expressions use (column(i)/num_rows/
-    schema) and filters each accessed column once, lazily."""
+    schema, and child(i, path) for a struct child below a column) and
+    filters each accessed column once, lazily.
 
-    __slots__ = ("_batch", "_mask", "_cols", "num_rows", "schema")
+    It goes one level further for the struct children in `rule.alone`:
+    such a child is taken from the unfiltered struct, null where an
+    ancestor is, and filtered alone, so the struct's other children are
+    never copied. Either way gives the same arrays; `leaves_filtered`
+    counts the leaf arrays that went through the filter kernel."""
 
-    def __init__(self, batch: pa.RecordBatch, mask, num_rows: int):
+    __slots__ = ("_batch", "_mask", "_cols", "_flat", "_rule",
+                 "leaves_filtered", "num_rows", "schema")
+
+    def __init__(self, batch: pa.RecordBatch, mask, num_rows: int,
+                 rule: Optional[_ViewRule] = None):
         self._batch = batch
         self._mask = mask
-        self._cols = {}
+        self._cols = {}  # (column, *child path) -> the filtered array
+        self._flat = {}  # (column, *child path) -> (its type, its children)
+        self._rule = rule or _view_rule([], batch.schema)
+        self.leaves_filtered = 0
         self.num_rows = num_rows
         self.schema = batch.schema
 
     def column(self, i: int):
-        c = self._cols.get(i)
+        c = self._cols.get((i,))
         if c is None:
-            c = self._batch.column(i).filter(self._mask)
-            self._cols[i] = c
+            c = self._cols[(i,)] = self._batch.column(i).filter(self._mask)
+            self.leaves_filtered += self._rule.column_leaves[i]
         return c
+
+    def _unfiltered(self, at: tuple, merged: bool = True):
+        """The UNFILTERED array at (column, *child path); `merged`: null
+        where the struct above it or an ancestor is (one `flatten()` a
+        struct and call, whichever of its children are read). Below a
+        struct that every kept row holds (`rule.not_null`) the merge is
+        skipped: the filter drops every row it would mark, and a column
+        without a validity buffer filters three times faster."""
+        if len(at) == 1:
+            return self._batch.column(at[0])
+        if not merged or at[:-1] in self._rule.not_null:
+            return self._unfiltered(at[:-1], merged=False).field(at[-1])
+        flat = self._flat.get(at[:-1])
+        if flat is None:
+            struct = self._unfiltered(at[:-1])
+            flat = self._flat[at[:-1]] = (struct.type, struct.flatten())
+        return flat[1][flat[0].get_field_index(at[-1])]
+
+    def child(self, i: int, path: Tuple[str, ...]):
+        at = (i, *path)
+        c = self._cols.get(at)
+        if c is None:
+            above = self._cols.get(at[:-1])
+            if above is None and at in self._rule.alone:
+                c = self._unfiltered(at).filter(self._mask)
+                self.leaves_filtered += leaf_fields(c.type)
+            else:
+                if above is None:
+                    above = (self.child(i, path[:-1]) if len(path) > 1
+                             else self.column(i))
+                # no nulls above: no validity to merge, so no kernel
+                c = (pc.struct_field(above, at[-1]) if above.null_count
+                     else above.field(at[-1]))
+            self._cols[at] = c
+        return c
+
+    def book(self) -> None:
+        """The count that says the rule engaged, once the columns are
+        read: a `note` with no duration, `n` of the input's `padded` leaf
+        arrays went through the filter kernel."""
+        timeline.note("project.filter", 0.0, n=self.leaves_filtered,
+                      padded=sum(self._rule.column_leaves))
 
     def __getattr__(self, name):
         # duck-typing guard: a BoundExpr reaching for any other
@@ -962,8 +1166,8 @@ class _LazyFilteredBatch:
         # all-pass predicates never build this view)
         raise AttributeError(
             f"_LazyFilteredBatch (the lazy predicate-filtered RecordBatch "
-            f"view) exposes only column()/num_rows/schema, not {name!r}; "
-            f"teach the view that attribute or filter eagerly in "
+            f"view) exposes only column()/child()/num_rows/schema, not "
+            f"{name!r}; teach the view that attribute or filter eagerly in "
             f"CompiledProjection"
         )
 
@@ -977,21 +1181,39 @@ class CompiledProjection:
         self.exprs = exprs
         self.out_schema = out_schema
         self.predicate = predicate
+        self._rule: Optional[_ViewRule] = None  # needs the input's schema
+
+    def filtered(self, batch):
+        """`batch` under the predicate: None where no row passes, `batch`
+        itself where every row does (or nothing is asked), else the lazy
+        view, for the caller to `book()` once its columns are read."""
+        if self.predicate is None:
+            return batch
+        mask = self.predicate.eval(batch)
+        if mask.null_count:
+            mask = pc.fill_null(mask, False)
+        kept = mask.true_count
+        if kept == 0:
+            return None
+        if kept == batch.num_rows:
+            return batch
+        if self._rule is None:
+            self._rule = _view_rule(self.exprs, batch.schema,
+                                    self.predicate)
+        return _LazyFilteredBatch(batch, mask, kept, self._rule)
 
     def __call__(self, batch: pa.RecordBatch) -> Optional[pa.RecordBatch]:
-        if self.predicate is not None:
-            mask = pc.fill_null(self.predicate.eval(batch), False)
-            kept = pc.sum(mask).as_py() or 0
-            if kept == 0:
-                return None
-            if kept < batch.num_rows:
-                batch = _LazyFilteredBatch(batch, mask, kept)
+        rows = self.filtered(batch)
+        if rows is None:
+            return None
         arrays = []
         for e, f in zip(self.exprs, self.out_schema):
-            arr = e.eval(batch)
+            arr = e.eval(rows)
             if not arr.type.equals(f.type):
                 arr = _cast(arr, f.type)
             arrays.append(arr)
+        if rows is not batch:
+            rows.book()
         return pa.RecordBatch.from_arrays(arrays, schema=self.out_schema)
 
     @staticmethod

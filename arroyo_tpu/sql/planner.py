@@ -46,6 +46,7 @@ from .ast import (
     SubqueryRef,
     TableRef,
     Unnest,
+    expr_children,
 )
 from .expressions import BoundExpr, CompiledProjection, Scope, bind
 from .lexer import SqlError
@@ -2001,22 +2002,6 @@ def _proto_descriptor(t) -> Optional[dict]:
         raise SqlError(f"cannot read proto.descriptor_file {path!r}: {e}")
 
 
-def _expr_children(e: Expr):
-    """Immediate child expressions of an AST node, discovered generically
-    through its dataclass fields (lists/tuples flattened) so walkers never
-    miss a position — CASE branches, IN lists, BETWEEN bounds included."""
-
-    def flatten(v):
-        if isinstance(v, Expr):
-            yield v
-        elif isinstance(v, (list, tuple)):
-            for item in v:
-                yield from flatten(item)
-
-    for f in dataclasses.fields(e):
-        yield from flatten(getattr(e, f.name))
-
-
 def _find_aggregates(e: Expr) -> List[FuncCall]:
     out: List[FuncCall] = []
 
@@ -2028,7 +2013,7 @@ def _find_aggregates(e: Expr) -> List[FuncCall]:
         ):
             out.append(x)
             return  # don't descend into agg args
-        for c in _expr_children(x):
+        for c in expr_children(x):
             walk(c)
 
     walk(e)
@@ -2375,13 +2360,13 @@ def _find_field(schema: StreamSchema, name: str) -> Optional[int]:
 def _contains_unnest(e: Expr) -> bool:
     if isinstance(e, FuncCall) and e.name == "unnest":
         return True
-    return any(_contains_unnest(c) for c in _expr_children(e))
+    return any(_contains_unnest(c) for c in expr_children(e))
 
 
 def _expr_references(e: Expr, col_name: str) -> bool:
     if isinstance(e, Column) and e.name.lower() == col_name.lower():
         return True
-    return any(_expr_references(c, col_name) for c in _expr_children(e))
+    return any(_expr_references(c, col_name) for c in expr_children(e))
 
 
 def _find_item_by_alias(items: List[SelectItem], name: str):
@@ -2615,4 +2600,4 @@ def _plan_script(
 def _column_names(e: Expr) -> List[str]:
     if isinstance(e, Column):
         return [e.name] + ([e.table] if e.table else [])
-    return [n for c in _expr_children(e) for n in _column_names(c)]
+    return [n for c in expr_children(e) for n in _column_names(c)]

@@ -58,9 +58,9 @@ from .ast import (
     Star,
     SubqueryRef,
     TableRef,
+    expr_children,
 )
-from .expressions import BoundExpr, CompiledProjection
-from .planner import _expr_children
+from .expressions import BoundExpr, CompiledProjection, leaf_fields
 
 # table name (lowercased) -> [(reading SELECT, the qualifier its scope
 # gives the table's columns)]
@@ -158,7 +158,7 @@ def kept_schema(
         elif isinstance(e, IsNull) and isinstance(e.operand, Column):
             column(e.operand, qual, validity=True)
         else:
-            for c in _expr_children(e):
+            for c in expr_children(e):
                 walk(c, qual)
             if isinstance(e, FuncCall) and e.over is not None:
                 for p in e.over.partition_by:
@@ -195,13 +195,7 @@ def kept_schema(
 
 def leaf_count(schema: pa.Schema) -> int:
     """Leaf fields of a schema: a struct counts its children, deeply."""
-
-    def leaves(t: pa.DataType) -> int:
-        if pa.types.is_struct(t):
-            return sum(leaves(f.type) for f in t)
-        return 1
-
-    return sum(leaves(f.type) for f in schema)
+    return sum(leaf_fields(f.type) for f in schema)
 
 
 def prune_op(schema: StreamSchema, kept: StreamSchema) -> ChainedOp:

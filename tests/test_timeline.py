@@ -934,6 +934,65 @@ def test_an_unfused_value_operator_books_project_with_the_rows_it_took_in():
     assert [e["task"] for e in timeline.snapshot("proj")] == ["1-0", "1-0"]
 
 
+def test_a_projection_that_built_a_filtered_view_books_what_it_filtered():
+    """`project.filter` (ISSUE 43): a count with no duration inside
+    `project`, once per call that built the predicate's view: `n` of the
+    input's `padded` leaf arrays went through the filter kernel. q5's
+    program over a raw NEXmark row reads `bid.auction` and `_timestamp`:
+    2 of 26, not `bid`'s seven and one. A program that reads every child
+    filters the struct whole, once; a predicate that keeps every row
+    builds no view and books nothing."""
+    import asyncio
+
+    import pyarrow as pa
+    from test_lazy_filtered_batch import RAW_LEAVES, _nexmark, _programs
+
+    from arroyo_tpu.operators.projection import BatchMapOperator
+
+    class Collector:
+        async def collect(self, batch):
+            pass
+
+    raw = _nexmark()
+    bid = raw.column(2)
+    bid3 = pa.StructArray.from_arrays(
+        [bid.field(n) for n in ("auction", "price", "bidder")],
+        names=["auction", "price", "bidder"], mask=bid.is_null())
+    narrowed = pa.RecordBatch.from_arrays(
+        [bid3, raw.column(3)], names=["bid", "_timestamp"])
+
+    def run(job, batch, texts, predicate):
+        op = BatchMapOperator(_programs(batch, texts, predicate)[0],
+                              "agg_input")
+
+        async def go():
+            with timeline.phase("process", job=job, task="1-0",
+                                n=batch.num_rows, annotate=False):
+                await op.process_batch(batch, None, Collector())
+
+        asyncio.run(go())
+        t = timeline.phase_totals(job)
+        assert t["project"]["count"] == 1
+        return t.get("project.filter")
+
+    one = run("pf-q5", raw, ["bid.auction", "_timestamp"], "bid IS NOT NULL")
+    assert (one["count"], one["n"], one["padded"]) == (1, 2, RAW_LEAVES)
+    assert one["total_s"] == 0
+    every = run("pf-q7", narrowed,
+                ["bid.auction", "bid.price", "bid.bidder", "_timestamp"],
+                "bid IS NOT NULL")
+    assert (every["n"], every["padded"]) == (3 + 1, 4)
+    some = run("pf-q7max", narrowed, ["bid.price", "_timestamp"],
+               "bid IS NOT NULL")
+    assert (some["n"], some["padded"]) == (2, 4)
+    assert run("pf-all", raw, ["bid.auction", "_timestamp"],
+               "_timestamp >= 0") is None
+    assert [e["phase"] for e in timeline.snapshot("pf-q5")] == [
+        "project.filter", "project", "process"]
+    assert "project.filter" not in (timeline.ENCLOSING + timeline.WAITS
+                                    + timeline.DEVICE_WAITS)
+
+
 def test_a_breach_bundle_written_on_the_loop_is_a_leaf_of_the_ledger(tmp_path):
     """An SLO alert that fires serialises the flight recorder and the
     phase ring where it stands, on the controller's loop: `watch.bundle`,

@@ -37,6 +37,7 @@ from ..schema import StreamSchema, TIMESTAMP_FIELD
 from ..types import WatermarkKind
 from ..utils.logging import get_logger
 from .base import Operator
+from .session_table import SessionTable
 
 logger = get_logger("windows")
 
@@ -90,10 +91,6 @@ class WindowOperatorBase(Operator):
             # keep the mesh exchange). Real chip meshes keep salting —
             # there the spread buys S x scatter bandwidth.
             mesh_n = 0
-            if self._offmesh_backend is not None:
-                # session windows: imperative host bookkeeping dominates;
-                # off the mesh they keep their numpy accumulator
-                self.backend = self._offmesh_backend
         if mesh_n >= 2:
             from ..parallel import ShardedAccumulator, key_mesh
 
@@ -132,16 +129,12 @@ class WindowOperatorBase(Operator):
         self._dirty_base = 0
 
     # whether rows reach their slots through the table's assign() (tumbling,
-    # sliding, updating aggregates); session windows allocate slots
-    # imperatively and keep their keys themselves
+    # sliding, updating aggregates); session windows allocate slots in
+    # blocks and keep their keys themselves (operators/session_table.py)
     _uses_assign = True
     # operators whose state protocol is slot-based end to end can run on
-    # the mesh-sharded accumulator (tumbling, sliding; session bookkeeping
-    # allocates slots imperatively and stays host-side)
+    # the mesh-sharded accumulator (tumbling, sliding, session)
     _mesh_ok = False
-    # backend to fall back to when a salted stage is tiered OFF the mesh
-    # (None = keep the configured backend; sessions force numpy)
-    _offmesh_backend: Optional[str] = None
 
     def _mesh_devices(self, config: dict) -> int:
         if not self._mesh_ok or self.backend == "numpy":
@@ -458,11 +451,11 @@ class WindowOperatorBase(Operator):
         serve_stage: bool = True,
     ) -> pa.RecordBatch:
         """Build an output batch for one window [start, end). `key_arrays`
-        (one int64 array per key column, raw directory bit-patterns) is the
-        vectorized fast path used by the native-directory emit — no python
-        tuple per key. start/end/ts_value may be scalars (one window) or
+        (one int64 array per key word, raw directory bit-patterns; or the
+        session table's stored columns) is the vectorized path of the
+        native-directory emit and of sessions — no python tuple per key. start/end/ts_value may be scalars (one window) or
         per-row arrays (batched session emission)."""
-        n = len(key_arrays[0]) if key_arrays is not None else len(keys)
+        n = len(key_arrays[0]) if key_arrays else len(keys)
 
         def const_or_arr(v):
             if isinstance(v, np.ndarray):
@@ -497,7 +490,7 @@ class WindowOperatorBase(Operator):
             elif f.name in (self._key_names or []):
                 ki = self._key_names.index(f.name)
                 arrays.append(
-                    self.codec.arrow_from_words(ki, key_arrays)
+                    self.codec.arrow_from_arrays(ki, key_arrays)
                     if key_arrays is not None
                     else self.codec.arrow_from_keys(ki, keys)
                 )
@@ -1013,72 +1006,61 @@ def _batch_group_codes(key_cols: List[np.ndarray], n: int) -> np.ndarray:
 
 class SessionWindowOperator(WindowOperatorBase):
     """Per-key gap-merged sessions
-    (reference session_aggregating_window.rs:51-942). Session bookkeeping is
-    inherently scalar and stays host-side; the accumulator arithmetic runs
-    on the numpy backend single-device (a lone jax device wins nothing over
-    the bookkeeping) but shards across the device mesh in mesh mode —
-    slots are allocated round-robin across shards and every accumulator
-    update/gather rides the sharded all_to_all path like tumbling/sliding
-    (reference treats all window types uniformly)."""
+    (reference session_aggregating_window.rs:51-942). The open sessions
+    are rows of arrays (`operators/session_table.py`): a batch is cut into
+    segments (one key, rows less than the gap apart), the segments' keys
+    are looked up together, hits extend their session and misses open one
+    from a single `alloc_slots` call; only a key with several open sessions
+    (rows far out of order, a row that bridges two) is placed one segment
+    at a time. A watermark finds what it closes with one array expression
+    over the open rows. The accumulator takes the tier every window
+    operator takes (`make_accumulator`; sharded over the mesh in mesh
+    mode): a scatter a batch, and a gather and a reset of the closed slots
+    on every watermark that closes any (reference treats all window types
+    uniformly)."""
 
     _mesh_ok = True
     _uses_assign = False
-    _offmesh_backend = "numpy"
 
     def __init__(self, config: dict):
-        config = dict(config)
-        if self._cfg_mesh_devices(config) < 2:
-            config["backend"] = "numpy"
         super().__init__(config, "session_window")
         self.gap = int(config["gap_nanos"])
         assert self.gap > 0
-        # key -> list of [start, last_ts, slot], sorted by start
-        self.sessions: Dict[tuple, List[List]] = {}
-        # incremental checkpointing (ROADMAP item 4): keys whose sessions
-        # or accumulators changed since the last epoch, and keys whose
-        # last session closed (tombstoned in the sess table) — capture
-        # cost is O(touched sessions), not O(live sessions)
-        self._ckpt_dirty: set = set()
-        self._ckpt_dead: set = set()
-        # serve partial staging (ISSUE 20) follows the same delta
-        # discipline: only keys whose sessions changed since the last
-        # capture are re-staged as partials (unchanged partials persist
-        # in the cumulative view/mirror), so the capture span stays
-        # O(touched sessions) under growing live-session counts
-        self._serve_dirty: set = set()
-        self._serve_dead: set = set()
-        self._serve_partial_keys: set = set()
+        # made with the key types, which arrive with the first schema
+        self._table: Optional[SessionTable] = None
         self._next_shard = 0
-        # block-refilled slot pool: one vectorized alloc_slots call per
-        # _POOL_BLOCK sessions instead of one Python directory call per
-        # session (the mesh facade deals the block round-robin across
-        # shards, preserving placement balance)
-        self._slot_pool: List[int] = []
 
-    _POOL_BLOCK = 64
-
-    def _alloc_slot(self) -> int:
-        if not self._slot_pool:
-            self._slot_pool = self.dir.alloc_slots(
-                self._POOL_BLOCK, self._next_shard
-            ).tolist()
-            self._next_shard += self._POOL_BLOCK
-        return self._slot_pool.pop()
-
-    def _free_slot(self, slot: int):
-        self.dir.free_slot(int(slot))
-
-    def _return_pool(self):
-        """Return unused pooled slots to the directory free lists. Left
-        in the pool across a checkpoint they are allocated-but-unused:
-        required_capacity (and the accumulator grow threshold) carries
-        up to _POOL_BLOCK-1 idle slots, and a restore from that
-        checkpoint strands them entirely (ADVICE round 5)."""
-        if self._slot_pool:
-            self.dir.free_slots(
-                np.asarray(self._slot_pool, dtype=np.int64)
+    def _capture_key_meta(self, ctx):
+        super()._capture_key_meta(ctx)
+        if self._table is None:
+            self._table = SessionTable(
+                self.gap,
+                [self.codec.int_like(i) for i in range(len(self.key_cols))],
             )
-            self._slot_pool = []
+
+    @property
+    def sessions(self) -> Dict[tuple, List[List[int]]]:
+        """{key: [[start, last, slot], ...]} of the open sessions, by
+        start: a view for tests and debugging, built on demand."""
+        t = self._table
+        if t is None or not t.n_live:
+            return {}
+        rows = t.live_rows()
+        cols = self.codec.value_lists([k[rows] for k in t.keys])
+        out: Dict[tuple, List[List[int]]] = {}
+        for i, r in enumerate(rows.tolist()):
+            out.setdefault(tuple(c[i] for c in cols), []).append(
+                [int(t.start[r]), int(t.last[r]), int(t.slot[r])])
+        for v in out.values():
+            v.sort()
+        return out
+
+    def _alloc(self, n: int) -> np.ndarray:
+        """n accumulator slots from one directory call (the mesh facade
+        deals them round-robin over the shards from `_next_shard`)."""
+        slots = self.dir.alloc_slots(n, self._next_shard)
+        self._next_shard += n
+        return slots
 
     def tables(self):
         from ..state.table_config import global_table
@@ -1094,8 +1076,7 @@ class SessionWindowOperator(WindowOperatorBase):
             # unkeyed (window-global) sessions keep the legacy
             # per-subtask snapshot — there is no key to partition by
             for snap in _snaps_for_me(table, ctx, False):
-                self._restore_sessions(snap, ctx)
-            self._serve_dirty.update(self.sessions)
+                self._restore_snapshot(snap, ctx)
             return
         legacy, per_key = [], []
         for k, v in table.items():
@@ -1104,27 +1085,26 @@ class SessionWindowOperator(WindowOperatorBase):
             elif isinstance(v, dict) and "sessions" in v:
                 legacy.append(v)
         for snap in legacy:
-            self._restore_sessions(snap, ctx)
+            self._restore_snapshot(snap, ctx)
         kept = self._restore_per_key(per_key, ctx)
         # each subtask's chain carries ONLY its own keys from here on:
         # out-of-range entries (and replayed legacy snaps) are owned and
         # re-persisted by their own subtasks this same epoch, so they are
         # pruned without tombstones — which keeps the cross-subtask union
-        # free of stale replicated copies and lets rebase drop tombstones
+        # free of stale replicated copies and lets rebase drop tombstones.
+        # Everything restored is dirty, so it re-persists at the first
+        # post-restore epoch (covers legacy-format upgrades and the
+        # pruned replicas)
         table.retain(lambda k: isinstance(k, tuple) and k and k[0] == "sk"
                      and k in kept)
-        # everything restored re-persists at the first post-restore epoch
-        # (covers legacy-format upgrades and the pruned replicas)
-        self._ckpt_dirty.update(self.sessions)
-        self._serve_dirty.update(self.sessions)
 
     async def handle_checkpoint(self, barrier, ctx, collector):
-        self._return_pool()
+        t = self._table
+        if t is None:
+            return
         if self._serve_view is None:
-            # no attached view consumes the serve delta sets; keep them
-            # bounded on unviewed jobs
-            self._serve_dirty.clear()
-            self._serve_dead.clear()
+            # no attached view consumes the serve flags
+            t.restage[:t.top] = False
         if ctx.table_manager is None:
             return
         table = await ctx.table("sess")
@@ -1133,347 +1113,274 @@ class SessionWindowOperator(WindowOperatorBase):
             snap["subtask"] = ctx.task_info.task_index
             table.put(ctx.task_info.task_index, snap)
             return
-        for key in self._ckpt_dead:
-            table.delete(self._sess_key(key))
-        self._ckpt_dead.clear()
-        dirty = [k for k in self._ckpt_dirty if k in self.sessions]
-        self._ckpt_dirty.clear()
-        if dirty:
-            # one batched accumulator gather for every dirty session
-            slots = [s[2] for k in dirty for s in self.sessions[k]]
-            values = self.acc.snapshot(
-                np.asarray(slots, dtype=np.int64)
-            ) if slots else []
-            idx = 0
-            for k in dirty:
-                sess = self.sessions[k]
-                n = len(sess)
-                table.put(self._sess_key(k), {
-                    "s": [[int(x) for x in s[:2]] + [int(s[2])]
-                          for s in sess],
-                    "v": [_tolist(col[idx:idx + n]) for col in values],
-                })
-                idx += n
-
-    def _sess_key(self, key: tuple) -> tuple:
-        """Portable per-session-key table key ("sk", *values) — msgpack
-        round-trips it as a list, GlobalTable re-tuples on load."""
-        return ("sk", *self.codec.values(key))
+        # a key whose last session closed and that a barrier had written:
+        # its entry goes (one that opened and closed between two barriers
+        # was never written and leaves nothing)
+        for cols in t.dead_stored:
+            for key in zip(*self.codec.value_lists(cols)):
+                table.delete(("sk", *key))
+        t.dead_stored.clear()
+        # every dirty key's entry anew, the values of all of them from ONE
+        # gather; a key outside the sorted index writes all its sessions
+        # when any of them changed
+        alone = np.nonzero(t.dirty[:t.top] & ~t.shared[:t.top])[0]
+        groups = [g for g in t.shared_keys() if t.dirty[g].any()]
+        rows = np.concatenate(
+            [alone] + [np.asarray(g, dtype=np.int64) for g in groups])
+        if not len(rows):
+            return
+        slots = t.slot[rows]
+        values = [_tolist(c) for c in self.acc.snapshot(slots)]
+        spans = np.stack([t.start[rows], t.last[rows], slots], axis=1).tolist()
+        keys = list(zip(*self.codec.value_lists([k[rows] for k in t.keys])))
+        for i in range(len(alone)):
+            table.put(("sk", *keys[i]), {
+                "s": [spans[i]], "v": [[col[i]] for col in values]})
+        i = len(alone)
+        for g in groups:
+            j = i + len(g)
+            table.put(("sk", *keys[i]), {
+                "s": spans[i:j], "v": [col[i:j] for col in values]})
+            i = j
+        t.stored[rows] = True
+        t.dirty[rows] = False
+        return len(rows)
 
     def _restore_per_key(self, items: list, ctx) -> set:
         """Replay per-key entries owned by this subtask; returns the set
         of table keys kept (for the retain() prune)."""
         if not items:
             return set()
-        key_rows = [list(k[1:]) for k, _v in items]
-        mask = self._range_mask(key_rows, ctx)
+        mask = self._range_mask([list(k[1:]) for k, _v in items], ctx)
         kept = set()
-        sessions, slots, cols = [], [], None
-        idx = 0
+        entries, cols = [], None
+        at = 0
         for i, (k, v) in enumerate(items):
             if mask is not None and not mask[i]:
                 continue
             kept.add(k)
-            sess_list = []
-            for s in v["s"]:
-                sess_list.append([s[0], s[1], idx])
-                slots.append(idx)
-                idx += 1
-            sessions.append([list(k[1:]), sess_list])
+            entries.append(
+                (list(k[1:]), [[s[0], s[1], at + j]
+                               for j, s in enumerate(v["s"])]))
+            at += len(v["s"])
             if cols is None:
                 cols = [[] for _ in v["v"]]
             for c, col in zip(cols, v["v"]):
                 c.extend(col)
-        if sessions:
-            self._restore_sessions(
-                {"sessions": sessions, "slots": slots, "values": cols or []},
-                ctx,
-            )
+        self._restore(entries, cols or [], stored=True)
         return kept
 
     def _snapshot_sessions(self) -> dict:
-        slots = [s[2] for v in self.sessions.values() for s in v]
-        slots_arr = np.asarray(slots, dtype=np.int64)
-        values = self.acc.snapshot(slots_arr) if slots else []
+        """Every open session in the legacy form (`_restore_snapshot`)."""
+        sessions = self.sessions
+        slots = [s[2] for v in sessions.values() for s in v]
+        values = self.acc.snapshot(
+            np.asarray(slots, dtype=np.int64)) if slots else []
         return {
-            "sessions": [
-                [self.codec.values(key), [[int(x) for x in s] for s in v]]
-                for key, v in self.sessions.items()
-            ],
-            "slots": [int(s) for s in slots],
-            "values": [v.tolist() for v in values],
+            "sessions": [[list(k), v] for k, v in sessions.items()],
+            "slots": slots,
+            "values": [_tolist(v) for v in values],
         }
 
-    def _restore_sessions(self, snap: dict, ctx=None):
-        """Replay one pre-restart subtask's sessions, remapping slots (old
-        slot ids collide across subtasks) and skipping keys outside this
-        subtask's range."""
+    def _restore_snapshot(self, snap: dict, ctx=None):
+        """Replay one pre-restart subtask's snapshot (the legacy form:
+        {sessions: [[key values, [[start, last, old slot], ...]], ...],
+        slots, values}), skipping keys outside this subtask's range."""
+        entries = snap["sessions"]
+        key_rows = [key_vals for key_vals, _ in entries]
+        mask = self._range_mask(key_rows, ctx) if key_rows else None
         slot_pos = {s: i for i, s in enumerate(snap["slots"])}
+        self._restore(
+            [(k, [[s[0], s[1], slot_pos[s[2]]] for s in sess])
+             for i, (k, sess) in enumerate(entries)
+             if mask is None or mask[i]],
+            snap["values"], stored=False)
+
+    def _restore(self, entries: list, values: list, stored: bool):
+        """Open the sessions of `entries` ([(key values, [[start, last,
+        position in `values`], ...]), ...]) on fresh slots (old slot ids
+        collide across subtasks), with one accumulator restore."""
+        key_rows, spans = [], []
+        for key_vals, sess in entries:
+            for s in sess:
+                key_rows.append(key_vals)
+                spans.append(s)
+        if not spans:
+            return
+        t = self._table
+        start, last, pos = np.asarray(spans, dtype=np.int64).T
+        key_cols = self.codec.value_columns_from_values(key_rows)
+        slots = self._alloc(len(spans))
+        self._ensure_capacity()
         # trailing host-state columns are ragged per-slot lists (same
         # object-array discipline as _restore_rows)
         n_phys = len(self.acc.phys)
-        values = []
-        for j, v in enumerate(snap["values"]):
+        cols = []
+        for j, v in enumerate(values):
             if j < n_phys:
-                values.append(np.asarray(v))
+                cols.append(np.asarray(v)[pos])
             else:
                 arr = np.empty(len(v), dtype=object)
                 arr[:] = v
-                values.append(arr)
-        key_rows = [key_vals for key_vals, _ in snap["sessions"]]
-        mask = self._range_mask(key_rows, ctx) if key_rows else None
-        new_slots: List[int] = []
-        positions: List[int] = []
-        for si, (key_vals, sess_list) in enumerate(snap["sessions"]):
-            if mask is not None and not mask[si]:
-                continue
-            key = self.codec.key(key_vals)
-            cur = self.sessions.setdefault(key, [])
-            for s in sess_list:
-                new_slot = self._alloc_slot()
-                new_slots.append(new_slot)
-                positions.append(slot_pos[s[2]])
-                cur.append([s[0], s[1], new_slot])
-            cur.sort(key=lambda x: x[0])
-        if new_slots:
-            # one batched restore (a single scatter dispatch in mesh mode)
-            self._ensure_capacity()
-            pos = np.asarray(positions, dtype=np.int64)
-            self.acc.restore(
-                np.asarray(new_slots, dtype=np.int64),
-                [v[pos] for v in values],
-            )
+                cols.append(arr[pos])
+        self.acc.restore(slots, cols)
+        t.load(t.codes_of(key_cols, len(spans)), key_cols, start, last,
+               slots, stored)
 
     async def process_batch(self, batch, ctx, collector, input_index: int = 0):
         self._capture_key_meta(ctx)
-        ts = ctx.in_schemas[0].timestamps(batch)
-        wm = ctx.watermarks.current_nanos()
-        keys = self.codec.columns(batch, self.key_cols)
-        cols = self._agg_input_cols(batch)
-        n = len(ts)
-        row_slots = np.full(n, -1, dtype=np.int64)
-        live = (
-            np.ones(n, dtype=bool) if wm is None
-            else ts + self.gap > wm  # else fully late: already emitted
-        )
-        li = np.nonzero(live)[0]
-        if len(li):
-            # vectorized segmentation: group rows by key, split each
-            # key's time-sorted rows where the gap is exceeded, then do
-            # the scalar bookkeeping ONCE PER SEGMENT (high-rate session
-            # streams have many rows per segment; the old per-row
-            # _place loop was the session operator's host ceiling)
-            lts = ts[li]
-            lk = [np.asarray(k)[li] for k in keys]
-            inverse = _batch_group_codes(lk, len(li))
-            order = np.lexsort((lts, inverse))
-            so_key = inverse[order]
-            so_ts = lts[order]
-            new_seg = np.ones(len(order), dtype=bool)
-            if len(order) > 1:
-                new_seg[1:] = (so_key[1:] != so_key[:-1]) | (
-                    so_ts[1:] - so_ts[:-1] >= self.gap
-                )
-            seg_id = np.cumsum(new_seg) - 1
-            starts = np.nonzero(new_seg)[0]
-            ends = np.r_[starts[1:], len(order)] - 1
-            seg_slots = np.empty(len(starts), dtype=np.int64)
-            for g in range(len(starts)):
-                first = int(order[starts[g]])
-                key = tuple(_to_py(c[first]) for c in lk)
-                seg_slots[g] = self._place_segment(
-                    key, int(so_ts[starts[g]]), int(so_ts[ends[g]])
-                )
-                self._ckpt_dirty.add(key)
-                self._ckpt_dead.discard(key)
-                self._serve_dirty.add(key)
-                self._serve_dead.discard(key)
-            row_slots[li[order]] = seg_slots[seg_id]
-        keep = row_slots >= 0
-        if keep.any():
+        t = self._table
+        with timeline.phase("sess.segment") as ph:
+            ts = ctx.in_schemas[0].timestamps(batch)
+            ph.padded = n = len(ts)
+            wm = ctx.watermarks.current_nanos()
+            keys = self.codec.value_columns(batch, self.key_cols)
+            live = None
+            if wm is not None:
+                # a row whose session a watermark has closed is fully late
+                live = ts + self.gap > wm
+                if live.all():
+                    live = None
+                else:
+                    ts = ts[live]
+                    keys = [k[live] for k in keys]
+                    n = len(ts)
+            ph.n = n
+            if not n:
+                return
+            # one segment per run of a key's rows less than the gap apart
+            # (a distance of exactly the gap opens a new session)
+            code = t.codes_of(keys, n)
+            order = np.lexsort((ts, code))
+            so_code, so_ts = code[order], ts[order]
+            cut = np.ones(n, dtype=bool)
+            cut[1:] = (so_code[1:] != so_code[:-1]) | (
+                so_ts[1:] - so_ts[:-1] >= self.gap)
+            if not t.exact:
+                # two keys under one hash are two segments
+                for k in keys:
+                    so_k = k[order]
+                    cut[1:] |= np.asarray(so_k[1:] != so_k[:-1], dtype=bool)
+            first = np.nonzero(cut)[0]
+            seg_of = np.cumsum(cut) - 1
+            seg_hi = so_ts[np.r_[first[1:], n] - 1]
+            at = order[first]
+        with timeline.phase("sess.place", n=len(first)) as ph:
+            rows = t.place(so_code[first], [k[at] for k in keys],
+                           so_ts[first], seg_hi, self._alloc,
+                           self._fold_slots)
+            ph.padded = t.scalar
+            # counts, no duration: sessions opened, sessions folded away
+            timeline.note("sess.open", 0.0, n=t.opened)
+            if t.merged:
+                timeline.note("sess.merge", 0.0, n=t.merged)
             self._ensure_capacity()
-            self.acc.update(
-                row_slots[keep], {c: v[keep] for c, v in cols.items()}
-            )
+            slots = np.empty(n, dtype=np.int64)
+            slots[order] = t.slot[rows][seg_of]
+        with timeline.phase("win.cols", n=n):
+            cols = self._agg_input_cols(batch)
+            if live is not None:
+                cols = {c: v[live] for c, v in cols.items()}
+        self.acc.update(slots, cols)
 
-    def _place_segment(self, key: tuple, lo: int, hi: int) -> int:
-        """Find/extend/merge the session covering [lo, hi] (all rows of
-        one batch segment share it); returns its slot. Interval union
-        with gap is order-independent, so segment-level placement yields
-        the same final sessions as the old per-row placement."""
-        sess = self.sessions.setdefault(key, [])
-        hit = None
-        for s in sess:
-            if s[0] - self.gap < hi and lo < s[1] + self.gap:
-                hit = s
-                break
-        if hit is None:
-            slot = self._alloc_slot()
-            self._ensure_capacity()
-            sess.append([lo, hi, slot])
-            sess.sort(key=lambda s: s[0])
-            return slot
-        hit[0] = min(hit[0], lo)
-        hit[1] = max(hit[1], hi)
-        # the extension may bridge adjacent sessions: merge while
-        # overlapping. When the HIT side is the one folded away (it
-        # bridged backwards into an earlier session), the survivor
-        # becomes the hit — returning the folded slot would scatter the
-        # segment's rows into a freed (reusable) slot.
-        sess.sort(key=lambda s: s[0])
-        i = 0
-        while i < len(sess) - 1:
-            a, b = sess[i], sess[i + 1]
-            if b[0] < a[1] + self.gap:
-                self._merge_slots(a, b)
-                sess.pop(i + 1)
-                if b is hit:
-                    hit = a
-            else:
-                i += 1
-        return hit[2]
+    def _emitted_keys(self, rows: np.ndarray) -> tuple:
+        """`_build_output`'s (keys, key_arrays) of the sessions in `rows`:
+        the stored key columns, or one empty key a row where sessions are
+        window-global."""
+        if not self.key_cols:
+            return [()] * len(rows), None
+        return [], [k[rows] for k in self._table.keys]
 
-    def _merge_slots(self, a: List, b: List):
-        """Fold session b's accumulator into a's; free b's slot."""
-        self.acc.merge_slot_into(a[2], b[2])
-        ga = self.acc.gather(np.asarray([a[2], b[2]], dtype=np.int64))
+    def _fold_slots(self, dst: int, src: int):
+        """Fold slot src's accumulator into dst's; free src (a row that
+        bridges two open sessions: the table's scalar path)."""
+        self._ensure_capacity()
+        self.acc.merge_slot_into(dst, src)
+        both = self.acc.gather(np.asarray([dst, src], dtype=np.int64))
         combined = []
-        for (op, dt, _, _), vals in zip(self.acc.phys, ga):
+        for (op, _dt, _, _), vals in zip(self.acc.phys, both):
             if op == "add":
                 combined.append(np.asarray([vals[0] + vals[1]]))
             elif op == "min":
                 combined.append(np.asarray([min(vals[0], vals[1])]))
             else:
                 combined.append(np.asarray([max(vals[0], vals[1])]))
-        self.acc.restore(np.asarray([a[2]], dtype=np.int64), combined)
-        self.acc.reset_slots(np.asarray([b[2]], dtype=np.int64))
-        self._free_slot(b[2])
-        a[0] = min(a[0], b[0])
-        a[1] = max(a[1], b[1])
+        self.acc.restore(np.asarray([dst], dtype=np.int64), combined)
+        self.acc.reset_slots(np.asarray([src], dtype=np.int64))
+        self.dir.free_slot(src)
 
     def serve_stage_snapshot(self, view) -> None:
         """Serve OPEN sessions as partials (ISSUE 20 satellite). Called
         by seal_op inside the checkpoint capture span. Delta-staged:
-        only keys whose sessions changed since the last capture — new
-        events, merges, expiries, tracked in `_serve_dirty` beside the
-        incremental-checkpoint sets — are re-gathered and re-staged
-        flagged `partial: True` (end is the would-be close `last_ts +
-        gap`), so point reads — worker- and follower-side alike — see
-        in-flight sessions at the published epoch instead of a 404
-        until the gap closes. Unchanged partials persist in the
-        cumulative view/mirror, keeping capture cost O(touched
-        sessions) rather than O(live sessions) — the state-bloat
-        flatness gate depends on this. Requires a side-effect-free
-        `gather`; mesh-fused accumulators expose only gather_and_reset,
-        so they skip partials (a documented known limit — finals are
-        unaffected). A key whose sessions all closed since the last
-        capture is tombstoned ONLY if no final landed in this barrier
-        interval, so partials never clobber a just-emitted final."""
+        only sessions that changed since the last capture — new events,
+        merges, a neighbour's expiry: the table's `restage` flags — are
+        re-gathered and re-staged flagged `partial: True` (end is the
+        would-be close `last_ts + gap`), so point reads — worker- and
+        follower-side alike — see in-flight sessions at the published
+        epoch instead of a 404 until the gap closes. Unchanged partials
+        persist in the cumulative view/mirror, keeping capture cost
+        O(touched sessions) rather than O(live sessions) — the
+        state-bloat flatness gate depends on this. Requires a
+        side-effect-free `gather`; mesh-fused accumulators expose only
+        gather_and_reset, so they skip partials (a documented known
+        limit — finals are unaffected). A served partial needs no
+        retraction: the watermark that closes a session stages its final
+        (`_build_output`) in the same barrier interval, and the final is
+        the key's newer row; a partial staged behind it belongs to a
+        session the key has opened since."""
         gather = getattr(self.acc, "gather", None)
-        prev = getattr(self, "_serve_partial_keys", set())
-        if gather is None:
+        t = self._table
+        if gather is None or t is None:
             return
-        dirty = getattr(self, "_serve_dirty", None)
-        delta = dirty is not None
-        if not delta:
-            # stub operators (tests) without the delta sets: stage the
-            # full open set and diff against prev for tombs
-            dirty = set(self.sessions)
-        dead = getattr(self, "_serve_dead", set())
-        keys: List[tuple] = []
-        starts: List[int] = []
-        ends: List[int] = []
-        slots: List[int] = []
-        for key in dirty:
-            for s in self.sessions.get(key, ()):
-                # one row per session; staging overwrites per key, so a
-                # multi-session key serves its latest (max-start) session
-                keys.append(key)
-                starts.append(s[0])
-                ends.append(s[1] + self.gap)
-                slots.append(s[2])
-        staged: set = set()
-        if keys:
-            from ..serve import stage_batch
+        rows = np.nonzero(t.restage[:t.top])[0]
+        if not len(rows):
+            return
+        from ..serve import stage_batch
 
-            slot_arr = np.asarray(slots, dtype=np.int64)
-            agg_cols = self.acc.finalize(gather(slot_arr))
-            out = self._build_output(
-                keys, agg_cols,
-                np.asarray(starts, dtype=np.int64),
-                np.asarray(ends, dtype=np.int64),
-                serve_stage=False,
-            )
-            staged = set(stage_batch(view, out, partial=True))
-        gone = (prev & dead) if delta else (prev - staged)
-        for k in gone:
-            if view.has_staged(k):
-                continue  # a final landed this interval; keep it
-            if view.live_mode:
-                v = view.read(k, None)[1]
-                if not (isinstance(v, dict) and v.get("partial")):
-                    continue
-            view.stage_tomb(k)
-        # gone keys leave the partial set either way: tombed, or their
-        # staged row this interval is a final, no longer a partial
-        self._serve_partial_keys = (prev - gone) | staged
-        if delta:
-            dirty.clear()
-            dead.clear()
+        # one row per session; staging overwrites per key, so a
+        # multi-session key serves its latest (max-start) session
+        rows = rows[np.argsort(t.start[rows], kind="stable")]
+        agg_cols = self.acc.finalize(gather(t.slot[rows]))
+        keys, key_arrays = self._emitted_keys(rows)
+        out = self._build_output(
+            keys, agg_cols, t.start[rows], t.last[rows] + self.gap,
+            key_arrays=key_arrays, serve_stage=False,
+        )
+        stage_batch(view, out, partial=True)
+        t.restage[rows] = False
 
     async def handle_watermark(self, watermark, ctx, collector):
         if watermark.kind != WatermarkKind.EVENT_TIME:
             return watermark
-        t = watermark.timestamp
-        # collect every expired session first: one batched gather +
-        # finalize + reset per watermark (2 device dispatches in mesh
-        # mode), one output batch with per-row window bounds
-        exp_keys: List[tuple] = []
-        exp_starts: List[int] = []
-        exp_ends: List[int] = []
-        exp_slots: List[int] = []
-        for key in list(self.sessions):
-            remaining = []
-            expired_any = False
-            for s in self.sessions[key]:
-                if s[1] + self.gap <= t:
-                    expired_any = True
-                    exp_keys.append(key)
-                    exp_starts.append(s[0])
-                    exp_ends.append(s[1] + self.gap)
-                    exp_slots.append(s[2])
-                else:
-                    remaining.append(s)
-            if remaining:
-                self.sessions[key] = remaining
-                if expired_any:
-                    self._ckpt_dirty.add(key)
-                    # the expiry final overwrote the key's served
-                    # partial; re-stage the still-open session
-                    self._serve_dirty.add(key)
-            else:
-                del self.sessions[key]
-                if expired_any:
-                    self._ckpt_dead.add(key)
-                    self._ckpt_dirty.discard(key)
-                    self._serve_dead.add(key)
-                    self._serve_dirty.discard(key)
-        if exp_slots:
-            slot_arr = np.asarray(exp_slots, dtype=np.int64)
+        t = self._table
+        if t is None or not t.n_live:
+            return watermark
+        wm = watermark.timestamp
+        rows = t.expired(wm)
+        if not len(rows):
+            return watermark
+        # every expired session of this watermark together: one gather and
+        # one reset of their slots (one fused program on a mesh), one
+        # output batch with per-row window bounds. Ledger-only: it awaits
+        with timeline.phase("sess.expire", n=len(rows), key=wm,
+                            annotate=False) as ph:
+            ph.padded = t.n_live
+            slots = t.slot[rows]
+            starts = t.start[rows]
+            ends = t.last[rows] + self.gap
+            keys, key_arrays = self._emitted_keys(rows)
+            t.close(rows)
             fused = getattr(self.acc, "gather_and_reset", None)
             if fused is not None:
-                # mesh: one fused device program per expiry wave
-                agg_cols = self.acc.finalize(fused(slot_arr))
-                self.acc.drop_host_state(slot_arr)
+                agg_cols = self.acc.finalize(fused(slots))
+                self.acc.drop_host_state(slots)
             else:
-                agg_cols = self.acc.finalize(self.acc.gather(slot_arr))
-                self.acc.reset_slots(slot_arr)
-            self.dir.free_slots(slot_arr)  # batch: one extend per shard
-            out = self._build_output(
-                exp_keys, agg_cols,
-                np.asarray(exp_starts, dtype=np.int64),
-                np.asarray(exp_ends, dtype=np.int64),
-            )
+                agg_cols = self.acc.finalize(self.acc.gather(slots))
+                self.acc.reset_slots(slots)
+            self.dir.free_slots(slots)  # batch: one extend per shard
+            with timeline.phase("close.build", key=wm, n=len(rows)):
+                out = self._build_output(keys, agg_cols, starts, ends,
+                                         key_arrays=key_arrays)
             await collector.collect(out)
         return watermark
 

@@ -77,14 +77,15 @@ class SlotDirectory:
         self.next_slot += 1
         return s
 
-    # imperative allocation (session windows bypass assign()); the shard
+    # imperative allocation (session windows bypass assign()): a batch's
+    # new sessions take their slots in one `alloc_slots` call; the shard
     # hint only matters to the mesh facade, which load-balances with it
     def alloc_slot(self, shard_hint: int = 0) -> int:
         return self.free.pop() if self.free else self._alloc()
 
     def alloc_block(self, k: int) -> List[int]:
-        """Bulk-allocate k slots in one call (session slot pool): drains
-        the free list first, then extends the high-water mark once."""
+        """Bulk-allocate k slots in one call: drains the free list first,
+        then extends the high-water mark once."""
         nf = min(k, len(self.free))
         out = self.free[len(self.free) - nf:]
         del self.free[len(self.free) - nf:]
@@ -104,8 +105,8 @@ class SlotDirectory:
         self.free.append(int(slot))
 
     def free_slots(self, slots):
-        """Batch free (session expiry waves / slot-pool returns): one
-        C-level extend instead of a python call per slot."""
+        """Batch free (a watermark's closed sessions): one C-level extend
+        instead of a python call per slot."""
         self.free.extend(np.asarray(slots, dtype=np.int64).tolist())
 
     def bins_up_to(self, bin_exclusive: int) -> List[int]:
@@ -409,6 +410,79 @@ class KeyCodec:
         if pa.types.is_timestamp(kt):
             return pa.array(vals, type=pa.int64()).cast(kt)
         return pa.array(vals, type=kt)
+
+    # -- "values": the operator keeps its keys as columns --------------------
+    # One array per key column, indexed by the operator's own rows (the
+    # session table): int64 bit patterns where the type is integer-like
+    # (ints, bools, timestamps), plain values in an object array otherwise
+    # (strings, floats, a struct as the tuple of its children).
+
+    def int_like(self, ki: int) -> bool:
+        kt = self.types[ki]
+        return not (pa.types.is_struct(kt) or _is_interned_type(kt))
+
+    def value_columns(self, batch: pa.RecordBatch, key_cols
+                      ) -> List[np.ndarray]:
+        """A batch's key columns in the stored form. An integer-like
+        column that holds nulls arrives as floats and is refused: a NULL
+        session key has no bit pattern."""
+        out = []
+        for ki, c in enumerate(self.columns(batch, key_cols)):
+            c = np.asarray(c)
+            if not self.int_like(ki):
+                out.append(c.astype(object, copy=False))
+                continue
+            if c.dtype.kind == "M":
+                c = c.view("i8")
+            elif c.dtype == np.uint64:
+                c = c.view(np.int64)
+            elif c.dtype.kind not in "iub":
+                raise ValueError(
+                    f"session key column {ki} ({self.types[ki]}) holds "
+                    "nulls")
+            out.append(c.astype(np.int64, copy=False))
+        return out
+
+    def value_columns_from_values(self, keys: List[list]
+                                  ) -> List[np.ndarray]:
+        """Portable key rows (a checkpoint's) -> stored columns."""
+        out = []
+        for ki, kt in enumerate(self.types):
+            vals = [k[ki] for k in keys]
+            if not self.int_like(ki):
+                col = np.empty(len(vals), dtype=object)
+                # msgpack round-trips a struct's tuple as a list
+                col[:] = [tuple(v) if isinstance(v, list) else v
+                          for v in vals]
+            elif pa.types.is_unsigned_integer(kt):
+                col = np.asarray(vals, dtype=np.uint64).view(np.int64)
+            else:
+                col = np.asarray(vals, dtype=np.int64)
+            out.append(col)
+        return out
+
+    def value_lists(self, cols: List[np.ndarray]) -> List[list]:
+        """Stored columns -> one list of portable values per column."""
+        return [
+            (c.view(np.uint64) if c.dtype == np.int64
+             and pa.types.is_unsigned_integer(kt) else c).tolist()
+            for c, kt in zip(cols, self.types)
+        ]
+
+    def arrow_from_arrays(self, ki: int, arrays) -> pa.Array:
+        """Key column `ki` from the columns an emission carries: the
+        table's word columns ("words") or the operator's stored columns
+        ("values")."""
+        if self.words:
+            return self.arrow_from_words(ki, arrays)
+        col = arrays[ki]
+        kt = self.types[ki]
+        if col.dtype == np.int64:
+            if pa.types.is_unsigned_integer(kt):
+                return pa.array(col.view(np.uint64), type=kt)
+            return pa.array(col).cast(kt)
+        return self.arrow_from_keys(
+            ki, [(None,) * ki + (v,) for v in col.tolist()])
 
     # -- table key <-> portable values ---------------------------------------
 

@@ -255,46 +255,64 @@ def test_join_snapshot_cross_product_outer_padding_and_tombs():
     assert v.read((2,), 2) == (False, None)
 
 
-def test_session_partial_tomb_never_clobbers_final():
-    """Session partials tombstone a key whose sessions all closed ONLY
-    when no final landed in the same barrier interval (the final wins);
-    in live mode a non-partial served value is likewise protected."""
+def test_a_closed_sessions_final_supersedes_its_partial():
+    """A session is served as a partial from the barrier after it opened;
+    the watermark that closes it stages its final in the same barrier
+    interval, so no retraction is ever staged: the final is the key's
+    newer row, and a key that has opened a new session since serves that
+    one as a partial again."""
+    import asyncio
+    import types
+
+    import pyarrow as pa
+
     from arroyo_tpu.operators.windows import SessionWindowOperator
+    from arroyo_tpu.schema import StreamSchema
+    from arroyo_tpu.types import WatermarkKind
 
-    v = _plan_view(kind="window", key_names=["k"],
-                   value_names=["cnt"])
-    op = type("Op", (), {})()
-    op.acc = type("A", (), {"gather": None})()  # mesh-fused: skip
-    op.sessions = {}
-    op._serve_partial_keys = {(7,), (8,)}
-    # key 7's final landed this interval (staged); key 8 just vanished
-    v.stage((7,), {"cnt": 42})
-    SessionWindowOperator.serve_stage_snapshot(op, v)
-    # gather is None -> partials skipped entirely, including tombs
-    v.seal(1)
-    assert v.read((7,), 1) == (True, {"cnt": 42})
+    in_schema = StreamSchema.from_fields([("k", pa.int64())])
+    op = SessionWindowOperator({
+        "aggregates": [{"kind": "count", "name": "cnt"}],
+        "schema": StreamSchema.from_fields(
+            [("k", pa.int64()), ("cnt", pa.int64())]),
+        "gap_nanos": 1000, "key_cols": [0], "backend": "numpy"})
+    v = op._serve_view = _plan_view(
+        kind="window", key_names=["k"], value_names=["cnt"])
+    ctx = types.SimpleNamespace(
+        in_schemas=[in_schema], table_manager=None,
+        watermarks=types.SimpleNamespace(current_nanos=lambda: None))
 
-    class _Gather:
-        @staticmethod
-        def gather(slots):
-            return []
+    class Sink:
+        async def collect(self, b):
+            pass
 
-        @staticmethod
-        def finalize(x):
-            return []
+    def feed(keys, ts):
+        asyncio.run(op.process_batch(pa.RecordBatch.from_arrays(
+            [pa.array(keys, type=pa.int64()),
+             pa.array(ts, type=pa.timestamp("ns"))],
+            schema=in_schema.schema), ctx, None))
 
-    op2 = type("Op", (), {})()
-    op2.acc = _Gather()
-    op2.gap = 10
-    op2.sessions = {}
-    op2._serve_partial_keys = {(7,), (8,)}
-    v2 = _plan_view(kind="window", key_names=["k"],
-                    value_names=["cnt"])
-    v2.stage((7,), {"cnt": 42})  # the final, staged this interval
-    SessionWindowOperator.serve_stage_snapshot(op2, v2)
-    v2.seal(1)
-    assert v2.read((7,), 1) == (True, {"cnt": 42})  # final survived
-    assert v2.read((8,), 1) == (False, None)        # stale partial gone
+    def barrier(epoch):
+        asyncio.run(op.handle_checkpoint(None, ctx, None))
+        op.serve_stage_snapshot(v)
+        v.seal(epoch)
+
+    feed([7, 7, 8], [0, 10, 5])
+    barrier(1)
+    assert v.read((7,), 1) == (True, {"cnt": 2, "partial": True})
+    assert v.read((8,), 1) == (True, {"cnt": 1, "partial": True})
+    # both close; 8 comes back before the barrier, 9 is new
+    asyncio.run(op.handle_watermark(types.SimpleNamespace(
+        kind=WatermarkKind.EVENT_TIME, timestamp=1500), ctx, Sink()))
+    feed([8, 9], [3000, 3000])
+    barrier(2)
+    assert v.read((7,), 2) == (True, {"cnt": 2})        # the final
+    assert v.read((8,), 2) == (True, {"cnt": 1, "partial": True})
+    assert v.read((9,), 2) == (True, {"cnt": 1, "partial": True})
+    assert v.read((7,), 1) == (True, {"cnt": 2})  # (the view moved on)
+    # an unchanged partial is not staged again
+    barrier(3)
+    assert not v._stage and v.read((9,), 3)[1]["partial"] is True
 
 
 # -- the mirror: segments through a real chain (ISSUE 25) --------------------

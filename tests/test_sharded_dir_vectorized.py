@@ -288,7 +288,12 @@ def test_flush_elision_skips_disjoint_reads(mesh):
     assert np.asarray(out_a[0]).tolist() == [0] * 32
 
 
-def test_session_pool_returned_at_checkpoint():
+def test_sessions_hold_no_slot_they_do_not_use():
+    """Slots are allocated a batch's misses at a time, one directory call
+    each, and freed a watermark's closes at a time: no pool stands between
+    the operator and the directory, so a checkpoint can strand no
+    allocated-but-unused slot (ADVICE round 5) and a close's slots are the
+    next open's."""
     import asyncio
     import types
 
@@ -296,7 +301,9 @@ def test_session_pool_returned_at_checkpoint():
 
     from arroyo_tpu.operators.windows import SessionWindowOperator
     from arroyo_tpu.schema import StreamSchema
+    from arroyo_tpu.types import WatermarkKind
 
+    in_schema = StreamSchema.from_fields([("k", pa.int64())])
     op = SessionWindowOperator({
         "aggregates": [{"kind": "count", "name": "cnt"}],
         "schema": StreamSchema.from_fields(
@@ -305,18 +312,37 @@ def test_session_pool_returned_at_checkpoint():
         "gap_nanos": 1000,
         "key_cols": [0],
     })
-    s = op._alloc_slot()
-    assert len(op._slot_pool) == op._POOL_BLOCK - 1
-    ctx = types.SimpleNamespace(table_manager=None)
-    asyncio.run(op.handle_checkpoint(None, ctx, None))
-    # pool drained back into the directory free list: a checkpoint can
-    # no longer strand allocated-but-unused slots (ADVICE round 5)
-    assert not op._slot_pool
-    assert len(op.dir.free) == op._POOL_BLOCK - 1
-    # the next refill recycles the returned slots: the block of 64 costs
-    # one fresh slot (the one still held by the live session), not 64
-    mark = op.dir.next_slot
-    s2 = op._alloc_slot()
-    assert op.dir.next_slot == mark + 1
-    assert not op.dir.free
-    assert s2 != s
+    ctx = types.SimpleNamespace(
+        in_schemas=[in_schema], table_manager=None,
+        watermarks=types.SimpleNamespace(current_nanos=lambda: None))
+    calls = []
+    alloc = op.dir.alloc_slots
+    op.dir.alloc_slots = lambda n, hint=0: calls.append(n) or alloc(n, hint)
+
+    def batch(keys, ts):
+        return pa.RecordBatch.from_arrays(
+            [pa.array(keys, type=pa.int64()),
+             pa.array(ts, type=pa.timestamp("ns"))],
+            schema=in_schema.schema)
+
+    class Sink:
+        async def collect(self, b):
+            pass
+
+    async def go():
+        await op.process_batch(batch(list(range(70)), [10] * 70), ctx, None)
+        assert calls == [70] and op.dir.next_slot == 70 and not op.dir.free
+        await op.handle_checkpoint(None, ctx, None)
+        assert op.dir.next_slot == 70 and not op.dir.free
+        # keys 0..29 go quiet and close; their 30 slots are the next 30
+        await op.process_batch(
+            batch(list(range(30, 70)), [900] * 40), ctx, None)
+        await op.handle_watermark(types.SimpleNamespace(
+            kind=WatermarkKind.EVENT_TIME, timestamp=1200), ctx, Sink())
+        assert len(op.dir.free) == 30 and len(op.sessions) == 40
+        await op.process_batch(
+            batch(list(range(100, 130)), [1300] * 30), ctx, None)
+        assert calls == [70, 30]
+        assert op.dir.next_slot == 70 and not op.dir.free
+
+    asyncio.run(go())

@@ -195,9 +195,15 @@ async def _node(args):
 
 async def _worker(args):
     from .engine.worker import worker_main
+    from .parallel.multihost import ensure_initialized
     from .utils import init_logging
 
     init_logging()
+    # join the job's multi-process device mesh BEFORE any jax backend
+    # init: the controller assigned (coordinator, n, rank) via
+    # ARROYO__TPU__MESH_* env overrides at scheduling time
+    # (parallel/multihost.py; no-op in single-process deployments)
+    ensure_initialized()
     await worker_main(args.controller)
 
 

@@ -3,7 +3,7 @@
 An operator class registered as fusable (`fusable = True`) may be fused
 into a segment run that executes with ONE dispatch per batch and NO
 per-operator checkpoint participation: the runner captures no state for
-it and the segment drains, not snapshots, at barriers. A fusable
+it and the segment does nothing at a barrier. A fusable
 operator that quietly grows state (self._state...), reaches for the
 state tables (ctx.table_manager / ctx.table(...)) or overrides the
 checkpoint hooks would silently lose that state across recovery — its

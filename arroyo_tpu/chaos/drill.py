@@ -540,12 +540,6 @@ def run_rescale_drill(seed: int, workdir: str,
     fault_sql = load_query(query_path, fault_out, golden_dir,
                            throttle=throttle)
     plan = chaos.install(rescale_plan(seed))
-    from .. import obs
-
-    # fresh span buffer: the drill reports barrier-drain time from the
-    # faulted run's runner.pipeline_drain spans (ISSUE 14 — the
-    # measurement ROADMAP item 4's generation-overlap rescale needs)
-    obs.recorder().clear()
     error = None
     restarts = rescales = 0
     decisions: List[dict] = []
@@ -605,14 +599,6 @@ def run_rescale_drill(seed: int, workdir: str,
         error = f"unfired faults: {[s.describe() for s in plan.unfired()]}"
     if error is None and rescales < 1:
         error = "the autoscaler never triggered a rescale"
-    # barrier-drain measurement: per-barrier pipeline drain time from the
-    # runner.pipeline_drain spans (the data the zero-downtime-rescale arc
-    # needs: how long a barrier waits on in-flight staged batches)
-    drains = [
-        s for s in obs.recorder().snapshot()
-        if s.get("name") == "runner.pipeline_drain"
-    ]
-    drain_ms = sorted(s["dur"] / 1000.0 for s in drains)
     # output-gap-per-rescale probes (ISSUE 15): a fault-free 1->2
     # source+window rescale per mode — the generation-overlap gap
     # (rescale.overlap span, checkpoint interval 0.25s) with the
@@ -649,15 +635,6 @@ def run_rescale_drill(seed: int, workdir: str,
         unfired=[s.describe() for s in plan.unfired()],
         error=error,
         extras={
-            "pipeline_drain_barriers": len(drains),
-            "pipeline_drain_ms_p50": round(
-                drain_ms[len(drain_ms) // 2], 3) if drain_ms else 0.0,
-            "pipeline_drain_ms_max": round(drain_ms[-1], 3)
-            if drain_ms else 0.0,
-            "pipeline_drain_staged_max": max(
-                (int(s.get("attrs", {}).get("staged", 0)) for s in drains),
-                default=0,
-            ),
             "rescale_gap_overlap": gap_overlap,
             "rescale_gap_stop_the_world": gap_stw,
         },
@@ -705,19 +682,14 @@ def pipeline_plan(seed: int) -> FaultPlan:
 
 def run_pipeline_drill(seed: int, workdir: str, n_rows: int = 6000,
                        timeout: float = 150.0) -> DrillResult:
-    """ISSUE 14 acceptance: exactly-once through the fused segment
-    runtime's double-buffered staging queue. A 3-op stateless chain
+    """Exactly-once through a fused segment. A 3-op stateless chain
     (filter -> convert -> round) feeds a tumbling aggregate; the clean
-    reference runs UNFUSED on the host kernels, the faulted run keeps
-    fusion + two-deep pipelining ON with the segment's jitted device
-    tier forced onto jax-CPU and small batches, so barriers routinely
-    arrive while a dispatched batch is staged un-materialized, and a
-    worker SIGKILL lands mid-stream. Passes iff
-    (a) canonical output is byte-identical (no staged event lost or
-    duplicated), (b) the kill forced a real recovery, and (c) the
-    runner.pipeline_drain spans prove at least one barrier actually
-    drained a staged batch (the scenario exercised what it claims)."""
-    from .. import obs
+    reference runs UNFUSED, the faulted run keeps fusion ON with small
+    batches, so barriers land between the segment's batches, and a
+    worker SIGKILL plus a dropped connection land mid-stream. Passes iff
+    (a) canonical output is byte-identical (no event lost or
+    duplicated), (b) the kill forced a real recovery, (c) every fault
+    fired and (d) the conservation audit is clean."""
     from ..config import update
 
     os.makedirs(workdir, exist_ok=True)
@@ -752,16 +724,10 @@ def run_pipeline_drill(seed: int, workdir: str, n_rows: int = 6000,
         "$out", fault_out).format(
         throttle=",\n  throttle_per_sec = '1500'")
     plan = chaos.install(pipeline_plan(seed))
-    obs.recorder().clear()
     error = None
     restarts = 0
     try:
-        # small batches + two-deep staging, with the segment's JAX tier
-        # forced (jax-CPU): dispatched-but-unmaterialized batches really
-        # sit in the staging queue, so barriers land mid-pipeline —
-        # host-tier results emit eagerly and would never stage
-        with update(engine={"segment_fusion": True, "pipeline_depth": 2},
-                    tpu={"enabled": True, "require_accelerator": False},
+        with update(engine={"segment_fusion": True},
                     pipeline={"source_batch_size": 64}):
             restarts = _run_embedded(
                 fault_sql, "drill-pipe-faulted",
@@ -775,25 +741,14 @@ def run_pipeline_drill(seed: int, workdir: str, n_rows: int = 6000,
         chaos.clear()
 
     got = canonicalize_output(fault_out, fault_sql, {})
-    drains = [
-        s for s in obs.recorder().snapshot()
-        if s.get("name") == "runner.pipeline_drain"
-    ]
-    staged_max = max(
-        (int(s.get("attrs", {}).get("staged", 0)) for s in drains),
-        default=0,
-    )
     passed = (error is None and got == want and not plan.unfired()
-              and restarts >= 1 and staged_max >= 1)
+              and restarts >= 1)
     if error is None and got != want:
         error = f"output diverged: {len(got)} rows vs {len(want)}"
     if error is None and plan.unfired():
         error = f"unfired faults: {[s.describe() for s in plan.unfired()]}"
     if error is None and restarts < 1:
         error = "the SIGKILL never forced a recovery"
-    if error is None and staged_max < 1:
-        error = ("no barrier ever drained a staged batch — the drill "
-                 "did not exercise the mid-flight pipeline")
     passed, error, audit_breaches = _audit_verdict(audit_mark, passed, error)
     return DrillResult(
         query="fused_pipeline_kill",
@@ -806,14 +761,6 @@ def run_pipeline_drill(seed: int, workdir: str, n_rows: int = 6000,
         expected_log=plan.expected_log(),
         unfired=[s.describe() for s in plan.unfired()],
         error=error,
-        extras={
-            "pipeline_drain_barriers": len(drains),
-            "pipeline_drain_staged_max": staged_max,
-            "barriers_with_staged": sum(
-                1 for s in drains
-                if int(s.get("attrs", {}).get("staged", 0)) >= 1
-            ),
-        },
         audit_breaches=audit_breaches,
     )
 

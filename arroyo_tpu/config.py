@@ -122,8 +122,7 @@ class TpuConfig:
     # GSPMD device-resident keyed exchange (one fused route+scatter+
     # reduce jitted program; XLA compiles the all_to_all into the step;
     # no host combiner), 'host_fed' = combiner + dst-major packed
-    # transfer (the multi-process / virtual-mesh fallback), 'a2a' =
-    # host-packed src-major layout + in-step all_to_all. 'auto' picks
+    # transfer (the multi-process / virtual-mesh fallback). 'auto' picks
     # 'device' on real chip meshes and 'host_fed' on virtual (forced
     # host-platform) or multi-process CPU meshes.
     mesh_exchange: str = "auto"
@@ -153,9 +152,6 @@ class TpuConfig:
     device_join: bool = True
     # joins below this probe-side row count stay on the host arrow join
     device_join_min_rows: int = 4096
-    # run the join probe even without tpu.enabled (jax on CPU): lets the
-    # bench measure the probe's cost model off-TPU
-    device_join_force: bool = False
 
 
 @dataclasses.dataclass
@@ -164,26 +160,12 @@ class EngineConfig:
     contiguous runs of stateless value operators inside a chained task
     (filter -> project -> expression-eval) are compiled into ONE segment
     program at plan time, so the runner makes one dispatch per segment
-    per batch instead of one per operator, and the batch path is
-    double-buffered so host Arrow decode/pack of batch k+1 overlaps the
-    in-flight dispatch of batch k."""
+    per batch instead of one per operator."""
 
     # master switch for plan-time segment fusion: off = every stateless
     # operator keeps its own per-batch dispatch (the pre-fusion data
     # plane; the nightly bench A/B child runs with this off)
     segment_fusion: bool = True
-    # batches a fused segment may hold in flight (dispatch issued, output
-    # not yet materialized/emitted): 2 = double buffering — batch k's
-    # device dispatch overlaps batch k+1's host decode/pack. Emission
-    # stays strictly FIFO, watermarks are held while batches are staged,
-    # and checkpoint barriers drain the pipeline before capture
-    # (runner.pipeline_drain), so outputs are byte-identical at any
-    # depth. 1 disables staging.
-    pipeline_depth: int = 2
-    # donate segment input buffers to the jitted program (XLA in-place
-    # aliasing on the steady-state dispatch): 'auto' = only on real
-    # accelerators, 'on' = always, 'off' = never
-    segment_donation: str = "auto"
 
 
 @dataclasses.dataclass

@@ -1093,13 +1093,6 @@ class WorkerServer:
 
 
 async def worker_main(controller_addr: str):
-    # join the job's multi-process device mesh BEFORE any jax backend
-    # init: the controller assigned (coordinator, n, rank) via
-    # ARROYO__TPU__MESH_* env overrides at scheduling time
-    # (parallel/multihost.py; no-op in single-process deployments)
-    from ..parallel.multihost import ensure_initialized
-
-    ensure_initialized()
     pooled = os.environ.get("ARROYO_WORKER_POOLED") == "1"
     w = WorkerServer(controller_addr, pooled=pooled)
     await w.start()

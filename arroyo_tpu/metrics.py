@@ -403,8 +403,7 @@ DEVICE_PADDING_WASTE = REGISTRY.gauge(
 SEGMENT_DISPATCH_SECONDS = REGISTRY.histogram(
     "arroyo_segment_dispatch_seconds",
     "per-batch execution wall time of fused stateless segments, per "
-    "segment program and tier (tier=jax: one jitted XLA program for the "
-    "whole chain; tier=host: the composed arrow/numpy program)")
+    "segment program")
 SEGMENT_FUSED_OPS = REGISTRY.gauge(
     "arroyo_segment_fused_ops",
     "operators fused into each segment program (the dispatches a batch "

@@ -54,7 +54,6 @@ from ..metrics import (
     DEVICE_EXCHANGE_SECONDS,
     DEVICE_PADDING_WASTE,
     DEVICE_DISPATCH_SECONDS,
-    SEGMENT_DISPATCH_SECONDS,
     XLA_COMPILE_CACHE,
     XLA_COMPILE_SECONDS,
     XLA_COMPILES,
@@ -215,11 +214,9 @@ class InstrumentedJit:
     histogram will show it — but it still costs a python-side trace."""
 
     __slots__ = ("program", "fn", "seen", "_compiles", "_hit", "_miss",
-                 "_compile_h", "_dispatch_h", "_exchange_h", "_segment_h",
-                 "_rows")
+                 "_compile_h", "_dispatch_h", "_exchange_h", "_rows")
 
-    def __init__(self, program: str, fn, exchange: bool = False,
-                 segment: bool = False):
+    def __init__(self, program: str, fn, exchange: bool = False):
         self.program = program
         self.fn = fn
         self.seen: set = set()
@@ -237,13 +234,6 @@ class InstrumentedJit:
         self._exchange_h = (
             DEVICE_EXCHANGE_SECONDS.labels(program=program)
             if exchange else None
-        )
-        # fused-segment programs (engine/segments.py) additionally feed
-        # arroyo_segment_dispatch_seconds{tier="jax"} so the per-segment
-        # ledger separates whole-chain dispatches from other device work
-        self._segment_h = (
-            SEGMENT_DISPATCH_SECONDS.labels(program=program, tier="jax")
-            if segment else None
         )
 
     def __call__(self, *args, rung: Optional[int] = None,
@@ -300,8 +290,6 @@ class InstrumentedJit:
             self._dispatch_h.observe(dt)
             if self._exchange_h is not None:
                 self._exchange_h.observe(dt)
-            if self._segment_h is not None:
-                self._segment_h.observe(dt)
         return out
 
 
@@ -442,15 +430,14 @@ def summary() -> dict:
     ]
     padding.sort(key=lambda e: (e["program"], int(e["rung"] or 0)))
     # fused-segment ledger (engine/segments.py): per-segment dispatch
-    # stats by tier plus the fused-op count — what the mesh_profile
-    # BASELINE ledger renders as per-segment rows
+    # stats plus the fused-op count — what the mesh_profile BASELINE
+    # ledger renders as per-segment rows (a segment runs on the host)
     segments: Dict[str, dict] = {}
     for labels, h in snap.get("arroyo_segment_dispatch_seconds", []):
         s = segments.setdefault(labels.get("program", "?"), {})
-        tier = labels.get("tier", "?")
-        s[f"{tier}_dispatches"] = int(h.get("count", 0))
-        s[f"{tier}_s_total"] = round(h.get("sum", 0.0), 4)
-        s[f"{tier}_quantiles"] = {
+        s["host_dispatches"] = int(h.get("count", 0))
+        s["host_s_total"] = round(h.get("sum", 0.0), 4)
+        s["host_quantiles"] = {
             q: round(v, 6) for q, v in hist_quantiles(h).items()
         }
     for labels, v in snap.get("arroyo_segment_fused_ops", []):

@@ -197,12 +197,6 @@ class SubtaskRunner:
         self._marker_secs = LATENCY_MARKER_SECONDS.labels(job=jid, task=tid)
         self._e2e_secs = E2E_LATENCY_SECONDS.labels(job=jid, task=tid)
         self._compile_trace = obs.new_trace(jid, f"batch-{tid}")
-        # fused segments in this chain (engine/segments.py): their staged
-        # double-buffered batches must drain before a barrier's capture
-        self._segment_idxs = [
-            i for i, op in enumerate(ops)
-            if getattr(op, "is_fused_segment", False)
-        ]
         # conservation ledger (obs/audit.py): receiver-side attestation
         # taps (one per input whose queue the wiring stamped with its
         # edge key) + per-operator in/out selectivity counts. All state
@@ -755,7 +749,6 @@ class SubtaskRunner:
         awaits its predecessor), so file-list bookkeeping and completion
         reports stay ordered while barrier cadence is fully decoupled
         from upload time. `then_stop` and commit paths drain completely."""
-        await self._drain_pipeline(barrier)
         await self._admit_flush()
         self.control_tx.put_nowait(
             CheckpointEventResp(
@@ -857,26 +850,6 @@ class SubtaskRunner:
             cnt[0] = 0
             cnt[1] = 0
         return {"tx": tx, "rx": rx, "ops": ops, "flow": flow}
-
-    async def _drain_pipeline(self, barrier):
-        """Drain every fused segment's staged (double-buffered) batches
-        downstream before the barrier's state capture, so the epoch's
-        durable state reflects every pre-barrier event and no batch is
-        in flight across the checkpoint. Recorded as a
-        `runner.pipeline_drain` span per barrier (the rescale drill
-        reports drain time per barrier from these spans)."""
-        if not self._segment_idxs:
-            return
-        staged = sum(
-            self.ops[i].staged_depth for i in self._segment_idxs
-        )
-        span = self._barrier_span("runner.pipeline_drain", barrier)
-        t0 = time.perf_counter()
-        with span:
-            for i in self._segment_idxs:
-                await self.ops[i].drain(self.ctxs[i], self.collectors[i])
-            span.set(staged=staged,
-                     drain_ms=round(1e3 * (time.perf_counter() - t0), 3))
 
     @protocol_effect("worker.admit_flush")
     async def _admit_flush(self):

@@ -110,10 +110,8 @@ def device_tier_active() -> bool:
 
 def device_join_active() -> bool:
     """Gate for the merge-join probe, shared by the instant/expiring and
-    updating join operators: the device tier (or the force flag for
-    off-TPU cost-model measurement) plus the join-specific switch."""
+    updating join operators: the device tier plus the join-specific
+    switch."""
     from ..config import config
 
-    cfg = config().tpu
-    return cfg.device_join and (device_tier_active()
-                                or cfg.device_join_force)
+    return config().tpu.device_join and device_tier_active()

@@ -11,7 +11,7 @@ XLA collectives over ICI, which requires this process-spanning mesh.
 Wiring (SURVEY.md §5.8): the controller assigns
 (coordinator address, process count, process id) at scheduling time —
 `controller/scheduler.py` injects them into each spawned worker's env as
-`ARROYO__TPU__MESH_*` config overrides — and `worker_main` calls
+`ARROYO__TPU__MESH_*` config overrides — and the `worker` command calls
 `ensure_initialized()` BEFORE any jax backend init. Operators then build
 meshes from the global device list exactly as in single-host mode.
 

@@ -33,11 +33,6 @@ chosen per deployment (`tpu.mesh_exchange`, default `auto`):
     [S, R] buffer: the sharded host->device transfer IS the shuffle
     and the step has no collective at all.
 
-  * `a2a` — the legacy host-packed [S, S, R] src-major layout with the
-    in-step all_to_all (kept for device-resident producers that are
-    already sharded by source, and as the shard_map exchange tier the
-    multihost tests drive).
-
     emission: jitted (shard, slot) gather -> host, once per watermark
     wave, chunked at `tpu.mesh_emission_chunk` and padded on the
     sticky emission rung ladder (see _StickyRung).
@@ -92,8 +87,8 @@ from ..types import hash_arrays, hash_column, server_for_hash_array
 # (not the current capacity) so capacity growth never re-numbers live slots.
 STRIDE = 1 << 32
 
-# process-wide packed-exchange traffic diagnostics (direct [S, R] or
-# all_to_all [S, S, R] layout, whichever each update used), aggregated
+# process-wide packed-exchange traffic diagnostics (host-fed [S, R] or
+# device-routed [S, C] layout, whichever each update used), aggregated
 # across every ShardedAccumulator instance; bench --mesh reads these to
 # report the padding overhead of the host->device/ICI row shipment and
 # the dispatch amortization (device steps per engine update call).
@@ -732,7 +727,6 @@ class ShardedAccumulator(Accumulator):
         mesh,
         capacity_per_shard: int = 4096,
         rows_per_shard: int = 1024,
-        host_fed: bool = True,
         salted: bool = False,
         flush_rows: int = 0,
         exchange: Optional[str] = None,
@@ -759,7 +753,6 @@ class ShardedAccumulator(Accumulator):
         self._rung_direct = _StickyRung(
             _pow2_ladder(rows_per_shard * self.n_shards, floor=16)
         )
-        self._rung_a2a = _StickyRung(_pow2_ladder(rows_per_shard, floor=2))
         # device-routed exchange rungs: C = src-major rows per source
         # shard, R = all_to_all cell rows (sized from the host bincount)
         self._rung_chunk = _StickyRung(
@@ -778,11 +771,9 @@ class ShardedAccumulator(Accumulator):
         self._multiproc = is_multiprocess_mesh(mesh)
         # exchange tier: 'device' (fused GSPMD route+scatter+reduce, no
         # host combiner), 'host_fed' (combiner + dst-major [S, R] packed
-        # transfer — the multi-process / virtual-mesh fallback), 'a2a'
-        # (host-packed [S, S, R] + in-step all_to_all). See module
-        # docstring for the auto-resolution rationale.
-        self._exchange = self._resolve_exchange(exchange, host_fed)
-        self.host_fed = self._exchange == "host_fed"
+        # transfer — the multi-process / virtual-mesh fallback). See
+        # module docstring for the auto-resolution rationale.
+        self._exchange = self._resolve_exchange(exchange)
         # emission/reset/restore reads are chunked at
         # tpu.mesh_emission_chunk and padded on their own sticky ladder:
         # big drain waves re-use the full-chunk program instead of
@@ -839,8 +830,7 @@ class ShardedAccumulator(Accumulator):
         self._sharding = self._make_sharding()
         self.state = self._fresh_state(capacity_per_shard)
 
-    def _resolve_exchange(self, exchange: Optional[str],
-                          host_fed: bool) -> str:
+    def _resolve_exchange(self, exchange: Optional[str]) -> str:
         """Pick the exchange tier. Explicit ctor/config choices win; auto
         keeps the host-fed combiner path wherever the device-routed
         exchange cannot pay for itself: multi-process meshes (no ICI
@@ -853,15 +843,13 @@ class ShardedAccumulator(Accumulator):
         mode = exchange or str(
             getattr(config_fn().tpu, "mesh_exchange", "auto") or "auto"
         )
-        if mode not in ("auto", "device", "host_fed", "a2a"):
+        if mode not in ("auto", "device", "host_fed"):
             raise ValueError(
-                f"tpu.mesh_exchange must be auto|device|host_fed|a2a, "
+                f"tpu.mesh_exchange must be auto|device|host_fed, "
                 f"got {mode!r}"
             )
         if mode != "auto":
             return mode
-        if not host_fed:
-            return "a2a"  # ctor opt-in to the src-major packed layout
         if self._multiproc or mesh_is_virtual(self.mesh):
             return "host_fed"
         return "device"
@@ -1174,7 +1162,7 @@ class ShardedAccumulator(Accumulator):
         """(program, step, buffer shape, row indices, flat positions, rows
         of the busiest destination) of each step that ships `slots`."""
         n = len(slots)
-        S, R = self.n_shards, self.rows_per_shard
+        S = self.n_shards
         owners = slots // STRIDE
         if self.salted:
             # balanced spread: every shard takes ~n/S rows of each group;
@@ -1184,43 +1172,20 @@ class ShardedAccumulator(Accumulator):
         so = owners[order]
         starts = np.searchsorted(so, so, side="left")
         pos = np.arange(n, dtype=np.int64) - starts   # rank within owner
+        # dst-major [S, R] direct layout: the host already sees every
+        # row, so the key shuffle happens at packing time and the
+        # sharded host->device transfer IS the routing.
         steps = []
-        if self.host_fed:
-            # dst-major [S, R] direct layout: the host already sees every
-            # row, so the key shuffle happens at packing time and the
-            # sharded host->device transfer IS the routing.
-            r_cap = self.rows_per_shard * S
-            chunk = pos // r_cap
-            for c in range(int(chunk.max()) + 1):
-                in_chunk = chunk == c
-                pm = pos[in_chunk] - c * r_cap
-                dst = so[in_chunk]
-                r_c = self._rung_direct.fit(int(pm.max()) + 1)
-                steps.append((
-                    "mesh.step_direct", self._direct_step(), (S, r_c),
-                    order[in_chunk], dst * r_c + pm,
-                    int(np.bincount(dst).max()),
-                ))
-            return steps
-        # Balanced packing into the [src, dst, row] all_to_all layout:
-        # each destination shard's rows are dealt round-robin across the
-        # S source positions, so every (src, dst) cell carries
-        # ceil(count_dst / S) rows and the per-cell row budget R shrinks
-        # to the sticky-bucketed max — the buffer is sized to the batch
-        # (plus skew), not to the configured ceiling. Splits into
-        # multiple steps only when the hottest destination overflows
-        # S * rows_per_shard rows.
-        srcs = pos % S
-        cell = pos // S                               # row within cell
-        chunk = cell // R
+        r_cap = self.rows_per_shard * S
+        chunk = pos // r_cap
         for c in range(int(chunk.max()) + 1):
             in_chunk = chunk == c
-            cm = cell[in_chunk] - c * R
+            pm = pos[in_chunk] - c * r_cap
             dst = so[in_chunk]
-            r_c = self._rung_a2a.fit(int(cm.max()) + 1)
+            r_c = self._rung_direct.fit(int(pm.max()) + 1)
             steps.append((
-                "mesh.step", self._step(), (S, S, r_c), order[in_chunk],
-                (srcs[in_chunk] * S + dst) * r_c + cm,
+                "mesh.step_direct", self._direct_step(), (S, r_c),
+                order[in_chunk], dst * r_c + pm,
                 int(np.bincount(dst).max()),
             ))
         return steps
@@ -1306,7 +1271,7 @@ class ShardedAccumulator(Accumulator):
     def _dispatch(self, step, shape, rows, flat, locals_, vals, signs):
         """Pack (slots, valid, per-source values) buffers of `shape` and
         run one jitted step. Buffers enter the device sharded on dim 0
-        (the destination-shard dimension in both layouts). `vals` holds
+        (the destination-shard dimension). `vals` holds
         one value array per non-count physical accumulator, pre-extracted
         at update() time so buffered flushes just concatenate."""
         MESH_STATS["dispatches"] += 1
@@ -1339,61 +1304,12 @@ class ShardedAccumulator(Accumulator):
                 rung=shape[-1], rows=len(rows), padded=total,
             )
 
-    def _step(self):
-        return self._program("step", self._make_step)
-
     def _direct_step(self):
         return self._program("step_direct", self._make_direct_step)
 
     def _route_step(self, C: int, R: int):
         return self._program("route", lambda: self._make_route_step(C, R),
                              C, R)
-
-    def _make_step(self):
-        jax = get_jax()
-
-        from .mesh import _get_jnp
-
-        jnp = _get_jnp()
-        phys = list(self.phys)
-        axis = self.axis
-
-        scatter = _scatter_body(phys, jnp, self._neutral)
-
-        def local_update(state_shards, slots, valid, *vals):
-            # local views: state [1, cap]; slots/valid/vals [1, S, R] where
-            # dim1 indexes the destination shard. all_to_all over the mesh
-            # axis exchanges those blocks (the ICI shuffle): afterwards
-            # [S, R] holds the rows every source shard sent to THIS shard.
-            def exchange(x):
-                return jax.lax.all_to_all(x[0], axis, 0, 0, tiled=True)
-
-            valid_r = exchange(valid).reshape(-1)
-            flat_slots = exchange(slots).reshape(-1)
-            vals_r = [exchange(v).reshape(-1) for v in vals]
-            return scatter(state_shards, flat_slots, valid_r, vals_r)
-
-        n_state = len(self.phys)
-
-        @partial(jax.jit, donate_argnums=(0,), static_argnums=())
-        def mesh_step(state, slots, valid, *vals):
-            from jax.sharding import PartitionSpec as P
-
-            f = jax.shard_map(
-                local_update,
-                mesh=self.mesh,
-                in_specs=(
-                    tuple(P(axis, None) for _ in range(n_state)),
-                    P(axis, None),
-                    P(axis, None),
-                )
-                + tuple(P(axis, None) for _ in vals),
-                out_specs=tuple(P(axis, None) for _ in range(n_state)),
-            )
-            return list(f(tuple(state), slots, valid, *vals))
-
-        return obs_device.InstrumentedJit("mesh.step", mesh_step,
-                                          exchange=True)
 
     def _make_direct_step(self):
         """Step for host-fed dst-major [S, R] batches: rows were routed to

@@ -531,12 +531,6 @@ class ServeView:
         _top_dict(self.served)[key] = value
         self._served_exact = False
 
-    def has_staged(self, key: Tuple) -> bool:
-        return any(
-            key in layer if isinstance(layer, dict)
-            else layer.find(key, self) >= 0
-            for layer in self._stage)
-
     def seal(self, epoch: int):
         """Move the staged layers under `epoch` as one layer (called at
         checkpoint capture, synchronously at the barrier). The rule is

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import operator as _op
 from typing import (
     Callable,
     Dict,
@@ -118,11 +117,6 @@ class BoundExpr:
     fn: Callable[[pa.RecordBatch], object]  # -> pa.Array | pa.Scalar
     dtype: pa.DataType
     name: str
-    # device-lowerable mirror (JaxExpr) when this expression can run
-    # inside a whole-segment jitted program (engine/segments.py); None
-    # keeps the expression host-only (it can still feed a segment as a
-    # host-evaluated input leaf when its dtype is numeric)
-    jax: Optional["JaxExpr"] = None
     # the parsed expression and the scope `bind` bound it against; None
     # for a closure built by hand
     source: Optional[Tuple[Expr, "Scope"]] = None
@@ -147,159 +141,8 @@ class BoundExpr:
 _NANOS = pa.timestamp("ns")
 
 
-# ---------------------------------------------------------------------------
-# Device lowering (whole-segment jit, engine/segments.py)
-#
-# Numeric expressions additionally carry a JaxExpr: a closure evaluating
-# the same computation over jax arrays inside ONE traced program, so a
-# fused stateless segment (filter -> project -> eval) compiles to a
-# single XLA executable instead of N arrow-kernel passes. Anything not
-# lowerable (strings, structs, UDFs, json) either becomes a
-# host-evaluated input LEAF of the segment program (numeric dtype) or
-# blocks the jax tier for that segment (the composed host tier runs it
-# instead) — values, not availability, are the invariant.
-# ---------------------------------------------------------------------------
-
-
-def jax_lowerable_type(t: pa.DataType) -> bool:
-    """Types representable as a dense jax array column (timestamps and
-    durations ride as int64 nanos)."""
-    return (
-        pa.types.is_integer(t)
-        or pa.types.is_floating(t)
-        or pa.types.is_boolean(t)
-        or pa.types.is_timestamp(t)
-        or pa.types.is_duration(t)
-    )
-
-
-def np_value_dtype(t: pa.DataType):
-    """The numpy dtype a lowerable arrow type computes in on device."""
-    if pa.types.is_timestamp(t) or pa.types.is_duration(t):
-        return np.dtype("int64")
-    if pa.types.is_boolean(t):
-        return np.dtype("bool")
-    return np.dtype(t.to_pandas_dtype())
-
-
-@dataclasses.dataclass
-class JaxExpr:
-    """Device mirror of a BoundExpr: `fn(env)` returns the jax array for
-    this expression, where `env.col(j)` resolves input column j of the
-    expression's own relation and `env.host(key)` resolves a
-    host-evaluated leaf. `scalar` marks literal constants (they follow
-    jax weak-typing, mirroring pa.Scalar coercion on the host path).
-    `leaf` marks inputs that do no device compute themselves; `strict`
-    marks subtrees whose null semantics are strict propagation (null in
-    -> null out), so output validity can be reconstructed host-side as
-    the AND of the leaf validities — kleene AND/OR are NOT strict, and
-    a segment falls back to the host tier for batches where a null
-    would reach a non-strict subtree."""
-
-    fn: Callable
-    cols: frozenset = frozenset()
-    hosts: tuple = ()  # BoundExpr leaves evaluated host-side (stage 0 only)
-    scalar: bool = False
-    leaf: bool = False
-    strict: bool = True
-    # bit-exact vs the arrow/numpy host kernels: True for arith, compare,
-    # cast, abs, mod, sqrt (all correctly rounded / integer-identical);
-    # False for transcendentals, whose libm may differ in the last ulp —
-    # the segment's numpy VECTOR tier requires exact (it must stay
-    # byte-identical to the unfused plan), the jax device tier does not
-    exact: bool = True
-
-
-def _jx_col(idx: int, dtype: pa.DataType) -> Optional[JaxExpr]:
-    if not jax_lowerable_type(dtype):
-        return None
-    return JaxExpr(lambda env: env.col(idx), frozenset((idx,)), leaf=True)
-
-
-def _jx_lit(v) -> Optional[JaxExpr]:
-    if isinstance(v, (bool, int, float)):
-        return JaxExpr(lambda env: v, scalar=True, leaf=True)
-    return None
-
-
-def _jx_cast(jx: JaxExpr, target: pa.DataType) -> JaxExpr:
-    """astype mirrors the host `pc.cast(..., safe=False)` / numpy
-    truncation semantics for numeric-to-numeric casts."""
-    to = np_value_dtype(target)
-
-    def fn(env, f=jx.fn):
-        v = f(env)
-        if hasattr(v, "astype"):
-            return v.astype(to)
-        return np.asarray(v, dtype=to)  # python literal (constant-folds)
-
-    return dataclasses.replace(jx, fn=fn, scalar=False, leaf=False)
-
-
-def _jx_pair(left: "BoundExpr", right: "BoundExpr"):
-    """Both operands' JaxExprs with the host path's _coerce_pair type
-    coercion mirrored (cast to common_type); None when either side is
-    not lowered or the coercion itself is not device-representable."""
-    lj, rj = left.jax, right.jax
-    if lj is None or rj is None:
-        return None
-    lt, rt = left.dtype, right.dtype
-    if pa.types.is_null(lt) or pa.types.is_null(rt):
-        return None
-    if not lt.equals(rt):
-        # literal scalars ride jax weak typing (the host path coerces
-        # the pa.Scalar the same way); real arrays get an explicit cast
-        if not (lj.scalar or rj.scalar):
-            t = common_type(lt, rt)
-            if not jax_lowerable_type(t):
-                return None
-            if not lt.equals(t):
-                lj = _jx_cast(lj, t)
-            if not rt.equals(t):
-                rj = _jx_cast(rj, t)
-    return lj, rj
-
-
-def _jx_combine(f: Callable, *parts: JaxExpr, op_strict: bool = True,
-                op_exact: bool = True) -> JaxExpr:
-    cols = frozenset().union(*(p.cols for p in parts))
-    hosts = []
-    for p in parts:
-        for h in p.hosts:
-            if not any(h is o for o in hosts):
-                hosts.append(h)
-    fns = tuple(p.fn for p in parts)
-    return JaxExpr(
-        lambda env: f(*(g(env) for g in fns)), cols, tuple(hosts),
-        strict=op_strict and all(p.strict for p in parts),
-        exact=op_exact and all(p.exact for p in parts),
-    )
-
-
-def _jnp():
-    from ..ops._jax import get_jax
-
-    return get_jax().numpy
-
-
-def _anp(x):
-    """Array-namespace dispatch: the composed segment closures run the
-    SAME computation on numpy arrays (the host vector tier) and on jax
-    tracers (the jitted device tier)."""
-    return np if isinstance(x, np.ndarray) else _jnp()
-
-
 def bind(expr: Expr, scope: Scope) -> BoundExpr:
-    """Bind + attach the device mirror: expressions that do not lower to
-    jax themselves (struct field access, string ops, UDFs, ...) but have
-    a device-representable dtype become host-evaluated input LEAVES of a
-    fused segment program — `bid.price * 100 / 121` ships the
-    struct_field read as a leaf and multiplies on device."""
     be = _bind(expr, scope)
-    if be.jax is None and jax_lowerable_type(be.dtype):
-        be.jax = JaxExpr(
-            lambda env, _k=id(be): env.host(_k), hosts=(be,), leaf=True
-        )
     be.source = (expr, scope)
     return be
 
@@ -368,8 +211,7 @@ def _bind(expr: Expr, scope: Scope) -> BoundExpr:
         else:
             col = scope.resolve(expr.name)
         idx = col.index
-        return BoundExpr(lambda b: b.column(idx), col.dtype, expr.name,
-                         jax=_jx_col(idx, col.dtype))
+        return BoundExpr(lambda b: b.column(idx), col.dtype, expr.name)
     if isinstance(expr, FieldAccess):
         at = _column_path(expr, scope)
         if at is not None:
@@ -396,48 +238,31 @@ def _bind(expr: Expr, scope: Scope) -> BoundExpr:
         if v is None:
             return BoundExpr(lambda b: pa.scalar(None, pa.null()), pa.null(), "NULL")
         t = _literal_type(v)
-        return BoundExpr(lambda b: pa.scalar(v, t), t, str(v), jax=_jx_lit(v))
+        return BoundExpr(lambda b: pa.scalar(v, t), t, str(v))
     if isinstance(expr, Interval):
         nanos = expr.nanos
         return BoundExpr(
             lambda b: pa.scalar(nanos, pa.int64()), pa.duration("ns"),
-            "interval", jax=_jx_lit(nanos),
+            "interval",
         )
     if isinstance(expr, BinaryOp):
         return _bind_binary(expr, scope)
     if isinstance(expr, UnaryOp):
         operand = bind(expr.operand, scope)
         if expr.op == "NOT":
-            jx = (
-                _jx_combine(_op.invert, operand.jax)
-                if operand.jax is not None
-                and pa.types.is_boolean(operand.dtype) else None
-            )
             return BoundExpr(
                 lambda b: pc.invert(operand.eval(b)), pa.bool_(),
-                f"NOT {operand.name}", jax=jx,
+                f"NOT {operand.name}",
             )
-        jx = (
-            _jx_combine(_op.neg, operand.jax)
-            if operand.jax is not None
-            and not pa.types.is_boolean(operand.dtype) else None
-        )
         return BoundExpr(
             lambda b: pc.negate(operand.eval(b)), operand.dtype,
-            f"-{operand.name}", jax=jx,
+            f"-{operand.name}",
         )
     if isinstance(expr, Cast):
         operand = bind(expr.operand, scope)
         target = sql_type_to_arrow(expr.type_name)
-        jx = (
-            _jx_cast(operand.jax, target)
-            if operand.jax is not None
-            and jax_lowerable_type(operand.dtype)
-            and jax_lowerable_type(target) else None
-        )
         return BoundExpr(
-            lambda b: _cast(operand.eval(b), target), target, operand.name,
-            jax=jx,
+            lambda b: _cast(operand.eval(b), target), target, operand.name
         )
     if isinstance(expr, IsNull):
         operand = bind(expr.operand, scope)
@@ -473,16 +298,7 @@ def _bind(expr: Expr, scope: Scope) -> BoundExpr:
             )
             return pc.invert(out) if expr.negated else out
 
-        jx = None
-        plo, phi = _jx_pair(operand, lo), _jx_pair(operand, hi)
-        if plo is not None and phi is not None:
-            jx = _jx_combine(
-                lambda v1, l1, v2, h1: (v1 >= l1) & (v2 <= h1),
-                plo[0], plo[1], phi[0], phi[1],
-            )
-            if expr.negated:
-                jx = _jx_combine(_op.invert, jx)
-        return BoundExpr(between_fn, pa.bool_(), "between", jax=jx)
+        return BoundExpr(between_fn, pa.bool_(), "between")
     if isinstance(expr, Case):
         return _bind_case(expr, scope)
     if isinstance(expr, FuncCall):
@@ -526,13 +342,6 @@ _CMP = {
 }
 
 
-_JAX_CMP = {
-    "=": _op.eq, "!=": _op.ne, "<": _op.lt, "<=": _op.le,
-    ">": _op.gt, ">=": _op.ge,
-}
-_JAX_ARITH = {"+": _op.add, "-": _op.sub, "*": _op.mul, "/": _op.truediv}
-
-
 def _bind_binary(expr: BinaryOp, scope: Scope) -> BoundExpr:
     left = bind(expr.left, scope)
     right = bind(expr.right, scope)
@@ -540,17 +349,8 @@ def _bind_binary(expr: BinaryOp, scope: Scope) -> BoundExpr:
     name = f"{left.name}{op}{right.name}"
     if op in ("AND", "OR"):
         f = pc.and_kleene if op == "AND" else pc.or_kleene
-        jx = None
-        if (left.jax is not None and right.jax is not None
-                and pa.types.is_boolean(left.dtype)
-                and pa.types.is_boolean(right.dtype)):
-            # kleene and/or are not strictly null-propagating (true OR
-            # null = true): nulls reaching this subtree force the
-            # segment's host tier for that batch
-            jx = _jx_combine(_op.and_ if op == "AND" else _op.or_,
-                             left.jax, right.jax, op_strict=False)
         return BoundExpr(lambda b: f(left.eval(b), right.eval(b)), pa.bool_(),
-                         name, jax=jx)
+                         name)
     if op in _CMP:
         if pa.types.is_struct(left.dtype) and pa.types.is_struct(right.dtype):
             if op != "=":
@@ -568,14 +368,8 @@ def _bind_binary(expr: BinaryOp, scope: Scope) -> BoundExpr:
 
             return BoundExpr(struct_eq, pa.bool_(), name)
         f = _CMP[op]
-        pair = _jx_pair(left, right)
-        jx = (
-            _jx_combine(_JAX_CMP[op], pair[0], pair[1])
-            if pair is not None else None
-        )
         return BoundExpr(
-            lambda b: f(*_coerce_pair(left, right, b)), pa.bool_(), name,
-            jax=jx,
+            lambda b: f(*_coerce_pair(left, right, b)), pa.bool_(), name
         )
     if op == "||":
         return BoundExpr(
@@ -594,13 +388,8 @@ def _bind_binary(expr: BinaryOp, scope: Scope) -> BoundExpr:
             lv, rv = _coerce_pair(left, right, b)
             return _numpy_binary(np.mod, lv, rv)
 
-        pair = _jx_pair(left, right)
-        jx = (
-            _jx_combine(lambda a, c: _anp(a).mod(a, c), pair[0], pair[1])
-            if pair is not None else None
-        )
         return BoundExpr(mod_fn, common_type(_num(left.dtype), _num(right.dtype)),
-                         name, jax=jx)
+                         name)
     raise SqlError(f"unsupported operator {op}")
 
 
@@ -611,10 +400,6 @@ def _num(t: pa.DataType) -> pa.DataType:
 def _bind_arith(left: BoundExpr, right: BoundExpr, op: str, name: str) -> BoundExpr:
     lt, rt = left.dtype, right.dtype
 
-    def _pair_jax(f):
-        pair = _jx_pair(left, right)
-        return _jx_combine(f, pair[0], pair[1]) if pair is not None else None
-
     # timestamp +- interval arithmetic in int64 nanos
     if pa.types.is_timestamp(lt) and pa.types.is_duration(rt):
         f = pc.add if op == "+" else pc.subtract
@@ -623,22 +408,20 @@ def _bind_arith(left: BoundExpr, right: BoundExpr, op: str, name: str) -> BoundE
             lv = pc.cast(left.eval(b), pa.int64())
             return pc.cast(f(lv, right.fn(b)), _NANOS)
 
-        return BoundExpr(ts_fn, _NANOS, name,
-                         jax=_pair_jax(_op.add if op == "+" else _op.sub))
+        return BoundExpr(ts_fn, _NANOS, name)
     if pa.types.is_duration(lt) and pa.types.is_timestamp(rt) and op == "+":
         def ts_fn2(b):
             rv = pc.cast(right.eval(b), pa.int64())
             return pc.cast(pc.add(rv, left.fn(b)), _NANOS)
 
-        return BoundExpr(ts_fn2, _NANOS, name, jax=_pair_jax(_op.add))
+        return BoundExpr(ts_fn2, _NANOS, name)
     if pa.types.is_timestamp(lt) and pa.types.is_timestamp(rt) and op == "-":
         def diff_fn(b):
             return pc.subtract(
                 pc.cast(left.eval(b), pa.int64()), pc.cast(right.eval(b), pa.int64())
             )
 
-        return BoundExpr(diff_fn, pa.duration("ns"), name,
-                         jax=_pair_jax(_op.sub))
+        return BoundExpr(diff_fn, pa.duration("ns"), name)
     out_t = common_type(_num(lt), _num(rt))
     if op == "/" and pa.types.is_integer(out_t):
         # SQL integer division truncates
@@ -648,13 +431,9 @@ def _bind_arith(left: BoundExpr, right: BoundExpr, op: str, name: str) -> BoundE
                 lambda a, c: (a // c).astype(np.int64), lv, rv
             )
 
-        return BoundExpr(
-            idiv, out_t, name,
-            jax=_pair_jax(lambda a, c: (a // c).astype(np.int64)),
-        )
+        return BoundExpr(idiv, out_t, name)
     f = _ARITH[op]
-    return BoundExpr(lambda b: f(*_coerce_pair(left, right, b)), out_t, name,
-                     jax=_pair_jax(_JAX_ARITH[op]))
+    return BoundExpr(lambda b: f(*_coerce_pair(left, right, b)), out_t, name)
 
 
 def _coerce_pair(left: BoundExpr, right: BoundExpr, b) -> Tuple:
@@ -801,36 +580,6 @@ _EXTRACT_FUNCS = {
 }
 
 
-# jnp mirrors for the float64-exact math subset (host kernels and XLA
-# agree bit-for-bit on these elementwise f64 ops); ceil/floor only lower
-# for floats (pc.ceil keeps ints integral, jnp.ceil would promote)
-_JAX_FLOAT_FUNCS = {
-    "ceil": "ceil", "floor": "floor", "sqrt": "sqrt", "exp": "exp",
-    "ln": "log", "log10": "log10", "log2": "log2", "sin": "sin",
-    "cos": "cos", "tan": "tan", "asin": "arcsin", "acos": "arccos",
-    "atan": "arctan",
-}
-
-
-def _jx_func(name: str, a: BoundExpr) -> Optional[JaxExpr]:
-    if a.jax is None:
-        return None
-    if name == "abs" and (pa.types.is_integer(a.dtype)
-                          or pa.types.is_floating(a.dtype)):
-        return _jx_combine(_op.abs, a.jax)
-    jname = _JAX_FLOAT_FUNCS.get(name)
-    if jname is not None and pa.types.is_float64(a.dtype):
-        return _jx_combine(
-            lambda v, _j=jname: getattr(_anp(v), _j)(v), a.jax,
-            # sqrt is IEEE correctly-rounded everywhere; the other libm
-            # functions may differ in the last ulp between kernels, so
-            # only the jax tier (not the byte-identical vector tier)
-            # may run them
-            op_exact=(jname == "sqrt"),
-        )
-    return None
-
-
 def bind_scalar_function(expr: FuncCall, scope: Scope) -> BoundExpr:
     from ..udf import registry as udf_registry
 
@@ -839,8 +588,7 @@ def bind_scalar_function(expr: FuncCall, scope: Scope) -> BoundExpr:
     if name in _SIMPLE_FUNCS:
         f, out_t = _SIMPLE_FUNCS[name]
         a = args[0]
-        return BoundExpr(lambda b: f(a.eval(b)), out_t or a.dtype, name,
-                         jax=_jx_func(name, a))
+        return BoundExpr(lambda b: f(a.eval(b)), out_t or a.dtype, name)
     if name in ("power", "pow"):
         return BoundExpr(
             lambda b: pc.power(args[0].eval(b), args[1].fn(b)), pa.float64(), name

@@ -265,13 +265,8 @@ class Planner:
         out_schema = StreamSchema(add_timestamp_field(pa.schema(out_fields)))
         ts_idx = upstream.schema.timestamp_index
 
-        from .expressions import _jx_col
-
         ts_expr = keep_timestamp_from or BoundExpr(
-            lambda b: b.column(ts_idx), pa.timestamp("ns"), TIMESTAMP_FIELD,
-            # device mirror: the timestamp passthrough is a plain column
-            # ref, so it must not block whole-segment jax lowering
-            jax=_jx_col(ts_idx, pa.timestamp("ns")),
+            lambda b: b.column(ts_idx), pa.timestamp("ns"), TIMESTAMP_FIELD
         )
         # updating streams carry __updating_meta through every projection
         from ..schema import UPDATING_META_FIELD, UPDATING_META_TYPE
@@ -1876,31 +1871,20 @@ class Planner:
         if declared:
             exprs = []
             names = []
-            from .expressions import _jx_col
-
             for i, (df, qf) in enumerate(zip(declared, data_cols)):
                 idx = out.schema.schema.names.index(qf.name)
                 be = BoundExpr(
-                    (lambda j: lambda b: b.column(j))(idx), qf.type, df.name,
-                    # column passthroughs/casts must not block segment
-                    # lowering (sink_cast is the tail of most chains)
-                    jax=_jx_col(idx, qf.type),
+                    (lambda j: lambda b: b.column(j))(idx), qf.type, df.name
                 )
                 if not qf.type.equals(df.type):
-                    from .expressions import _cast, _jx_cast, jax_lowerable_type
+                    from .expressions import _cast
 
-                    jx = (
-                        _jx_cast(be.jax, df.type)
-                        if be.jax is not None
-                        and jax_lowerable_type(df.type) else None
-                    )
                     be = BoundExpr(
                         (lambda j, tt: lambda b: _cast(b.column(j), tt))(
                             idx, df.type
                         ),
                         df.type,
                         df.name,
-                        jax=jx,
                     )
                 exprs.append(be)
                 names.append(df.name)

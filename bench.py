@@ -172,9 +172,10 @@ def child(events: int, backend: str, query: str = "q5",
     if mesh_devices:
         config().tpu.mesh_devices = mesh_devices
     if force_device_join:
-        # measure the jitted join probe's cost model without tpu.enabled
-        # (jax-CPU): VERDICT r3 item 4
-        config().tpu.device_join_force = True
+        # measure the jitted join probe's cost model off the TPU
+        # (jax-CPU): the device tier, accelerator waived
+        config().tpu.enabled = True
+        config().tpu.require_accelerator = False
     if backend == "jax":
         # bench-only tuning (chip_smoke.py runs the defaults): keep the
         # XLA program count flat — every (bucket, capacity) pair

@@ -69,16 +69,14 @@ def test_rescale_drill_exactly_once(tmp_path):
 
 
 def test_pipeline_drill_staged_batches_survive_kill(tmp_path):
-    """ISSUE 14 acceptance: a fused stateless segment with the two-deep
-    staging pipeline on takes a worker SIGKILL mid-flight — canonical
-    output byte-identical to the UNFUSED fault-free run (no staged event
-    lost or duplicated), and the runner.pipeline_drain spans prove a
-    barrier actually drained a staged batch."""
+    """A fused stateless segment on small batches takes a worker SIGKILL
+    and a dropped connection mid-stream — canonical output byte-identical
+    to the UNFUSED fault-free run (no event lost or duplicated), every
+    fault fired, the conservation audit silent."""
     res = drill.run_pipeline_drill(seed=20260804, workdir=str(tmp_path))
-    assert res.passed, f"{res.error}\nextras: {res.extras}"
+    assert res.passed, f"{res.error}\nfired: {res.fired}"
     assert res.restarts >= 1
-    assert res.extras["pipeline_drain_staged_max"] >= 1
-    assert res.extras["barriers_with_staged"] >= 1
+    assert not res.unfired and not res.audit_breaches
 
 
 def test_state_bloat_drill_flat_checkpoints(tmp_path):

@@ -1,3 +1,7 @@
+import dataclasses
+
+import pytest
+
 import arroyo_tpu.config as cfg_mod
 from arroyo_tpu.config import Config, load_config, parse_duration, parse_size, update
 
@@ -40,3 +44,27 @@ def test_scoped_update():
     with update(pipeline={"source_batch_size": 9}):
         assert cfg_mod.config().pipeline.source_batch_size == 9
     assert cfg_mod.config().pipeline.source_batch_size == base
+
+
+def leaves(node) -> int:
+    if not dataclasses.is_dataclass(node):
+        return 1
+    return sum(leaves(getattr(node, f.name))
+               for f in dataclasses.fields(node))
+
+
+@pytest.mark.parametrize("section, key, value", [
+    ("engine", "pipeline_depth", 2),
+    ("engine", "segment_donation", "on"),
+    ("tpu", "device_join_force", True),
+])
+def test_an_option_that_went_is_an_unknown_key(section, key, value):
+    """The fused segment's staging depth and donation went with the
+    tiers they steered, the join's force flag with the rule every other
+    operator uses (`tpu.enabled` + `tpu.require_accelerator`): a file
+    that still sets one is told so, not ignored."""
+    before = leaves(cfg_mod.config())
+    with pytest.raises(ValueError, match=f"unknown config key: {key}"):
+        with update(**{section: {key: value}}):
+            pass
+    assert leaves(cfg_mod.config()) == before == leaves(Config()) == 148

@@ -28,7 +28,8 @@ from arroyo_tpu.controller.scheduler import (
 from arroyo_tpu.controller.state_machine import JobState
 
 
-def bounded_sql(tmp, tag, j, n=3000, rate=1_000_000, realtime=False):
+def bounded_sql(tmp, tag, j, n=3000, rate=1_000_000, realtime=False,
+                window_ms=1):
     """Deterministic event-time pipeline (byte-identical across runs).
     `realtime` uses the impulse REPLAY mode (wall-paced arrival,
     synthetic timestamps): a slow wall-paced fleet run and a fast solo
@@ -45,7 +46,8 @@ def bounded_sql(tmp, tag, j, n=3000, rate=1_000_000, realtime=False):
     );
     INSERT INTO out
     SELECT k, cnt FROM (
-      SELECT counter % 8 as k, tumble(interval '1 millisecond') as w,
+      SELECT counter % 8 as k,
+             tumble(interval '{window_ms} millisecond') as w,
              count(*) as cnt
       FROM impulse GROUP BY 1, 2
     );
@@ -105,6 +107,18 @@ def test_multiplexed_fleet_exactly_once(tmp_path):
     completion produces output byte-identical to its solo run (the
     exactly-once machinery holds per job while co-scheduled)."""
     N = 25
+    # 20 ms windows: at 700 events/s a 1 ms window closes once per event,
+    # and 25 jobs' 17,500 one-row closes a second are more than the one
+    # event loop (controller, both workers, every runner) can turn; the
+    # heartbeats then age past the timeout, every job recovers at once,
+    # replays at the same rate, and no job ever finishes
+    sql = dict(n=3000, rate=700, realtime=True, window_ms=20)
+
+    async def until(cond, what, timeout=30.0):
+        deadline = time.monotonic() + timeout
+        while not cond():
+            assert time.monotonic() < deadline, f"never saw: {what}"
+            await asyncio.sleep(0.02)
 
     async def fleet():
         with update(
@@ -128,15 +142,13 @@ def test_multiplexed_fleet_exactly_once(tmp_path):
             for j in range(N):
                 await c.submit_job(
                     f"fl{j}",
-                    sql=bounded_sql(tmp_path, "fleet", j, n=3000,
-                                    rate=700, realtime=True),
+                    sql=bounded_sql(tmp_path, "fleet", j, **sql),
                     storage_url=str(tmp_path / f"ck-{j}"),
                     n_workers=2, parallelism=1,
                     tenant=f"t{j % 3}",
                 )
             # every job multiplexed onto the same 2 pool workers
-            await asyncio.sleep(0.1)
-            assert len(sched.pool) == 2
+            await until(lambda: len(sched.pool) == 2, "a pool of two")
             for jid in (f"fl{j}" for j in range(N)):
                 await c.wait_for_state(jid, JobState.RUNNING,
                                        JobState.FINISHED, JobState.FAILED,
@@ -151,8 +163,16 @@ def test_multiplexed_fleet_exactly_once(tmp_path):
             for jid in stopped:
                 await c.stop_job(jid, "immediate")
             # one mid-run SIGKILL-equivalent on a pool worker: every job
-            # with subtasks there recovers independently from checkpoints
-            await asyncio.sleep(1.0)
+            # with subtasks there recovers independently from checkpoints.
+            # Mid-run is a condition, not a time: a job that is running
+            # and has a published checkpoint to recover from
+            def mid_run():
+                return [j for j in c.jobs.values()
+                        if j.job_id not in stopped
+                        and j.state == JobState.RUNNING
+                        and j.published_epoch >= 1]
+
+            await until(mid_run, "a running job with a checkpoint")
             victim = next(
                 w for w, _t in sched.pool
                 if not getattr(w, "_shutdown_started", False)
@@ -183,8 +203,7 @@ def test_multiplexed_fleet_exactly_once(tmp_path):
             # the solo bytes independent of wall-clock conditions
             await c.submit_job(
                 f"solo{j}",
-                sql=bounded_sql(tmp_path, "solo", j, n=3000, rate=700,
-                                realtime=True),
+                sql=bounded_sql(tmp_path, "solo", j, **sql),
                 storage_url=str(tmp_path / f"solo-ck-{j}"),
                 n_workers=2, parallelism=1,
             )

@@ -109,40 +109,6 @@ def test_float_accumulators_stay_on_host_without_ieee_float64(
         [AggSpec("sum", 0, "s", is_float=True)]).backend == "numpy"
 
 
-def test_float_segment_leaves_the_jax_tier_without_ieee_float64(
-        tpu_like_float64):
-    import asyncio
-
-    from arroyo_tpu.engine import Engine
-    from arroyo_tpu.metrics import REGISTRY
-    from arroyo_tpu.sql import plan_query
-
-    ddl = """CREATE TABLE nexmark WITH (connector = 'nexmark',
-      event_rate = '100000', message_count = '4000', start_time = '0');"""
-    chain = """SELECT auction, p - p % 10 AS p FROM (
-      SELECT bid.auction AS auction, {expr} AS p
-      FROM nexmark WHERE bid IS NOT NULL);"""
-
-    def jax_dispatches(expr):
-        REGISTRY.reset()
-        rows = []
-        plan = plan_query(ddl + chain.format(expr=expr),
-                          preview_results=rows)
-
-        async def go():
-            await Engine(plan.graph).start().join(120)
-
-        asyncio.run(go())
-        assert rows
-        return sum(
-            h.get("count", 0) for labels, h in REGISTRY.snapshot().get(
-                "arroyo_segment_dispatch_seconds", [])
-            if labels.get("tier") == "jax")
-
-    assert jax_dispatches("bid.price * 100 / 121") > 0       # int64 only
-    assert jax_dispatches("CAST(bid.price * 0.908 AS BIGINT)") == 0
-
-
 def run_smoke(*argv, **env):
     return subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), *argv],

@@ -414,7 +414,7 @@ def test_every_mesh_program_has_its_own_name_in_a_trace():
                                exchange="device")
     salted = ShardedAccumulator([AggSpec("count", None, "num")], mesh, salted=True)
     programs = [
-        keyed._route_step(16, 16), keyed._step(), keyed._direct_step(),
+        keyed._route_step(16, 16), keyed._direct_step(),
         keyed._sliced_gather_program(), keyed._sliced_take_program(),
         keyed._sliced_reset_program(), keyed._sliced_restore_program(),
         salted._gather_program(), salted._take_program(),
@@ -423,7 +423,7 @@ def test_every_mesh_program_has_its_own_name_in_a_trace():
     ]
     names = {p.program: p.fn.__name__ for p in programs}
     assert names == {p: p.replace(".", "_") for p in (
-        "mesh.route", "mesh.step", "mesh.step_direct", "mesh.sgather",
+        "mesh.route", "mesh.step_direct", "mesh.sgather",
         "mesh.stake", "mesh.sreset", "mesh.srestore", "mesh.gather",
         "mesh.take", "mesh.reset", "mesh.restore", "mesh.gather_free")}
     # and that is the module's name where XLA compiles it

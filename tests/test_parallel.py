@@ -188,38 +188,23 @@ def test_packed_exchange_sized_to_batch(mesh):
     assert g[0][0] == 4096 and g[1][0] == 4096
 
 
-def test_all_to_all_path_matches_direct(mesh):
-    """host_fed=False keeps the [S, S, R] src-major packing + in-step
-    all_to_all (the multi-host / device-resident-producer shuffle); it
-    must produce identical state to the host-fed direct layout."""
-    from arroyo_tpu.parallel import MeshSlotDirectory, ShardedAccumulator
+def test_the_legacy_a2a_layout_is_refused_by_name(mesh):
+    """The mesh has two exchange layouts, `device` and `host_fed`; the
+    host-packed [S, S, R] `a2a` went, and asking for it (the ctor's
+    `exchange=` or `tpu.mesh_exchange`) is an error like any other
+    unknown name, not a quiet fallback."""
+    from arroyo_tpu.config import update
+    from arroyo_tpu.parallel import ShardedAccumulator
 
-    specs = [AggSpec("count", None, "cnt"), AggSpec("sum", 0, "total"),
-             AggSpec("min", 1, "lo")]
-    rng = np.random.default_rng(11)
-    n = 5000
-    keys = rng.integers(0, 300, n)
-    bins = rng.integers(0, 2, n)
-    ints = rng.integers(-100, 100, n)
-    ints2 = rng.integers(0, 1000, n)
-
-    outs = []
-    for host_fed in (True, False):
-        acc = ShardedAccumulator(specs, mesh, capacity_per_shard=1024,
-                                 rows_per_shard=256, host_fed=host_fed)
-        d = MeshSlotDirectory(acc.n_shards)
-        for lo in range(0, n, 1700):
-            hi = min(lo + 1700, n)
-            slots = d.assign(bins[lo:hi], [keys[lo:hi]])
-            acc.update(slots, {0: ints[lo:hi], 1: ints2[lo:hi]})
-        rows = {}
-        for b in (0, 1):
-            ks, ss = d.take_bin(b)
-            g = acc.gather(ss)
-            for k, c, t, m in zip(ks, g[0], g[1], g[2]):
-                rows[(b, k[0])] = (int(c), int(t), int(m))
-        outs.append(rows)
-    assert outs[0] == outs[1]
+    specs = [AggSpec("count", None, "cnt")]
+    with pytest.raises(ValueError, match="auto\\|device\\|host_fed, got 'a2a'"):
+        ShardedAccumulator(specs, mesh, exchange="a2a")
+    with update(tpu={"mesh_exchange": "a2a"}):
+        with pytest.raises(ValueError, match="got 'a2a'"):
+            ShardedAccumulator(specs, mesh)
+    with pytest.raises(TypeError):
+        ShardedAccumulator(specs, mesh, host_fed=False)
+    assert ShardedAccumulator(specs, mesh)._exchange == "host_fed"
 
 
 def test_salted_accumulator_low_cardinality(mesh):

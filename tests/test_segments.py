@@ -1,23 +1,17 @@
-"""Fused segment runtime (ISSUE 14): plan-time fusion, host/vector/jax
-execution tiers, the double-buffered staging pipeline, and the barrier
-drain — every tier must be value-identical to the unfused per-operator
-plan, and the pipeline must be byte-order-identical at any depth."""
+"""Fused segment runtime: plan-time fusion and the one composed view a
+fused segment runs — value-identical to the unfused per-operator plan,
+nothing held between batches."""
 
 import asyncio
 import json
+import types
 
 import pyarrow as pa
 import pytest
 
-from arroyo_tpu import obs
 from arroyo_tpu.config import update
-from arroyo_tpu.engine import Engine, segments
-from arroyo_tpu.engine.segments import (
-    FusedSegmentOperator,
-    SegmentFusionPass,
-    build_program,
-    plan_runs,
-)
+from arroyo_tpu.engine import Engine
+from arroyo_tpu.engine.segments import FusedSegmentOperator, plan_runs
 from arroyo_tpu.graph.logical import OperatorName
 from arroyo_tpu.metrics import REGISTRY
 from arroyo_tpu.sql import plan_query
@@ -161,18 +155,65 @@ def test_segment_config_json_round_trips_nested_op_lists():
                              "description": "key"}
 
 
-# -- execution tiers ---------------------------------------------------------
+# -- execution ---------------------------------------------------------------
 
 
-def test_fused_output_byte_identical_to_unfused():
+def nested(inner_select, where, outer="auction, v"):
+    return NEXMARK_DDL + f"""
+SELECT {outer} FROM (
+  SELECT {inner_select} FROM nexmark WHERE {where}
+);
+"""
+
+
+FUSED_VS_UNFUSED = {
+    "nexmark_chain": (PREVIEW_SQL, 512),
+    # a person is one event in fifty: at 16 rows a batch most batches
+    # hold none, and the segment drops them whole
+    "all_filtered_batches": (nested(
+        "person.id AS auction, person.id * 3 AS v",
+        "person IS NOT NULL"), 16),
+    # no IS NOT NULL: a person's or an auction's row has a null `bid`, so
+    # nulls reach the kleene AND / OR, which keep (true OR null) and drop
+    # (true AND null) as the unfused kernels do
+    "nulls_reach_kleene_and_or": (nested(
+        "bid.auction AS auction, bid.price AS v, "
+        "(bid.price > 500 OR bid.auction % 2 = 0) AS either, "
+        "(bid.price > 500 AND bid.auction % 2 = 0) AS both",
+        "(bid.price > 100 OR person IS NOT NULL) "
+        "AND (bid.auction % 3 = 0 OR bid.bidder % 2 = 0)",
+        outer="auction, v, either, both"), 128),
+    "numeric_chain_cast_between": (nested(
+        "bid.auction AS auction, "
+        "CAST(bid.price AS DOUBLE) / 3 AS v, "
+        "CAST(bid.price / 7 AS INT) AS small, -bid.bidder AS neg",
+        "bid IS NOT NULL AND bid.price BETWEEN 100 AND 100000 "
+        "AND bid.auction NOT BETWEEN 1010 AND 1020",
+        outer="auction, v + small AS v, neg % 5 AS m"), 512),
+    # q1's currency conversion as NEXmark writes it, and libm besides
+    "float64_chain": (nested(
+        "bid.auction AS auction, bid.price * 0.908 AS v",
+        "bid IS NOT NULL",
+        outer="auction, CAST(v AS BIGINT) AS eur, sqrt(v) AS r, "
+              "ln(v + 1.0) AS l, v - floor(v) AS frac"), 512),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED_VS_UNFUSED))
+def test_fused_output_byte_identical_to_unfused(case):
+    sql, batch_rows = FUSED_VS_UNFUSED[case]
     outs = {}
     for fusion in (True, False):
         REGISTRY.reset()
         with update(engine={"segment_fusion": fusion},
-                    tpu={"enabled": False}):
+                    tpu={"enabled": False},
+                    pipeline={"source_batch_size": batch_rows}):
             results = []
-            run_engine(PREVIEW_SQL, results)
+            plan = run_engine(sql, results)
             outs[fusion] = results
+        fused = [op for n in plan.graph.nodes.values() for op in n.chain
+                 if op.operator == OperatorName.FUSED_SEGMENT]
+        assert bool(fused) == fusion, "the case's chain did not fuse"
     assert len(outs[True]) == len(outs[False]) > 0
     assert canon(outs[True]) == canon(outs[False])
 
@@ -191,148 +232,10 @@ def test_dispatches_per_batch_collapse_at_least_3x():
     assert dpb[False] / dpb[True] >= 3.0
 
 
-def test_jax_tier_matches_host_tier():
-    """Whole-chain jit: one compiled program, identical output — incl.
-    null handling through the bid struct fields (non-bid rows)."""
-    outs = {}
-    for jax_on in (False, True):
-        REGISTRY.reset()
-        with update(
-            engine={"segment_fusion": True},
-            tpu={"enabled": jax_on, "require_accelerator": False},
-        ):
-            results = []
-            run_engine(PREVIEW_SQL, results)
-            outs[jax_on] = results
-            snap = REGISTRY.snapshot()
-            tiers = {
-                l.get("tier"): v.get("count", 0)
-                for l, v in snap.get("arroyo_segment_dispatch_seconds", [])
-            }
-        if jax_on:
-            assert tiers.get("jax", 0) > 0, tiers
-        else:
-            assert "jax" not in tiers
-    assert canon(outs[True]) == canon(outs[False])
-
-
-def test_jax_tier_recompiles_once_per_rung_change():
-    with update(
-        engine={"segment_fusion": True},
-        tpu={"enabled": True, "require_accelerator": False},
-    ):
-        plan = plan_query(CHAIN_SQL)
-        node = next(
-            n for n in plan.graph.nodes.values()
-            if any(op.operator == OperatorName.FUSED_SEGMENT
-                   for op in n.chain)
-        )
-        seg_cfg = next(
-            op for op in node.chain
-            if op.operator == OperatorName.FUSED_SEGMENT
-        )
-        op = FusedSegmentOperator(seg_cfg.config["ops"], None, "t")
-        prog = op._program()
-        assert prog is not None and op._use_jax
-        # two batch sizes inside one rung -> one signature; a bigger
-        # batch climbs the rung -> exactly one more compile. Real input
-        # batches are captured from one engine run.
-        batches = []
-        orig = FusedSegmentOperator.process_batch
-
-        async def cap(self, batch, ctx, collector, input_index=0):
-            batches.append(batch)
-            return await orig(self, batch, ctx, collector, input_index)
-
-        FusedSegmentOperator.process_batch = cap
-        try:
-            run_engine(CHAIN_SQL)
-        finally:
-            FusedSegmentOperator.process_batch = orig
-        assert batches
-        b = batches[0]
-        seen0 = len(prog.jit.seen) if prog.jit else 0
-        r1 = op._dispatch_jax(b.slice(0, min(100, b.num_rows)), prog)
-        r2 = op._dispatch_jax(b.slice(0, min(120, b.num_rows)), prog)
-        assert r1 is not None and r2 is not None
-        after_small = len(prog.jit.seen)
-        assert after_small == seen0 + 1  # both fit one rung: ONE signature
-        # climb: a batch past the rung compiles exactly once more
-        big = pa.concat_tables(
-            [pa.Table.from_batches([b])] * 6
-        ).combine_chunks().to_batches()[0]
-        r3 = op._dispatch_jax(big, prog)
-        assert r3 is not None
-        assert len(prog.jit.seen) == after_small + 1
-
-
-def test_vector_tier_filter_late_matches_view_tier():
-    """The numpy vector tier (filter-late over unfiltered leaves) must
-    equal the lazy-view tier batch for batch, including all-filtered
-    and no-predicate-hit batches."""
-    with update(engine={"segment_fusion": True}, tpu={"enabled": False}):
-        plan = plan_query(CHAIN_SQL)
-        node = next(
-            n for n in plan.graph.nodes.values()
-            if any(op.operator == OperatorName.FUSED_SEGMENT
-                   for op in n.chain)
-        )
-        seg_cfg = next(
-            op for op in node.chain
-            if op.operator == OperatorName.FUSED_SEGMENT
-        )
-        op = FusedSegmentOperator(seg_cfg.config["ops"], None, "t")
-        prog = op._program()
-        assert prog is not None and prog.exact
-        batches = []
-        orig = FusedSegmentOperator.process_batch
-
-        async def cap(self, batch, ctx, collector, input_index=0):
-            batches.append(batch)
-            return await orig(self, batch, ctx, collector, input_index)
-
-        FusedSegmentOperator.process_batch = cap
-        try:
-            run_engine(CHAIN_SQL)
-        finally:
-            FusedSegmentOperator.process_batch = orig
-        assert batches
-        for b in batches[:5]:
-            view = op._run_host(b)
-            vec = op._run_vector(b, prog)
-            assert vec is not b, "vector tier unexpectedly fell back"
-            if view is None:
-                assert vec is None
-            else:
-                assert view.equals(vec)
-
-
-# -- pipelining / staging ----------------------------------------------------
-
-
-def test_pipeline_depths_emit_identical_output():
-    """Staging engages on the jax tier (dispatched-but-unmaterialized
-    results); every depth must emit the SAME rows in the SAME order."""
-    outs = {}
-    for depth in (1, 2, 4):
-        REGISTRY.reset()
-        with update(engine={"segment_fusion": True,
-                            "pipeline_depth": depth},
-                    tpu={"enabled": True, "require_accelerator": False},
-                    pipeline={"source_batch_size": 128}):
-            results = []
-            run_engine(PREVIEW_SQL, results)
-            outs[depth] = [
-                json.dumps(r, sort_keys=True, default=str) for r in results
-            ]
-    # ORDER-identical, not just set-identical: staging is strictly FIFO
-    assert outs[1] == outs[2] == outs[4]
-
-
 def test_windowed_aggregate_downstream_of_segment_is_exact():
-    """Watermark hold/release: a tumbling aggregate fed by a fused
-    segment must see every pre-watermark row before the watermark (or
-    window counts would drop staged rows)."""
+    """A tumbling aggregate fed by a fused segment must see every
+    pre-watermark row before the watermark (or window counts would drop
+    rows)."""
     sql = NEXMARK_DDL + """
     CREATE TABLE sink (a BIGINT, c BIGINT)
     WITH (connector = 'blackhole', type = 'sink');
@@ -349,13 +252,8 @@ def test_windowed_aggregate_downstream_of_segment_is_exact():
     outs = {}
     for fusion in (True, False):
         REGISTRY.reset()
-        # fused run on the jitted tier (staging + watermark hold really
-        # engage); unfused reference on the plain host kernels
-        tpu = ({"enabled": True, "require_accelerator": False}
-               if fusion else {"enabled": False})
-        with update(engine={"segment_fusion": fusion,
-                            "pipeline_depth": 2},
-                    tpu=tpu,
+        with update(engine={"segment_fusion": fusion},
+                    tpu={"enabled": False},
                     pipeline={"source_batch_size": 128}):
             plan = plan_query(sql)
             segs = [
@@ -370,54 +268,59 @@ def test_windowed_aggregate_downstream_of_segment_is_exact():
     assert canon(outs[True]) == canon(outs[False])
 
 
-def test_barrier_drain_records_pipeline_drain_span(tmp_storage):
-    """Checkpoint barriers drain the staging queue before capture and
-    record a runner.pipeline_drain span per barrier."""
-    from arroyo_tpu.engine.engine import Engine as EmbeddedEngine
+def test_segment_emits_before_the_watermark_and_holds_nothing():
+    """The view emits eagerly: a batch's output is collected inside
+    `process_batch`, so a watermark behind it passes through unchanged
+    and in order, and a barrier and a close find nothing to flush — the
+    operator leaves all three to `Operator`'s defaults."""
+    from arroyo_tpu.operators.base import Operator
+    from arroyo_tpu.types import CheckpointBarrier, Watermark
 
-    obs.recorder().clear()
-    REGISTRY.reset()
-    with update(engine={"segment_fusion": True, "pipeline_depth": 2},
-                tpu={"enabled": False},
-                pipeline={"source_batch_size": 64}):
-        sql = NEXMARK_DDL.replace("20000", "4000").replace(
-            "40000", "20000") + """
-        SELECT auction, price_eur, bidder FROM (
-          SELECT auction, price_eur - price_eur % 10 AS price_eur,
-                 bidder FROM (
-            SELECT bid.auction as auction,
-                   bid.price * 100 / 121 as price_eur,
-                   bid.bidder as bidder
-            FROM nexmark WHERE bid IS NOT NULL
-          )
-        );
-        """
-        results = []
-        plan = plan_query(sql, preview_results=results)
+    with update(engine={"segment_fusion": True}):
+        plan = plan_query(CHAIN_SQL)
+    seg = next(op for n in plan.graph.nodes.values() for op in n.chain
+               if op.operator == OperatorName.FUSED_SEGMENT)
+    op = FusedSegmentOperator(seg.config["ops"], None, "t")
+    for hook in ("handle_watermark", "handle_checkpoint", "on_close"):
+        assert getattr(type(op), hook) is getattr(Operator, hook), hook
+    assert not hasattr(op, "drain") and not hasattr(op, "is_fused_segment")
 
-        async def go():
-            eng = EmbeddedEngine(plan.graph, job_id="seg-drain",
-                                 storage_url=tmp_storage).start()
-            done = asyncio.ensure_future(eng.join(120))
-            ck = 0
-            while not done.done() and ck < 3:
-                await asyncio.sleep(0.3)
-                if done.done():
-                    break
-                try:
-                    await eng.checkpoint_and_wait()
-                    ck += 1
-                except Exception:  # noqa: BLE001 - racing stream end
-                    break
-            await done
+    batches = []
+    orig = FusedSegmentOperator.process_batch
 
-        asyncio.run(go())
-    drains = [
-        s for s in obs.recorder().snapshot()
-        if s.get("name") == "runner.pipeline_drain"
-    ]
-    assert drains, "no runner.pipeline_drain span recorded at barriers"
-    assert all("staged" in s.get("attrs", {}) for s in drains)
+    async def cap(self, batch, ctx, collector, input_index=0):
+        batches.append(batch)
+        return await orig(self, batch, ctx, collector, input_index)
+
+    FusedSegmentOperator.process_batch = cap
+    try:
+        run_engine(CHAIN_SQL)
+    finally:
+        FusedSegmentOperator.process_batch = orig
+    batch = next(b for b in batches if op._run_host(b) is not None)
+
+    events = []
+
+    class Collector:
+        async def collect(self, out):
+            events.append(("batch", out.num_rows))
+
+    ctx = types.SimpleNamespace(
+        task_info=types.SimpleNamespace(job_id="j", task_id="t-0"))
+
+    async def go():
+        col = Collector()
+        await op.process_batch(batch, ctx, col)
+        wm = Watermark.event_time(7)
+        events.append(("watermark", await op.handle_watermark(wm, ctx, col)))
+        await op.handle_checkpoint(CheckpointBarrier(1, 0, 0, False), ctx, col)
+        events.append(("barrier", None))
+        assert await op.on_close(ctx, col, True) is None
+        return wm
+
+    wm = asyncio.run(go())
+    assert events == [("batch", op._run_host(batch).num_rows),
+                      ("watermark", wm), ("barrier", None)]
 
 
 # -- metrics / observability -------------------------------------------------

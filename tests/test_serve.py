@@ -125,9 +125,6 @@ class _ModelView:
         else:
             self._stage[key] = self._TOMB
 
-    def has_staged(self, key):
-        return key in self._stage
-
     def stage_batch(self, batch, partial=False):
         from arroyo_tpu.serve.store import _plain
 
@@ -248,7 +245,6 @@ def test_columnar_view_answers_as_the_dict_model(case, live_mode):
         at = None if live_mode else published
         for k in sorted(universe, key=repr):
             assert view.read(k, at) == model.read(k, at), (k, at)
-            assert view.has_staged(k) == model.has_staged(k), k
         assert view.stats()["keys"] == len(model.served)
 
     for _step in range(160):

@@ -266,7 +266,8 @@ def test_updating_inner_join_bulk_probe_path(tmp_path, monkeypatch):
         JOIN impulse_odd B ON A.counter = B.counter;
         """
     )
-    with update(tpu={"device_join_force": True, "device_join_min_rows": 0}):
+    with update(tpu={"enabled": True, "require_accelerator": False,
+                     "device_join_min_rows": 0}):
         final, ops = run_to_debezium(sql, tmp_path)
     got = sorted(r["left_count"] for r in final)
     assert got == list(range(1, 40, 2))
@@ -298,7 +299,8 @@ def test_updating_join_bulk_falls_back_on_retracts(tmp_path, monkeypatch):
         """
     )
     baseline, _ = run_to_debezium(sql, tmp_path / "base")
-    with update(tpu={"device_join_force": True, "device_join_min_rows": 0}):
+    with update(tpu={"enabled": True, "require_accelerator": False,
+                     "device_join_min_rows": 0}):
         final, _ = run_to_debezium(sql, tmp_path / "bulk")
     key = lambda rows: sorted(json.dumps(r, sort_keys=True) for r in rows)
     assert key(final) == key(baseline)
@@ -328,7 +330,8 @@ def test_updating_inner_join_bulk_probe_strings(tmp_path, monkeypatch):
         """
     )
     baseline, _ = run_to_debezium(sql, tmp_path / "base")
-    with update(tpu={"device_join_force": True, "device_join_min_rows": 0}):
+    with update(tpu={"enabled": True, "require_accelerator": False,
+                     "device_join_min_rows": 0}):
         bulk, _ = run_to_debezium(sql, tmp_path / "bulk")
     key = lambda rows: sorted(json.dumps(r, sort_keys=True) for r in rows)
     assert key(bulk) == key(baseline)

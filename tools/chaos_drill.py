@@ -94,11 +94,10 @@
         log + Perfetto trace land in the workdir.
 
     python tools/chaos_drill.py --pipeline
-        ISSUE 14 acceptance: a stateless chain fused into ONE segment
-        with the two-deep staging pipeline on, worker SIGKILL lands
-        while a batch is staged; requires byte-identical output vs the
-        UNFUSED fault-free run AND runner.pipeline_drain evidence that
-        a barrier actually drained a staged batch. (Every standard
+        A stateless chain fused into ONE segment on small batches, a
+        worker SIGKILL and a dropped connection land mid-stream;
+        requires byte-identical output vs the UNFUSED fault-free run, a
+        real recovery and every fault fired. (Every standard
         drill is also a fused-vs-unfused A/B: clean references run with
         segment fusion OFF, faulted runs keep the fused default.)
 """
@@ -135,11 +134,10 @@ def main() -> int:
                     "growth + SIGKILL mid-upload; requires byte-identical "
                     "output and ~flat capture time / delta bytes")
     ap.add_argument("--pipeline", action="store_true",
-                    help="also run the fused-pipeline drill: a stateless "
-                    "chain fused into one segment with two-deep staging, "
-                    "SIGKILL mid-flight; requires byte-identical output "
-                    "vs the UNFUSED clean run and proof that a barrier "
-                    "drained a staged batch")
+                    help="also run the fused-segment drill: a stateless "
+                    "chain fused into one segment, SIGKILL + connection "
+                    "drop mid-stream; requires byte-identical output "
+                    "vs the UNFUSED clean run and a real recovery")
     ap.add_argument("--shared", action="store_true",
                     help="also run the shared-plan fleet drill: two "
                     "tenants mount ONE shared scan, a worker SIGKILL "

@@ -337,21 +337,19 @@ def main() -> int:
                 # per-segment ledger (ISSUE 14): one row per fused
                 # segment program — how many operator dispatches each
                 # batch no longer pays, and what the single dispatch
-                # costs per tier
-                print("\n| segment | fused ops | tier | dispatches "
+                # costs
+                print("\n| segment | fused ops | dispatches "
                       "| total s | p50/p95 |")
-                print("|---|---|---|---|---|---|")
+                print("|---|---|---|---|---|")
                 for name, s in sorted(segs.items()):
-                    for tier in ("host", "jax"):
-                        n = s.get(f"{tier}_dispatches")
-                        if not n:
-                            continue
-                        q = s.get(f"{tier}_quantiles", {})
-                        print(f"| {name} | {s.get('fused_ops', '?')} "
-                              f"| {tier} | {n} "
-                              f"| {s.get(f'{tier}_s_total', 0)} "
-                              f"| {q.get('p50', 'n/a')}/"
-                              f"{q.get('p95', 'n/a')} s |")
+                    n = s.get("host_dispatches")
+                    if not n:
+                        continue
+                    q = s.get("host_quantiles", {})
+                    print(f"| {name} | {s.get('fused_ops', '?')} "
+                          f"| {n} | {s.get('host_s_total', 0)} "
+                          f"| {q.get('p50', 'n/a')}/"
+                          f"{q.get('p95', 'n/a')} s |")
     return 0
 
 
